@@ -18,45 +18,30 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-if "cpu" not in os.environ.get("JAX_PLATFORMS", "cpu"):
-    os.environ["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"] + ",cpu"
-
 LOGDIR = os.environ.get("MZT_PROFILE_DIR", "/tmp/mzt_profile")
 
 
 def main():
-    import contextlib
-
     import jax
 
-    from bench import _cpu_device, _phase, build_tpu_side
+    from bench import _phase, build_tpu_side
 
     sf = float(os.environ.get("MZT_BENCH_SF", "0.1"))
     ticks = int(os.environ.get("MZT_BENCH_TICKS", "5"))
     frac = float(os.environ.get("MZT_BENCH_FRAC", "0.005"))
 
-    cpu = _cpu_device()
-    bulk_ctx = jax.default_device(cpu) if cpu is not None else contextlib.nullcontext()
-    with bulk_ctx:
-        gen, init, caps, step, state = build_tpu_side(sf, ticks, frac, 0, 1)
-        from materialize_tpu.models.fused_q3 import hydrate
-        from materialize_tpu.repr import UpdateBatch
+    gen, init, caps, step, state = build_tpu_side(sf, ticks, frac, 0, 1)
+    from materialize_tpu.models.fused_q3 import hydrate
+    from materialize_tpu.repr import UpdateBatch
 
-        _phase("hydrating")
-        state = hydrate(state, init["customer"], init["orders"], init["lineitem"], 1)
-        jax.block_until_ready(state.accum.levels[-1].nrows)
-        empty_c = UpdateBatch.empty(8, (), (np.dtype(np.int64),) * 3)
-        refreshes = []
-        for t in range(2, 2 + ticks + 1):
-            r = gen.refresh(t, frac=frac)
-            refreshes.append((t, r))
-
-    dev = jax.devices()[0]
-    _phase(f"transferring to {dev}")
-    if cpu is not None and dev.platform != "cpu":
-        batches = [r for _t, r in refreshes]
-        state, empty_c, batches = jax.device_put((state, empty_c, batches), dev)
-        refreshes = [(t, r) for (t, _), r in zip(refreshes, batches)]
+    _phase("hydrating")
+    state = hydrate(state, init["customer"], init["orders"], init["lineitem"], 1)
+    jax.block_until_ready(state.accum.levels[-1].nrows)
+    empty_c = UpdateBatch.empty(8, (), (np.dtype(np.int64),) * 3)
+    refreshes = []
+    for t in range(2, 2 + ticks + 1):
+        r = gen.refresh(t, frac=frac)
+        refreshes.append((t, r))
 
     _phase("warmup (compile-cache expected warm)")
     t0, r0 = refreshes[0]

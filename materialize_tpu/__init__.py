@@ -31,25 +31,24 @@ jax.config.update("jax_enable_x64", True)
 
 # Kernel shapes recur across ticks, restarts, and processes (pow2-bucketed
 # capacities); the persistent compilation cache turns the per-shape XLA
-# compile into a one-time cost per machine. Default-on for accelerators
-# (where compiles cost tens of seconds); on CPU the XLA AOT loader warns
-# about machine-feature mismatches, so it's opt-in there via
-# MZT_COMPILE_CACHE=1. Opt out everywhere with MZT_NO_COMPILE_CACHE=1.
+# compile into a one-time cost per checkout. On for accelerators (where a
+# fused tick compiles for minutes); off under JAX_PLATFORMS=cpu, where the
+# XLA AOT loader warns about machine-feature mismatches. Where
+# JAX_COMPILATION_CACHE_DIR is set JAX already keeps its cache there and no
+# directory is set in code; otherwise the cache lives at one fixed path
+# inside the checkout (the path is part of the cache key, so it must not move).
 import os as _os
 
-_want_cache = _os.environ.get("MZT_NO_COMPILE_CACHE") != "1" and (
-    _os.environ.get("JAX_PLATFORMS", "") != "cpu"
-    or _os.environ.get("MZT_COMPILE_CACHE") == "1"
-)
-if _want_cache:
-    try:
-        _cache_dir = _os.environ.get(
-            "MZT_COMPILE_CACHE_DIR", "/tmp/materialize_tpu_xla_cache"
+if _os.environ.get("JAX_PLATFORMS", "") != "cpu":
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            _os.path.join(
+                _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+                ".jax_cache",
+            ),
         )
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
 
 __version__ = "0.1.0"

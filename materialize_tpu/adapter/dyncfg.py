@@ -272,10 +272,12 @@ KERNEL_BACKEND = Config(
     "kernel_backend",
     "auto",
     "which implementation the registered hot-path kernels (run_sum, "
-    "multi_take, probe, probe2; ops/kernels/) dispatch to: 'auto' picks "
-    "pallas on TPU and xla elsewhere, 'xla'/'pallas' force a backend on any "
-    "platform (pallas off-TPU runs in interpret mode — correct but slow, for "
-    "differential testing); takes effect at the next tick render, no restart",
+    "multi_take, probe, probe2, route_dest, bucket_rank; ops/kernels/) "
+    "dispatch to: 'auto' picks xla on every platform (no registered pallas "
+    "program compiles for the chip yet), 'xla'/'pallas' force a backend "
+    "(pallas off-TPU runs in interpret mode — correct but slow, for "
+    "differential testing; on a TPU it raises the chip compiler's error); "
+    "takes effect at the next tick render, no restart",
 )
 
 # -- frontend backend (serve/: reactor vs thread-per-connection serving) -----

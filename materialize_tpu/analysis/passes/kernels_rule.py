@@ -9,10 +9,14 @@ bit-identity guarantee if three lexical invariants hold across the tree:
      into a KeyError (or worse, an untested fallback) at tick time;
   2. ``pallas_call`` is confined to ``materialize_tpu/ops/kernels/`` and
      every call sets ``interpret=`` to a ``pallas_interpret()`` CALL — a
-     bare ``interpret=True``/``False`` either compiles for a chip that CI
-     does not have or interprets on the chip we paid for, and a pallas_call
-     outside the registry escapes the dispatch counter, the XLA oracle and
-     the differential suite;
+     bare ``interpret=False`` asks the chip's compiler off a chip (where the
+     tier-1 byte-identity suites could no longer run the kernel), a bare
+     ``interpret=True`` emulates on the chip a forced ``kernel_backend =
+     pallas`` paid for, and a pallas_call outside the registry escapes the
+     dispatch counter, the XLA oracle and the differential suite. ``auto``
+     never reaches a pallas_call (it resolves to xla on every platform);
+     whether a program compiles for the chip is tests/test_chip_compile.py's
+     question, asked with interpret forced off in the test;
   3. every ``dispatch("name", ...)`` literal names a registered kernel and
      every registered kernel is dispatched somewhere — a typo'd name fails
      at lint time, not as a KeyError in a compiled tick.
@@ -113,7 +117,9 @@ class KernelDispatchCoherence(Rule):
                             node.lineno,
                             "pallas_call must pass "
                             "interpret=registry.pallas_interpret() — the one "
-                            "place the interpret-off-TPU policy is decided",
+                            "place the interpret-off-TPU policy is decided "
+                            "(a forced kernel_backend = pallas compiles for "
+                            "the chip on a TPU and interprets elsewhere)",
                         )
 
         for name, (rel, line) in sorted(dispatched.items()):

@@ -110,7 +110,7 @@ class FusedCaps:
         return level_caps(full, max(self.delta, 64), self.levels, ratio=self.ratio)
 
     def join_caps(self, probe_cap: int, arr_caps) -> tuple:
-        """Per-LEVEL join output caps (the PROFILE_r5 §4 big-tick lever).
+        """Per-LEVEL join output caps (the big-tick lever).
 
         A uniform (join_out,) × levels cap pays K × join_out concat/sort
         width per probe even though the small levels hold a ratio^k-th of
@@ -426,8 +426,8 @@ class FusedCompiler:
         """Concat partials, O(n)-compact live rows, sort small, THEN shrink.
 
         The concatenation of K per-level join outputs is mostly padding;
-        sorting it at full width was the mid-cap sort tail of the r5 profile
-        (PROFILE_r5.md §3). `compact_to` moves the live rows into one small
+        sorting it at full width was the mid-cap sort tail of the r5 profile.
+        `compact_to` moves the live rows into one small
         buffer with a cumsum+scatter (no sort), so the canonicalizing sort
         runs at 2×out_cap instead of K× that. The 2× headroom exists because
         raw live rows are a MULTISET count: +/- pairs and duplicate rows from

@@ -21,13 +21,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import registry
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - tpu platform deregistered pre-import
-    pl = None
 
 
 def _group_by_dtype(cols: tuple) -> list[tuple]:
@@ -64,7 +60,7 @@ def _pallas_multi_take(cols: tuple, idx: jnp.ndarray) -> tuple:
         return ()
     m = int(idx.shape[0])
     n = int(cols[0].shape[0])
-    if pl is None or m == 0 or n == 0:
+    if m == 0 or n == 0:
         return _xla_multi_take(cols, idx)
     idx2 = idx.astype(jnp.int32).reshape(1, m)
     out: list = [None] * len(cols)

@@ -19,13 +19,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import registry
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - tpu platform deregistered pre-import
-    pl = None
 
 
 def _pred(a_elem: jnp.ndarray, q: jnp.ndarray, side: str) -> jnp.ndarray:
@@ -68,7 +64,7 @@ def _xla_searchsorted2(a_hi, a_lo, q_hi, q_lo, side: str = "left"):
 
 def _pallas_searchsorted(a: jnp.ndarray, q: jnp.ndarray, side: str = "left"):
     n = int(a.shape[0])
-    if pl is None or n == 0 or q.ndim != 1 or int(q.shape[0]) == 0:
+    if n == 0 or q.ndim != 1 or int(q.shape[0]) == 0:
         return _xla_searchsorted(a, q, side)
     m = int(q.shape[0])
 
@@ -96,7 +92,7 @@ def _pallas_searchsorted(a: jnp.ndarray, q: jnp.ndarray, side: str = "left"):
 
 def _pallas_searchsorted2(a_hi, a_lo, q_hi, q_lo, side: str = "left"):
     n = int(a_hi.shape[0])
-    if pl is None or n == 0 or q_hi.ndim != 1 or int(q_hi.shape[0]) == 0:
+    if n == 0 or q_hi.ndim != 1 or int(q_hi.shape[0]) == 0:
         return _xla_searchsorted2(a_hi, a_lo, q_hi, q_lo, side)
     m = int(q_hi.shape[0])
 

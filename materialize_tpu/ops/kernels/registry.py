@@ -8,9 +8,11 @@ through :func:`dispatch`, which resolves the active backend and bumps the
 which backend actually served each trace.
 
 **Backend selection.** The ``kernel_backend`` dyncfg has three modes:
-``auto`` (Pallas iff the default JAX backend is a TPU, XLA everywhere else),
-and the ``xla`` / ``pallas`` force modes for bisection. The mode is a
-process-global set by :func:`set_kernel_backend` (ALTER SYSTEM SET on the
+``auto`` (XLA on every platform — see :func:`resolve_backend` for the rule
+and its reason) and the ``xla`` / ``pallas`` force modes for bisection. A
+forced ``pallas`` on a TPU compiles the Pallas programs for the chip and
+raises whatever the chip's compiler raises; nothing catches it. The mode is
+a process-global set by :func:`set_kernel_backend` (ALTER SYSTEM SET on the
 coordinator; CreateInstance config on clusterd).
 
 **jit-boundary discipline.** Dispatch happens at TRACE time — a module-global
@@ -30,7 +32,8 @@ would reassociate floating-point falls back to the XLA implementation.
 
 **Interpret mode.** Off-TPU, Pallas kernels run under ``interpret=True``
 (pure XLA emulation of the kernel program) — that is what lets tier-1 prove
-bit-identity on CPU. The flag is decided in ONE place, :func:`pallas_interpret`,
+bit-identity on CPU. It proves nothing about whether the chip's compiler
+accepts a program; tests/test_chip_compile.py asks that. The flag is decided in ONE place, :func:`pallas_interpret`,
 and the kernel-dispatch-coherence lint pass enforces that every
 ``pallas_call`` site takes ``interpret=pallas_interpret()`` (never a bare
 constant) and lives inside ``ops/kernels/``.
@@ -87,7 +90,15 @@ def resolve_backend(mode: str | None = None) -> str:
     """Resolve a mode ('auto' included) to a concrete backend name."""
     m = _mode if mode is None else mode
     if m == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        # Rule: `auto` is the XLA lowering on every platform. None of the six
+        # registered Pallas programs compiles for a TPU v5e (Mosaic refuses
+        # run_sum / bucket_rank's unaligned lane shifts and i64 operands,
+        # probe / probe2's 1-D gather, multi_take's take shapes, route_dest's
+        # `%`), and none has a grid, so a whole column would have to sit in
+        # fast memory. tests/test_chip_compile.py holds one strict xfail per
+        # program; the PR that makes one compile flips its case and may then
+        # widen this rule by what it can observe (kernel, dtype, capacity).
+        return "xla"
     return m
 
 

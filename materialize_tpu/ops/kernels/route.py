@@ -22,13 +22,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import registry
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - tpu platform deregistered pre-import
-    pl = None
 
 
 # -- route_dest --------------------------------------------------------------
@@ -44,7 +40,7 @@ def _xla_route_dest(hashes: jnp.ndarray, n_dest: int) -> jnp.ndarray:
 
 def _pallas_route_dest(hashes: jnp.ndarray, n_dest: int) -> jnp.ndarray:
     n = int(hashes.shape[0])
-    if pl is None or n == 0 or hashes.ndim != 1:
+    if n == 0 or hashes.ndim != 1:
         return _xla_route_dest(hashes, n_dest)
     h = hashes.reshape(1, n)
     nd = int(n_dest)  # static python scalar — pallas kernels can't capture arrays
@@ -76,7 +72,7 @@ def _xla_bucket_rank(key_s: jnp.ndarray) -> jnp.ndarray:
 
 def _pallas_bucket_rank(key_s: jnp.ndarray) -> jnp.ndarray:
     n = int(key_s.shape[0])
-    if pl is None or n == 0 or key_s.ndim != 1:
+    if n == 0 or key_s.ndim != 1:
         return _xla_bucket_rank(key_s)
     k = key_s.reshape(1, n)
 

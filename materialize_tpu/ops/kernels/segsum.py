@@ -30,13 +30,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import registry
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - tpu platform deregistered pre-import
-    pl = None
 
 
 def _xla_run_sum(run_start: jnp.ndarray, cols: tuple) -> tuple:
@@ -54,7 +50,7 @@ def _pallas_run_sum(run_start: jnp.ndarray, cols: tuple) -> tuple:
     n = int(run_start.shape[0])
     if not cols:
         return ()
-    if pl is None or n == 0 or any(
+    if n == 0 or any(
         jnp.issubdtype(c.dtype, jnp.floating) for c in cols
     ):
         # float sums would reassociate under the scan — keep the oracle

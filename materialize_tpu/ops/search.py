@@ -19,7 +19,7 @@ the active backend in their cache key (ops entry points thread a static
 
 `sort_perm` is the 32-bit `jnp.lexsort`: under x64, jnp's argsort/lexsort
 carry an i64 iota operand through the sort — a 64-bit operand the TPU splits
-into u32 pairs. `sort_perm` threads an explicit i32 iota instead, so compiled
+into u32 pairs. `sort_perm` threads explicit i32 iotas instead, so compiled
 ticks contain no 64-bit sort operands at all.
 """
 
@@ -61,15 +61,24 @@ def sort_perm(cols) -> jnp.ndarray:
     """`jnp.lexsort(cols)` with an i32 iota: last column is the primary key.
 
     Returns the i32 permutation that stably sorts by (cols[-1], …, cols[0]).
-    Implemented as ONE stable lax.sort over all key columns plus an explicit
-    i32 iota payload — no 64-bit operand enters the sort.
+    Implemented as one least-significant-first pass per key column, each a
+    `lax.sort` over (key, position) with the running permutation as payload:
+    position as the second key makes the pass stable without the stable-sort
+    expansion. That shape is for the TPU compiler, which emits a bitonic
+    network per sort and whose compile time grows steeply with the operand
+    and key count: ONE stable 3-key + iota sort cost 60-100 s of compile at
+    any n >= 2^16, this chain about a quarter of that (chip compiler, PR 25;
+    tests/test_chip_compile.py compiles it at 2^22). The permutation is the
+    unique stable lexsort either way. No 64-bit operand enters a sort unless
+    a key column is itself 64-bit.
     """
     cols = [
         c.astype(jnp.int8) if c.dtype == jnp.bool_ else c
         for c in (jnp.asarray(x) for x in cols)
     ]
-    n = cols[0].shape[0]
-    iota = lax.iota(jnp.int32, int(n))
-    keys = list(reversed(cols))  # lax.sort: first operand is primary
-    out = lax.sort(tuple(keys) + (iota,), num_keys=len(keys), is_stable=True)
-    return out[-1]
+    iota = lax.iota(jnp.int32, int(cols[0].shape[0]))
+    perm = iota
+    for i, key in enumerate(cols):  # least significant first
+        k = key if i == 0 else key[perm]
+        _, _, perm = lax.sort((k, iota, perm), num_keys=2, is_stable=False)
+    return perm

@@ -61,8 +61,10 @@ def test_registry_registers_every_kernel():
 def test_mode_validation_and_resolution():
     with pytest.raises(ValueError):
         kernels.set_kernel_backend("cuda")
-    # on the CPU test runner, auto resolves to xla
+    # auto is xla on every platform, by rule (registry.resolve_backend): no
+    # registered pallas program compiles for the chip (test_chip_compile.py)
     assert kernels.resolve_backend("auto") == "xla"
+    assert kernels.resolve_backend("xla") == "xla"
     assert kernels.resolve_backend("pallas") == "pallas"
     kernels.set_kernel_backend("pallas")
     assert kernels.kernel_backend_mode() == "pallas"

@@ -78,7 +78,7 @@ def test_rollback_install_is_exact_undo():
     assert snap() == before
 
 
-# -- per-level join output caps (PROFILE_r5 §4 lever) -------------------------
+# -- per-level join output caps ---------------------------------------------
 
 
 def test_join_caps_taper_and_provable_bound():
