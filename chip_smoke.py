@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
-    python chip_smoke.py [--sf 1]      # one TPU chip: phases 1-3 below
-    python chip_smoke.py --chips 4     # the device-mesh phase only (SF 0.1)
+    python chip_smoke.py [--sf 1]            # one TPU chip: phases 1-2 below
+    python chip_smoke.py --fused --sf 0.01   # ... and phase 3 after them
+    python chip_smoke.py --chips 4           # the device-mesh phase only (SF0.001)
 
 One process owns the chip: the frontends start in-process with the calls
 `python -m materialize_tpu serve` makes (Coordinator, serve, serve_pgwire on
@@ -17,9 +18,14 @@ port 0) and this script talks to them over real sockets.
               diffs must equal models.tpch.q3_oracle recomputed on the host
               from the generator's stores, exactly; the view's state must live
               on the TPU.
-  3. fused    ALTER SYSTEM SET enable_fused_render = true, the same view as
-              q3_fused (one XLA program per tick), two more ticks; q3_fused,
-              q3 and the oracle must agree row for row after each.
+  3. fused    (only with --fused) ALTER SYSTEM SET enable_fused_render = true,
+              the same view as q3_fused (one XLA program per tick), two more
+              ticks; q3_fused, q3 and the oracle must agree row for row after
+              each. Behind an option because the one-program tick takes
+              about five minutes to compile for the chip even at SF0.01, on
+              top of phases 1-2, and its capacities, all scaled by the
+              snapshot size, do not fit one chip at SF1 (PERF.md, PR 25):
+              give it --sf 0.01.
 
 Every line printed is one JSON object; the last is
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}.
@@ -93,7 +99,7 @@ class Compiles:
     def snapshot(self) -> tuple:
         return (self.programs, self.cache_hits, self.seconds)
 
-    def since(self, snap: tuple) -> dict:
+    def since(self, snap: tuple = (0, 0, 0.0)) -> dict:
         p, h, s = self.programs - snap[0], self.cache_hits - snap[1], self.seconds - snap[2]
         return {"programs": p, "cache_hits": h, "compiled": p - h, "compile_seconds": round(s, 3)}
 
@@ -287,11 +293,6 @@ class Served:
 def _q3_key(lk, rev, od, sp) -> tuple:
     """One served Q3 row (text or JSON values) -> the oracle's
     ((l_orderkey, o_orderdate day number, o_shippriority), revenue * 10^4)."""
-    from materialize_tpu.storage.generator import date_num
-
-    if isinstance(od, str) and "-" in od:
-        y, m, d = (int(x) for x in od.split("-"))
-        od = int(date_num(y, m, d))
     scaled = Decimal(str(rev)) * 10_000
     check(scaled == scaled.to_integral_value(), f"revenue {rev!r} is not scale-4")
     return (int(lk), int(od), int(sp)), int(scaled)
@@ -429,8 +430,8 @@ def phase_fused(served: Served, sf: float, device, compiles: Compiles, subs: dic
     emit(view="q3_fused", **assert_state_on(served, "q3_fused", device))
 
 
-def run_one_chip(sf: float, device, compiles: Compiles, pin_host_exchange: bool) -> None:
-    """Phases 2 and 3 on one device."""
+def run_one_chip(sf: float, device, compiles: Compiles, pin_host_exchange: bool, fused: bool) -> None:
+    """Phase 2, and phase 3 when asked, on one device."""
     served = Served()
     if pin_host_exchange:
         # several devices visible and no --chips: exchange_backend = auto
@@ -438,7 +439,8 @@ def run_one_chip(sf: float, device, compiles: Compiles, pin_host_exchange: bool)
         served.sql.query("ALTER SYSTEM SET exchange_backend = host")
         emit(pinned_to_first_device=True, device=str(device))
     subs, tick = phase_serve(served, sf, device, compiles)
-    phase_fused(served, sf, device, compiles, subs, tick)
+    if fused:
+        phase_fused(served, sf, device, compiles, subs, tick)
     for sub in subs.values():
         sub.close()
     served.close()
@@ -480,9 +482,9 @@ def run_mesh(sf: float, devices, compiles: Compiles, mesh=None) -> None:
     df = served.dataflow("q3_mesh")
     check(isinstance(df, FusedDataflow), "q3_mesh fell back to the host render")
     check(df.n_shards == n, f"n_shards {df.n_shards} != {n} devices")
-    members = [r for r in sql.query("SELECT device, platform, in_mesh FROM mz_device_mesh")]
+    members = sql.query("SELECT device, platform, in_mesh FROM mz_device_mesh")
     emit(mz_device_mesh=members)
-    in_mesh = [r for r in members if r[2] in ("t", "true", "True")]
+    in_mesh = [r for r in members if r[2] == "t"]
     check(len(in_mesh) == n, members)
     check(all(r[1] == devices[0].platform for r in in_mesh), members)
     state_devices = set()
@@ -513,7 +515,7 @@ def run_mesh(sf: float, devices, compiles: Compiles, mesh=None) -> None:
     served.close()
 
 
-def phase_device(compiles_wanted: bool = True):
+def phase_device():
     """Phase 1. Returns (devices, Compiles)."""
     import jax
 
@@ -544,7 +546,9 @@ def phase_device(compiles_wanted: bool = True):
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--sf", type=float, default=None,
-                    help="TPC-H scale factor (default 1; 0.1 with --chips)")
+                    help="TPC-H scale factor (default 1; 0.001 with --chips)")
+    ap.add_argument("--fused", action="store_true",
+                    help="also run phase 3, the fused render (use with --sf 0.01)")
     ap.add_argument("--chips", type=int, default=1,
                     help="with N > 1: run only the device-mesh phase over N chips")
     args = ap.parse_args()
@@ -553,15 +557,15 @@ def main() -> None:
     devices, compiles = phase_device()
     if args.chips > 1:
         check(len(devices) == args.chips, f"--chips {args.chips} but {len(devices)} devices")
-        run_mesh(args.sf if args.sf is not None else 0.1, devices, compiles)
+        run_mesh(args.sf if args.sf is not None else 0.001, devices, compiles)
         used = devices
     else:
         run_one_chip(
             args.sf if args.sf is not None else 1.0,
-            devices[0], compiles, pin_host_exchange=len(devices) > 1,
+            devices[0], compiles, pin_host_exchange=len(devices) > 1, fused=args.fused,
         )
         used = devices[:1]
-    emit(total_seconds=round(time.perf_counter() - t0, 3), **compiles.since((0, 0, 0.0)))
+    emit(total_seconds=round(time.perf_counter() - t0, 3), **compiles.since())
     d0 = used[0]
     print(json.dumps({"ok": True, "device": {
         "platform": d0.platform, "kind": d0.device_kind, "count": len(used)}}), flush=True)
