@@ -161,7 +161,7 @@ class Coordinator:
         preflight: bool = False, mesh=None,
     ) -> None:
         # with `mesh`, fused dataflows run shard_map-sharded over its
-        # `workers` axis (multi-worker SQL execution; parallel/exchange.py)
+        # `workers` axis (multi-worker SQL execution; parallel/devicemesh/exchange.py)
         self.mesh = mesh
         self.catalog = Catalog()
         self.oracle = TimestampOracle()

@@ -464,7 +464,7 @@ class FusedCompiler:
         are co-located before probing/inserting sharded arrangements."""
         if self.axis_name is None:
             return keyed
-        from ..parallel.exchange import exchange
+        from ..parallel.devicemesh import exchange
 
         bucket = self.caps.bucket or self.caps.delta
         out, f = exchange(keyed, self.axis_name, self.n_shards, bucket)

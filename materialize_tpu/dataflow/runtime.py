@@ -45,7 +45,7 @@ class ShardContext:
     in render order, so identical rendering on every worker yields identical
     channel numbering — the deterministic-channel discipline of timely's
     exchange pact allocation. `exchange` is the network-boundary analogue of
-    parallel/exchange.py's device all_to_all: host-staged, hash-partitioned
+    parallel/devicemesh/exchange.py's device all_to_all: host-staged, hash-partitioned
     by the routing columns' values (parallel/netexchange.py), delivered over
     the epoch-fenced WorkerMesh.
     """

@@ -6,7 +6,7 @@ accumulable SUM reduce compile into a single program. Arrangements are
 LSM-leveled (arrangement/lsm.py) with a deterministic merge schedule, so a
 tick costs O(delta·log N), not O(N). On a mesh, arrangements are hash-sharded
 by their key over the `workers` axis and every key change is an `all_to_all`
-exchange (parallel/exchange.py) — the timely-worker config-5 shape
+exchange (parallel/devicemesh/exchange.py) — the timely-worker config-5 shape
 (BASELINE.md) with collectives riding ICI.
 
 All capacities are static (pytree state); overflow flags replace resizing.
@@ -35,7 +35,7 @@ from ..arrangement.spine import arrange_batch
 from ..expr import CallBinary, Column, Literal, MapFilterProject
 from ..ops.consolidate import compact_to, consolidate, merge_consolidate
 from ..ops.reduce import AggregateExpr, _contributions, _emit_output, consolidate_accums
-from ..parallel.exchange import exchange
+from ..parallel.devicemesh import exchange
 from ..repr.batch import UpdateBatch, bucket_cap
 from .tpch import BUILDING, Q3_DATE
 

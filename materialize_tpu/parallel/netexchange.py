@@ -1,6 +1,6 @@
 """Host-staged exchange routing: the network half of the exchange pacts.
 
-`parallel/exchange.py` shuffles rows between *devices* inside one process
+`parallel/devicemesh/exchange.py` shuffles rows between *devices* inside one process
 with a single `all_to_all` riding ICI. This module is the same pact at the
 *process* boundary (the reference's zero-copy TCP worker mesh,
 `src/cluster/src/communication.rs:100`): update batches are staged to host,
