@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
     python chip_smoke.py [--sf 1]            # one TPU chip: phases 1-2 below
-    python chip_smoke.py --fused --sf 0.01   # ... and phase 3 after them
+    python chip_smoke.py --fused --sf 0.001  # ... and phase 3 after them
     python chip_smoke.py --chips 4           # the device-mesh phase only (SF0.001)
 
 One process owns the chip: the frontends start in-process with the calls
@@ -25,7 +25,7 @@ port 0) and this script talks to them over real sockets.
               about five minutes to compile for the chip even at SF0.01, on
               top of phases 1-2, and its capacities, all scaled by the
               snapshot size, do not fit one chip at SF1 (PERF.md, PR 25):
-              give it --sf 0.01.
+              give it --sf 0.001 or 0.01.
 
 Every line printed is one JSON object; the last is
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}.
@@ -548,7 +548,7 @@ def main() -> None:
     ap.add_argument("--sf", type=float, default=None,
                     help="TPC-H scale factor (default 1; 0.001 with --chips)")
     ap.add_argument("--fused", action="store_true",
-                    help="also run phase 3, the fused render (use with --sf 0.01)")
+                    help="also run phase 3, the fused render (use with --sf 0.001 or 0.01)")
     ap.add_argument("--chips", type=int, default=1,
                     help="with N > 1: run only the device-mesh phase over N chips")
     args = ap.parse_args()
