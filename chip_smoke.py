@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
     python chip_smoke.py [--sf 1]            # one TPU chip: phases 1-2 below
-    python chip_smoke.py --fused --sf 0.001  # ... and phase 3 after them
+    python chip_smoke.py --fused             # ... and phase 3 after them (SF0.01)
     python chip_smoke.py --chips 4           # the device-mesh phase only (SF0.001)
 
 One process owns the chip: the frontends start in-process with the calls
@@ -21,11 +21,12 @@ port 0) and this script talks to them over real sockets.
   3. fused    (only with --fused) ALTER SYSTEM SET enable_fused_render = true,
               the same view as q3_fused (one XLA program per tick), two more
               ticks; q3_fused, q3 and the oracle must agree row for row after
-              each. Behind an option because the one-program tick takes
-              about five minutes to compile for the chip even at SF0.01, on
-              top of phases 1-2, and its capacities, all scaled by the
-              snapshot size, do not fit one chip at SF1 (PERF.md, PR 25):
-              give it --sf 0.001 or 0.01.
+              each. Behind an option, at SF0.01 unless --sf says otherwise,
+              because every capacity of the fused program scales with the
+              snapshot: at SF0.1 the chip's compiler refuses the tick
+              (RESOURCE_EXHAUSTED, 17.66G of 15.75G hbm; PERF.md, PR 25), and
+              at SF0.01 it takes about five minutes to compile on top of
+              phases 1-2.
 
 Every line printed is one JSON object; the last is
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}.
@@ -53,8 +54,6 @@ Q3_BODY = """
       AND l_shipdate > DATE '1995-03-15'
     GROUP BY l_orderkey, o_orderdate, o_shippriority"""
 
-
-SUBSCRIBE_DEPTH = 1 << 20
 
 
 class SmokeFailure(Exception):
@@ -243,12 +242,12 @@ def http_sql(port: int, sql: str) -> list:
 class Served:
     """Coordinator + both frontends, started as `cmd_serve` starts them."""
 
-    def __init__(self, **coord_kwargs):
+    def __init__(self):
         from materialize_tpu.adapter import Coordinator
         from materialize_tpu.frontend import serve
         from materialize_tpu.frontend.pgwire import serve_pgwire
 
-        self.coord = Coordinator(**coord_kwargs)
+        self.coord = Coordinator()
         self.httpd = serve(self.coord, host="127.0.0.1", port=0)
         self.lock = self.httpd.RequestHandlerClass.lock
         self.pg_srv, _thread = serve_pgwire(
@@ -386,11 +385,6 @@ def phase_serve(served: Served, sf: float, device, compiles: Compiles) -> tuple:
         sql.query("CREATE MATERIALIZED VIEW q3 AS" + Q3_BODY)
     check(not isinstance(served.dataflow("q3"), FusedDataflow), "q3 rendered fused under defaults")
     emit(view="q3", **assert_state_on(served, "q3", device))
-    # the one setting moved off its default: a SUBSCRIBE snapshot larger than
-    # subscribe_queue_depth (4096) is shed as it is published, whatever the
-    # client does, and Q3 holds more groups than that from SF0.1 up
-    sql.query(f"ALTER SYSTEM SET subscribe_queue_depth = {SUBSCRIBE_DEPTH}")
-    emit(setting="subscribe_queue_depth", value=SUBSCRIBE_DEPTH, default=4096)
     subs = {"q3": Subscriber(served.pg_port, "q3")}
     tick = 0
     for _ in range(3):
@@ -410,8 +404,10 @@ def phase_fused(served: Served, sf: float, device, compiles: Compiles, subs: dic
     emit(phase="fused", sf=sf, render="fused")
     sql.query("ALTER SYSTEM SET enable_fused_render = true")
     # q3 already exports shared traces of these inputs, and a fused plan that
-    # could import one yields to the host renderer (dataflow/fused.py); with
-    # sharing off the fused view arranges its own inputs on the device
+    # could import one yields to the host renderer (dataflow/fused.py; the
+    # render logs a warning when it does). With sharing off the fused view
+    # arranges its own inputs on the device: a cause stepped round here, not
+    # repaired (PERF.md, PR 25)
     sql.query("ALTER SYSTEM SET enable_arrangement_sharing = false")
     with Step(compiles, "hydrate", view="q3_fused"):
         sql.query("CREATE MATERIALIZED VIEW q3_fused AS" + Q3_BODY)
@@ -465,13 +461,13 @@ def shard_rows(df) -> dict:
     return out
 
 
-def run_mesh(sf: float, devices, compiles: Compiles, mesh=None) -> None:
+def run_mesh(sf: float, devices, compiles: Compiles) -> None:
     """The --chips phase: the fused Q3 view over a device mesh of all local
     devices, against the oracle and the same view on the host exchange plane."""
     from materialize_tpu.dataflow.fused import FusedDataflow
 
     n = len(devices)
-    served = Served(mesh=mesh) if mesh is not None else Served()
+    served = Served()
     sql = served.sql
     emit(phase="mesh", sf=sf, chips=n)
     sql.query("ALTER SYSTEM SET enable_fused_render = true")
@@ -498,7 +494,6 @@ def run_mesh(sf: float, devices, compiles: Compiles, mesh=None) -> None:
     host_df = served.dataflow("q3_host")
     check(isinstance(host_df, FusedDataflow) and host_df.n_shards == 1, "q3_host is not a one-shard fused view")
 
-    sql.query(f"ALTER SYSTEM SET subscribe_queue_depth = {SUBSCRIBE_DEPTH}")
     subs = {"q3_mesh": Subscriber(served.pg_port, "q3_mesh")}
     for tick in (1, 2):
         with Step(compiles, "tick", tick=tick, views=["q3_mesh", "q3_host"]):
@@ -546,9 +541,9 @@ def phase_device():
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--sf", type=float, default=None,
-                    help="TPC-H scale factor (default 1; 0.001 with --chips)")
+                    help="TPC-H scale factor (default 1; 0.01 with --fused; 0.001 with --chips)")
     ap.add_argument("--fused", action="store_true",
-                    help="also run phase 3, the fused render (use with --sf 0.001 or 0.01)")
+                    help="also run phase 3, the fused render")
     ap.add_argument("--chips", type=int, default=1,
                     help="with N > 1: run only the device-mesh phase over N chips")
     args = ap.parse_args()
@@ -561,7 +556,7 @@ def main() -> None:
         used = devices
     else:
         run_one_chip(
-            args.sf if args.sf is not None else 1.0,
+            args.sf if args.sf is not None else (0.01 if args.fused else 1.0),
             devices[0], compiles, pin_host_exchange=len(devices) > 1, fused=args.fused,
         )
         used = devices[:1]

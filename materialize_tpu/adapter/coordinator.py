@@ -533,7 +533,11 @@ class Coordinator:
         if sub.channel.frontier <= as_of:
             sub.channel.frontier = as_of + 1
         if updates or stmt.progress:
-            sub.publish(updates, progress_ts=(as_of + 1) if stmt.progress else None)
+            sub.publish(
+                updates,
+                progress_ts=(as_of + 1) if stmt.progress else None,
+                snapshot=True,
+            )
         self.subscriptions[sub_id] = sub
         out = ExecResult("subscribe", status=sub_id, columns=sub.columns)
         out.subscription = sub

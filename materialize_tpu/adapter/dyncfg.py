@@ -198,9 +198,11 @@ PEEK_QUEUE_DEPTH = Config(
 SUBSCRIBE_QUEUE_DEPTH = Config(
     "subscribe_queue_depth",
     4096,
-    "updates a SUBSCRIBE's egress queue may buffer before the slow client "
-    "is shed with 53400 (SubscriptionOverflow) and the subscription torn "
-    "down — bounds how much history one stalled reader can pin (0 = off)",
+    "updates since it subscribed that a SUBSCRIBE's egress queue may buffer "
+    "before the slow client is shed with 53400 (SubscriptionOverflow) and "
+    "the subscription torn down — bounds how much history one stalled "
+    "reader can pin; the subscriber's own snapshot is delivered whatever "
+    "its size and is not counted (0 = off)",
 )
 MAX_SUBSCRIPTIONS_PER_USER = Config(
     "max_subscriptions_per_user",

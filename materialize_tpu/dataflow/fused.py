@@ -750,7 +750,7 @@ class FusedDataflow:
         self.n_shards = int(mesh.shape[axis_name]) if mesh is not None else 1
         self._scale = 1
         self._build()
-        self.state = self._tiled_template()
+        self.state = self._on_mesh(self._tiled_template())
         self.index_traces: dict[str, Arrangement] = {}
         self.index_errs: dict[str, Arrangement] = {}
         for idx_id, (obj_id, key_cols) in desc.index_exports.items():
@@ -873,6 +873,19 @@ class FusedDataflow:
             lambda x: jnp.concatenate([x] * n, axis=0), tmpl
         )
 
+    def _on_mesh(self, state: dict) -> dict:
+        """State the host built, placed as a tick's output is placed: sharded
+        on axis 0 over the mesh. The sharding is part of an array's type, so
+        without this the hydration call (host-placed state) and every later
+        call (the previous tick's output) trace and compile the whole tick
+        program twice."""
+        if self.mesh is None:
+            return state
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        return jax.device_put(state, NamedSharding(self.mesh, P(self.axis_name)))
+
     def ensure_delta_capacity(self, n_rows: int) -> None:
         """Grow capacities (and recompile + migrate state) until a tick of
         `n_rows` input rows fits. Used for bulk hydration ticks and oversized
@@ -903,7 +916,7 @@ class FusedDataflow:
                 for have, want in zip(cur.levels, t.levels)
             )
             new_state[path] = type(t)(new_levels)
-        self.state = new_state
+        self.state = self._on_mesh(new_state)
 
     def _delta_cap(self) -> int:
         """GLOBAL per-source delta capacity (n_shards × the per-shard cap)."""

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..arrangement.spine import Arrangement, arrange_batch
+from ..obs import log as obs_log
 from ..ops.consolidate import consolidate
 from ..ops.join import join_against
 from ..ops.reduce import AccumState, accumulable_step, agg_out_dtype
@@ -31,6 +32,8 @@ from ..ops.topk import negate as negate_batch
 from ..ops.topk import topk_step
 from ..repr.batch import UpdateBatch, bucket_cap
 from . import plan as lir
+
+_log = obs_log.get_logger("render")
 
 ERR_DTYPES = (np.dtype(np.int64),)
 
@@ -2074,8 +2077,14 @@ def render_dataflow(
             if snap_rows:
                 df.ensure_delta_capacity(int(snap_rows))
             return df
-        except FusedUnsupported:
-            pass
+        except FusedUnsupported as e:
+            # the fused render was asked for and is not what the view gets:
+            # say so, once per view, where an operator reads it
+            _log.warn(
+                "fused render unsupported for this plan; rendering on the host",
+                reason=str(e),
+                objects=[bd.id for bd in desc.objects_to_build],
+            )
     return Dataflow(
         desc,
         traces=traces,

@@ -9,13 +9,16 @@ each `Subscription` is a cursor into that ring. A frontend (pgwire COPY out,
 HTTP NDJSON/poll, or the serve/ reactor) drains the cursor WITHOUT holding
 the coordinator lock; slow readers hold a cursor position, not a queue copy.
 
-Backpressure contract (unchanged from the bounded-queue era): a consumer
-whose pending backlog exceeds `subscribe_queue_depth` messages — or whose
-cursor falls off the ring's `fanout_ring_ticks` retention window — is
-*shed*: the subscription flips to `shed` and the next drain raises
+Backpressure contract: a consumer whose pending backlog of *updates since
+it subscribed* exceeds `subscribe_queue_depth` messages — or whose cursor
+falls off the ring's `fanout_ring_ticks` retention window — is *shed*: the
+subscription flips to `shed` and the next drain raises
 `SubscriptionOverflow` (SQLSTATE 53400), rather than letting one stalled
 client pin unbounded history (the overload-protection stance of
-adapter/overload.py, applied to egress).
+adapter/overload.py, applied to egress). The subscriber's own snapshot
+preamble is not part of that backlog: its size is the view's, fixed when the
+SUBSCRIBE is accepted, and says nothing about how fast the client reads — a
+view with more rows than the depth must still be subscribable.
 
 Threading: producer is the coordinator (under the global command lock),
 consumers are frontend threads / the reactor (explicitly NOT under it).
@@ -99,11 +102,13 @@ class Subscription:
         self.state = "active"
         self.delivered = 0  # messages handed to the consumer
         self.shed_count = 0
-        # private preamble: (FrameEntry, deliver_progress) pairs owned by
-        # THIS subscriber — snapshot rows and compat `publish()` entries
+        # private preamble: (FrameEntry, deliver_progress, snapshot) triples
+        # owned by THIS subscriber — snapshot rows and compat `publish()`
+        # entries
         self._private: deque = deque()
         self._poff = 0  # updates consumed in the head private entry
         self._priv_pending = 0  # undelivered private messages
+        self._snap_pending = 0  # ... of which snapshot rows (not backlog)
         self._shed_reason: str | None = None
         # shared-ring cursor: next entry seq + updates consumed within it
         self.channel = channel
@@ -123,18 +128,27 @@ class Subscription:
         self._frontier = int(v)
 
     # -- producer side (coordinator tick, holds the command lock) -------------
-    def publish(self, updates: list, progress_ts: int | None = None) -> bool:
+    def publish(
+        self, updates: list, progress_ts: int | None = None, snapshot: bool = False
+    ) -> bool:
         """Enqueue one tick's decoded updates `[(ts, diff, row)]` (plus an
         optional progress marker) into the PRIVATE preamble. Returns False
         when the subscription is no longer active — the caller should tear
-        it down. Shared-ring ticks arrive via the channel instead."""
+        it down. Shared-ring ticks arrive via the channel instead.
+
+        `snapshot=True` marks the subscriber's own snapshot: it is delivered
+        whatever its size and never counts against `max_depth`."""
         with self._cv:
             if self.state != "active":
                 return False
             n = len(updates) + (1 if progress_ts is not None else 0)
             if n == 0:
                 return True
-            if self.max_depth > 0 and self._depth_locked() + n > self.max_depth:
+            if (
+                not snapshot
+                and self.max_depth > 0
+                and self._backlog_locked() + n > self.max_depth
+            ):
                 self._shed_locked()
                 return False
             msgs = tuple((int(ts), False, int(d), row) for ts, d, row in updates)
@@ -144,8 +158,10 @@ class Subscription:
             )
             # private entries deliver their progress marker unconditionally:
             # the publisher asked for it explicitly
-            self._private.append((entry, progress_ts is not None))
+            self._private.append((entry, progress_ts is not None, snapshot))
             self._priv_pending += n
+            if snapshot:
+                self._snap_pending += n
             if n:
                 _UPDATES.inc(len(updates))
                 self._cv.notify_all()
@@ -171,14 +187,16 @@ class Subscription:
                     "(fanout_ring_ticks)"
                 )
                 return False, 0
-            if self.max_depth > 0 and self._depth_locked() > self.max_depth:
+            if self.max_depth > 0 and self._backlog_locked() > self.max_depth:
                 self._shed_locked()
                 return False, 0
             before_u, before_p = ch.cum_before(self._seq)
             # positional consumption (counting progress markers whether or
-            # not this cursor delivers them) minus the private backlog: a
-            # pessimistic position, so head - floor always bounds depth
-            return True, before_u + self._off + before_p - self._priv_pending
+            # not this cursor delivers them) minus the counted private
+            # backlog: a pessimistic position, so head - floor always bounds
+            # depth
+            backlog = self._priv_pending - self._snap_pending
+            return True, before_u + self._off + before_p - backlog
 
     def close(self, state: str = "dropped") -> None:
         """Terminal transition (idempotent): wakes blocked consumers. The
@@ -293,6 +311,11 @@ class Subscription:
             return self._depth_locked()
 
     # -- internals (all hold self._cv; may take the channel mutex inside) -----
+    def _backlog_locked(self) -> int:
+        """What `max_depth` bounds: everything undelivered but the
+        subscriber's own snapshot."""
+        return self._depth_locked() - self._snap_pending
+
     def _depth_locked(self) -> int:
         depth = self._priv_pending
         ch = self.channel
@@ -310,6 +333,7 @@ class Subscription:
         self._shed_reason = reason
         self._private.clear()  # a shed client never sees a partial tick
         self._priv_pending = 0
+        self._snap_pending = 0
         self._poff = 0
         _SHEDS.inc()
         self._cv.notify_all()
@@ -319,18 +343,23 @@ class Subscription:
             return None
         # private preamble first: snapshot rows precede the shared ticks
         while self._private:
-            entry, deliver_progress = self._private[0]
+            entry, deliver_progress, snapshot = self._private[0]
             if self._poff < len(entry.updates):
                 msg = entry.updates[self._poff]
                 self._poff += 1
-                self._priv_pending -= 1
+                self._took_private_locked(1, snapshot)
                 return msg
             self._private.popleft()
             self._poff = 0
             if entry.progress_ts is not None and deliver_progress:
-                self._priv_pending -= 1
+                self._took_private_locked(1, snapshot)
                 return (int(entry.progress_ts), True, 0, None)
         return self._next_shared_locked()
+
+    def _took_private_locked(self, n: int, snapshot: bool) -> None:
+        self._priv_pending -= n
+        if snapshot:
+            self._snap_pending -= n
 
     def _next_shared_locked(self):
         ch = self.channel
@@ -361,13 +390,13 @@ class Subscription:
         if self.state == "shed":
             return None
         while self._private:
-            entry, deliver_progress = self._private[0]
+            entry, deliver_progress, snapshot = self._private[0]
             msgs = list(entry.updates[self._poff:])
             if entry.progress_ts is not None and deliver_progress:
                 msgs.append((int(entry.progress_ts), True, 0, None))
             self._private.popleft()
             self._poff = 0
-            self._priv_pending -= len(msgs)
+            self._took_private_locked(len(msgs), snapshot)
             if not msgs:
                 continue
             # per-subscriber encode (each snapshot is at its own as_of);
@@ -435,7 +464,7 @@ class Subscription:
                 1 if (entry.progress_ts is not None and self.progress) else 0
             )
             if n:
-                self._private.append((entry, self.progress))
+                self._private.append((entry, self.progress, False))
                 self._priv_pending += n
             seq, off = seq + 1, 0
         self._seq, self._off = seq, 0

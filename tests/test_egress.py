@@ -152,6 +152,46 @@ def test_subscription_queue_unit():
     assert not sub2.publish([(1, 1, (0,))])
 
 
+def test_subscription_snapshot_is_not_backlog():
+    """A snapshot larger than max_depth is delivered whole (both drain
+    shapes); the bound still holds for what is published after it."""
+    from materialize_tpu.egress import Subscription
+
+    snap = [(1, 1, (i,)) for i in range(10)]
+    sub = Subscription("s1", "g1", "mv", None, ("a",), max_depth=3)
+    assert sub.publish(snap, progress_ts=2, snapshot=True)
+    assert sub.state == "active" and sub.queue_depth() == 11  # all pending
+    # later publishes count, alone: 3 fit beside the undrained snapshot
+    assert sub.publish([(2, 1, (100 + i,)) for i in range(3)])
+    got = [sub.pop(timeout=0) for _ in range(4)]
+    assert got == [(1, False, 1, (i,)) for i in range(4)]
+    fr = sub.pop_frame("ndjson", timeout=0)  # the rest of the snapshot + marker
+    assert fr.count == 7 and sub.queue_depth() == 3
+    # draining the snapshot freed no depth: the 4th counted update sheds
+    assert not sub.publish([(3, 1, (0,))])
+    assert sub.state == "shed" and sub.queue_depth() == 0
+    with pytest.raises(SubscriptionOverflow):
+        sub.pop(timeout=0)
+
+
+def test_coordinator_subscribe_snapshot_larger_than_depth():
+    c = Coordinator()
+    c.execute("CREATE TABLE t (a int)")
+    c.execute("INSERT INTO t VALUES " + ", ".join(f"({i})" for i in range(12)))
+    c.execute("CREATE MATERIALIZED VIEW mv AS SELECT a FROM t")
+    c.configs.set("subscribe_queue_depth", 4)
+    out = c.execute("SUBSCRIBE mv WITH (PROGRESS)")
+    sub = out.subscription
+    assert sub.state == "active" and out.status in c.subscriptions
+    c.execute("INSERT INTO t VALUES (100)")  # one update behind: within depth
+    msgs = sub.drain()
+    rows = sorted(m[3][0] for m in msgs if not m[1])
+    assert rows == list(range(12)) + [100] and sub.state == "active"
+    for j in range(6):  # nobody drains: the backlog bound still sheds
+        c.execute(f"INSERT INTO t VALUES ({200 + j})")
+    assert sub.state == "shed" and out.status not in c.subscriptions
+
+
 def test_coordinator_sheds_slow_subscriber_53400():
     c = Coordinator()
     c.execute("CREATE TABLE t (a int)")
