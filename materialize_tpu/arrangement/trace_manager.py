@@ -149,14 +149,11 @@ class SharedReduceTrace:
         if tick <= self.frontier:
             return self.cached
         from ..ops.reduce import accumulable_step
-        from ..repr.batch import bucket_cap
 
         self.state, out, errs = accumulable_step(
             self.state, oks, self.key_cols, self.aggs, tick
         )
-        n = int(self.state.count())
-        if bucket_cap(n) < self.state.cap:
-            self.state = self.state.with_capacity(bucket_cap(n))
+        self.state = self.state.rebucketed()
         if out is not None:
             self.out_arr.insert(out)
         if errs is not None:

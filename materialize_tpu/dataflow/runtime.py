@@ -587,9 +587,7 @@ class ReduceNode(Node):
         self.state, out, agg_errs = accumulable_step(
             self.state, oks, self.key_cols, self.aggs, tick
         )
-        n = int(self.state.count())
-        if bucket_cap(n) < self.state.cap:
-            self.state = self.state.with_capacity(bucket_cap(n))
+        self.state = self.state.rebucketed()
         return out, _union([errs, agg_errs])
 
     def state_info(self):
@@ -885,6 +883,7 @@ class DistinctNode(Node):
         self.state, out, coll = threshold_step(
             self.state, projected, "distinct", tick
         )
+        self.state = self.state.rebucketed()
         return out, _union([errs, coll])
 
     def state_info(self):
@@ -903,6 +902,7 @@ class ThresholdNode(Node):
         if oks is None:
             return None if errs is None else (None, errs)
         self.state, out, coll = threshold_step(self.state, oks, "threshold", tick)
+        self.state = self.state.rebucketed()
         return out, _union([errs, coll])
 
     def state_info(self):
@@ -1188,7 +1188,7 @@ class LetRecNode(Node):
             return None if not errs_parts else (None, _union(errs_parts))
         self.started = True
 
-        acc_out = []
+        out = None
         deltas = dict(ext)
         for _it in range(self.max_iters):
             self.inner_time += 1
@@ -1207,7 +1207,11 @@ class LetRecNode(Node):
             body = results.get("__letrec_body__")
             if body is not None:
                 if body[0] is not None:
-                    acc_out.append(body[0])
+                    # folded in as it comes and held at the pow2 bucket of
+                    # its rows: one union at the end would have the summed
+                    # capacity, a shape that follows the iteration count
+                    out = _union([out, _retime(body[0], tick)])
+                    out = out.with_capacity(bucket_cap(int(out.count())))
                 if body[1] is not None and int(body[1].count()) > 0:
                     errs_parts.append(_retime(body[1], tick))
             if converged:
@@ -1216,7 +1220,6 @@ class LetRecNode(Node):
             raise RuntimeError(
                 f"WITH MUTUALLY RECURSIVE did not converge in {self.max_iters} iterations"
             )
-        out = _union([_retime(b, tick) for b in acc_out]) if acc_out else None
         errs = _union(errs_parts) if errs_parts else None
         if out is None and errs is None:
             return None

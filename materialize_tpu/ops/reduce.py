@@ -114,6 +114,14 @@ class AccumState:
             ext(self.nrows, 0),
         )
 
+    def rebucketed(self) -> "AccumState":
+        """A consolidated table at the pow2 bucket of its live rows.
+
+        A step leaves the table at cap(state) + cap(delta); held there, the
+        capacity (a shape, so a new program for every kernel that sees it)
+        follows the number of ticks instead of the number of groups."""
+        return self.with_capacity(bucket_cap(int(self.count())))
+
 
 @dataclass(frozen=True)
 class AggregateExpr:
@@ -508,7 +516,7 @@ def accumulable_step(
 
     Host driver around jitted kernels; Δout is consolidated (no-op pairs
     cancel). Rows whose aggregate input expression errors land in Δerrs.
-    Capacity of state grows as needed; callers rebucket occasionally.
+    The state comes back at cap(state) + cap(Δ); callers `rebucketed()` it.
     """
     raw_contrib, errs = _contributions(delta, key_cols, aggs)
     contrib = consolidate_accums(raw_contrib)

@@ -33,6 +33,7 @@ from materialize_tpu.frontend import serve
 from materialize_tpu.frontend.pgwire import serve_pgwire
 
 sys.path.insert(0, os.path.dirname(__file__))
+import tpch_q3  # noqa: E402
 from test_pgwire import MiniPgClient  # noqa: E402
 
 pytestmark = pytest.mark.egress
@@ -281,16 +282,6 @@ def test_drop_closes_dependent_subscriptions():
 
 # -- pgwire COPY streaming ----------------------------------------------------
 
-Q3_SQL = """CREATE MATERIALIZED VIEW q3 AS
-   SELECT l_orderkey, sum(l_extendedprice * (1 - l_discount)) AS revenue,
-          o_orderdate, o_shippriority
-   FROM customer, orders, lineitem
-   WHERE c_mktsegment = 'BUILDING' AND c_custkey = o_custkey
-     AND l_orderkey = o_orderkey AND o_orderdate < DATE '1995-03-15'
-     AND l_shipdate > DATE '1995-03-15'
-   GROUP BY l_orderkey, o_orderdate, o_shippriority"""
-
-
 def _row_text(row) -> tuple:
     """Render a decoded peek row the way _send_copy_row does."""
     out = []
@@ -314,11 +305,9 @@ def test_pgwire_subscribe_tpch_q3_end_to_end():
     try:
         cl = MiniPgClient(srv.getsockname()[1])
         cl.startup()
-        _rows, _c, tags, errs = cl.query(
-            "CREATE SOURCE tp FROM LOAD GENERATOR TPCH (SCALE FACTOR 0.001)"
-        )
+        _rows, _c, tags, errs = cl.query(tpch_q3.SOURCE_SQL)
         assert not errs
-        _rows, _c, tags, errs = cl.query(Q3_SQL)
+        _rows, _c, tags, errs = cl.query(tpch_q3.VIEW_SQL)
         assert not errs
         # subscribe before any churn: the snapshot is empty, every row of
         # the final state must arrive (and consolidate) through deltas

@@ -3,6 +3,7 @@
 import json
 import threading
 import time
+import tracemalloc
 import urllib.request
 
 import pytest
@@ -105,9 +106,13 @@ def test_prof_endpoints(server):
     stop.set()
     assert "samples over" in body
     assert ";" in body or "distinct stacks" in body
-    h1 = urllib.request.urlopen(f"{base}/prof/heap", timeout=30).read().decode()
-    assert "tracemalloc" in h1
-    coord.execute("CREATE TABLE ph (a int)")
-    coord.execute("INSERT INTO ph VALUES (1), (2)")
-    h2 = urllib.request.urlopen(f"{base}/prof/heap", timeout=30).read().decode()
-    assert "KiB" in h2
+    try:
+        h1 = urllib.request.urlopen(f"{base}/prof/heap", timeout=30).read().decode()
+        assert "tracemalloc" in h1
+        coord.execute("CREATE TABLE ph (a int)")
+        coord.execute("INSERT INTO ph VALUES (1), (2)")
+        h2 = urllib.request.urlopen(f"{base}/prof/heap", timeout=30).read().decode()
+        assert "KiB" in h2
+    finally:
+        # /prof/heap started it, and a server never stops it (conftest.py)
+        tracemalloc.stop()

@@ -276,21 +276,13 @@ def test_consolidate_forced_pallas_matches_xla():
 
 
 def _q3_rows(backend):
+    import tpch_q3
     from materialize_tpu.adapter import Coordinator
 
     c = Coordinator()
     c.execute(f"ALTER SYSTEM SET kernel_backend = {backend}")
-    c.execute("CREATE SOURCE tp FROM LOAD GENERATOR TPCH (SCALE FACTOR 0.001)")
-    c.execute(
-        """CREATE MATERIALIZED VIEW q3 AS
-           SELECT l_orderkey, sum(l_extendedprice * (1 - l_discount)) AS revenue,
-                  o_orderdate, o_shippriority
-           FROM customer, orders, lineitem
-           WHERE c_mktsegment = 'BUILDING' AND c_custkey = o_custkey
-             AND l_orderkey = o_orderkey AND o_orderdate < DATE '1995-03-15'
-             AND l_shipdate > DATE '1995-03-15'
-           GROUP BY l_orderkey, o_orderdate, o_shippriority"""
-    )
+    c.execute(tpch_q3.SOURCE_SQL)
+    c.execute(tpch_q3.VIEW_SQL)
     for _ in range(3):
         c.advance()
     rows = sorted(c.execute("SELECT * FROM q3").rows)

@@ -53,14 +53,22 @@ def test_advance_times():
     assert out.tolist() == [5, 5, 10]
 
 
-def test_native_is_fast(rng):
-    """Sanity: 200k rows consolidate in well under a second natively."""
-    import time
+def test_native_is_the_path_taken(rng, monkeypatch):
+    """200k rows go through the native kernel (the NumPy fallback is not
+    entered) and come out as the fallback's rows, bit for bit."""
+    from materialize_tpu.utils import native
 
-    cols = mkcols(rng, 200_000, ncols=3)
     if get_native() is None:
         pytest.skip("no compiler")
-    t0 = time.perf_counter()
-    consolidate_host(cols)
-    dt = time.perf_counter() - t0
-    assert dt < 2.0, f"native consolidation too slow: {dt:.2f}s"
+    cols = mkcols(rng, 200_000, ncols=3)
+    keys = sorted(k for k in cols if k not in ("times", "diffs"))
+    want = _consolidate_numpy({k: v.copy() for k, v in cols.items()}, keys)
+
+    def no_fallback(*_a, **_k):
+        raise AssertionError("consolidate_host fell back to NumPy")
+
+    monkeypatch.setattr(native, "_consolidate_numpy", no_fallback)
+    got = consolidate_host(cols)
+    assert sorted(got) == sorted(want)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k])

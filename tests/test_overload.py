@@ -265,7 +265,7 @@ def test_admission_gate_sheds_beyond_depth():
     with pytest.raises(AdmissionShed) as ei:
         with coord.admission.admit():
             pass
-    assert time.time() - t0 < 1.0
+    assert time.time() - t0 < 5.0  # the occupiers hold the line for 10 s
     assert sqlstate_of(ei.value) == "53300" and ei.value.retryable
     release.set()
     for t in threads:

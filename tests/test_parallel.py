@@ -113,9 +113,16 @@ def test_fused_q3_matches_oracle(n_shards, val_dtype):
     empty_l = UpdateBatch.empty(8 * n_shards, (), (np.dtype(val_dtype),) * 6)
 
     run(1, init["customer"], init["orders"], init["lineitem"])
+    # refreshes ride at the hydration tick's input capacities: one compile of
+    # the whole-tick program per case, not one per input shape
     for t in range(2, 5):
         ref = gen.refresh(t, frac=0.02)
-        run(t, empty_c, ref["orders"], ref["lineitem"])
+        run(
+            t,
+            pad_to(empty_c, init["customer"].cap),
+            pad_to(ref["orders"], init["orders"].cap),
+            pad_to(ref["lineitem"], init["lineitem"].cap),
+        )
 
     integrated = {k: v for k, v in out_acc.items() if v != 0}
     want = tpch.q3_oracle(

@@ -88,9 +88,10 @@ def test_mutual_recursion_two_bindings(coord):
     assert r.rows == [(0,), (2,), (4,), (6,), (8,), (10,)]
 
 
-def test_nonconvergent_raises(coord):
+def test_nonconvergent_raises(coord, programs_built):
     coord.execute("CREATE TABLE s (n int)")
     coord.execute("INSERT INTO s VALUES (1)")
+    before = programs_built()
     with pytest.raises(RuntimeError, match="converge"):
         coord.execute(
             """WITH MUTUALLY RECURSIVE
@@ -99,3 +100,34 @@ def test_nonconvergent_raises(coord):
                  )
                SELECT count(*) FROM grow"""
         )
+    # 100 iterations over a relation that grows to 100 rows: operator state
+    # moves through pow2 buckets, so programs grow with log(rows) (249 in a
+    # fresh process); with state sized by the iteration count it was ~950
+    assert programs_built() - before < 400
+
+
+_CLOSURE = """WITH MUTUALLY RECURSIVE
+     reach (src int, dst int) AS (
+       SELECT src, dst FROM edges
+       UNION
+       SELECT r.src, e.dst FROM reach r, edges e WHERE r.dst = e.src
+     )
+   SELECT src, dst FROM reach ORDER BY src, dst"""
+
+
+def _closure_of(edges):
+    c = Coordinator()
+    c.execute("CREATE TABLE edges (src int, dst int)")
+    c.execute("INSERT INTO edges VALUES " + ", ".join(map(str, edges)))
+    return c.execute(_CLOSURE).rows
+
+
+def test_second_run_over_changed_data_compiles_nothing(programs_built):
+    """Shapes follow capacity buckets, not the data: other values and another
+    number of iterations (3, then 2) reuse every program of the first run."""
+    assert len(_closure_of([(1, 2), (2, 3), (3, 4)])) == 6
+    before = programs_built()
+    assert _closure_of([(7, 5), (9, 7), (20, 21)]) == [
+        (7, 5), (9, 5), (9, 7), (20, 21),
+    ]
+    assert programs_built() == before

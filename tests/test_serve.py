@@ -476,6 +476,10 @@ def _run_pgwire_churn(backend) -> bytes:
         cl = RecordingPgClient(srv.getsockname()[1])
         cl.startup()
         _send_query(cl, "SUBSCRIBE mv WITH (PROGRESS)")
+        # the churn starts once the server has accepted the SUBSCRIBE: sent
+        # and not awaited, its as_of lands wherever the threads' timing puts
+        # it among the churn statements, and the two streams differ by chance
+        assert cl.read_message()[0] == b"H"  # CopyOutResponse
         for stmt in CHURN:
             with lock:
                 coord.execute(stmt)

@@ -298,20 +298,19 @@ def test_introspection_and_metrics_surface_sharing():
 @pytest.mark.smoke
 def test_shared_mv_scaling_smoke():
     """Installing 8 identical-source MVs on the shared path must cost
-    ~O(sources), not O(8 × sources): arrangement bytes stay near the 1-MV
-    footprint (deterministic), and the per-tick wall stays ≤ ~2× the 1-MV
-    tick (generous slack — CI wall clocks are noisy)."""
-    from benchmarks.bench_shared_mvs import arrangement_bytes, run_scenario
+    ~O(sources), not O(8 × sources): the 8 views hold the 1-MV run's traces
+    and no more (each is maintained once per tick, by the first reader to
+    step it — SharedTrace.offer), and arrangement bytes stay near the 1-MV
+    footprint."""
+    from benchmarks.bench_shared_mvs import run_scenario
 
     rows, ticks = 1000, 3
-    run_scenario(8, True, rows=rows, ticks=ticks)  # discarded: XLA compiles
     r1 = run_scenario(1, True, rows=rows, ticks=ticks)
     r8 = run_scenario(8, True, rows=rows, ticks=ticks)
-    assert r8["imports"] > 0, "the 8-MV run must actually share"
-    # the deterministic half of the claim: inputs are arranged ONCE
+    # inputs are arranged ONCE: views 2-8 import every trace view 1 exported
+    assert r8["exports"] == r1["exports"] > 0, (r1, r8)
+    assert r8["imports"] == 7 * r1["exports"], (r1, r8)
     assert r8["arrangement_bytes"] < 2.0 * r1["arrangement_bytes"], (
         r1["arrangement_bytes"],
         r8["arrangement_bytes"],
     )
-    wall_ratio = r8["tick_wall_s_median"] / r1["tick_wall_s_median"]
-    assert wall_ratio <= 2.75, f"8 shared MVs cost {wall_ratio:.2f}x the 1-MV tick"

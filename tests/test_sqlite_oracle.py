@@ -62,6 +62,7 @@ class Oracle:
         ddl = ", ".join(f"{c} {t}" for c, t in cols)
         self.mz.execute(f"CREATE TABLE {name} ({ddl})")
         self.db.execute(f"CREATE TABLE {name} ({ddl})")
+        rows = []
         for _ in range(nrows):
             vals = []
             for _c, t in cols:
@@ -72,9 +73,13 @@ class Oracle:
                 else:
                     s = self.pick(["ab", "Abc", "x", "yz", "aa", "", "b%c"])
                     vals.append(f"'{s}'")
-            stmt = f"INSERT INTO {name} VALUES ({', '.join(vals)})"
-            self.mz.execute(stmt)
-            self.db.execute(stmt)
+            rows.append(f"({', '.join(vals)})")
+        # one statement, one batch: a table loaded row by row is a spine of
+        # many small batches, whose summed capacity gives every query over it
+        # shapes (and programs) of its own
+        stmt = f"INSERT INTO {name} VALUES {', '.join(rows)}"
+        self.mz.execute(stmt)
+        self.db.execute(stmt)
 
     def churn(self):
         name = self.pick(list(self.tables))

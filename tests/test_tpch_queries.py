@@ -2,6 +2,7 @@
 
 import numpy as np
 import pytest
+import tpch_q3
 
 from materialize_tpu.adapter import Coordinator
 
@@ -9,7 +10,7 @@ from materialize_tpu.adapter import Coordinator
 @pytest.fixture
 def coord():
     c = Coordinator()
-    c.execute("CREATE SOURCE tp FROM LOAD GENERATOR TPCH (SCALE FACTOR 0.001)")
+    c.execute(tpch_q3.SOURCE_SQL)
     return c
 
 
