@@ -38,6 +38,11 @@ REQUIRED_FAMILIES = (
     # of the shared frame ring, so both legs must stay observable
     "mzt_egress_frames_encoded_total",
     "mzt_egress_frames_delivered_total",
+    # the spine's fixed-capacity head (arrangement/spine.py): merges say the
+    # mechanism engages, spills and bypasses say how often it cannot
+    "mzt_arrangement_head_merges_total",
+    "mzt_arrangement_head_spills_total",
+    "mzt_arrangement_head_bypass_total",
 )
 
 _BUMP = re.compile(r'(?:\.bump|\.record_max)\(\s*"([a-z_]+)"')

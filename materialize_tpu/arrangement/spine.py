@@ -8,20 +8,82 @@ consolidated, hash-sorted UpdateBatches of geometrically decreasing capacity.
 - batch merge   = concat + consolidate (one fused XLA program)
 - cursor lookup = vectorized binary search over the hash column [ops.join]
 
-Merge scheduling is driven by static capacities (powers of two), so merge
-decisions never need a host↔device sync; live counts are only read back when
-re-bucketing shrinks capacity after compaction.
+Merge scheduling is driven by static capacities (powers of two); the one
+host read an insert makes is the delta's live row count (below), and
+re-bucketing reads live counts back when it shrinks capacity.
+
+**The head.** Every batch capacity is a family of XLA programs (the merge,
+and every join that probes the batch), so a spine that grew each delta
+through d, 2d, 4d, ... asked for new programs at nearly every insert. The
+small end of that ladder is one batch of FIXED capacity instead: the head,
+always the last element of `batches`, so readers see one more batch.
+
+- A delta is sized by the rows it holds (one host read), not by the capacity
+  its producer left it at: an operator's output keeps the summed capacity of
+  its inputs (at TPC-H SF1 the Q3 view's delta is 32,768 rows wide for some
+  260 rows, its error delta 131,072 wide for none), and merging costs by
+  capacity. A delta with no rows is not inserted.
+- A delta whose rows fill bucket d, arriving at a headless, non-empty
+  arrangement, starts a head of capacity T = HEAD_RATIO x d (x 2d where
+  the delta's capacity is wider than its rows: an operator's next output
+  may hold somewhat more). Later deltas merge into it with ONE program,
+  `merge_consolidate` at (T, d) with the output held at T (a smaller delta
+  is padded to d; a larger one of bucket d' <= T/2 takes its own (T, d')
+  program).
+- Bound: the host keeps `head_bound`, the sum of the row counts merged into
+  the head since it was empty. A merge never creates rows, so the head's
+  live rows never exceed it, and while it stays <= T, truncating the merge's
+  T + d output rows to T drops only padding — no device read needed.
+- Spill: when the next delta would take `head_bound` past T, the head joins
+  the spine as it is, at capacity T (the join programs that probe it are the
+  head's own), and a new head starts from the delta. The geometric rule then
+  runs among spine batches only, whose smallest level is T.
+- What a head cannot help goes to the spine as before, after spilling the
+  head so order and `since` handling are unchanged: the first batch of an
+  arrangement (a hydration snapshot), a delta whose bucket exceeds T/2, and
+  a delta whose head would be larger than the spine it fronts (a bulk load;
+  this also keeps a head from more than doubling an arrangement's memory,
+  which is the envelope the geometric spine reaches by itself before a full
+  merge). The smallest head there is, HEAD_RATIO x MIN_CAP rows, is always
+  allowed, so a table filled row by row has one from its second insert.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as obs_metrics
 from ..ops.consolidate import advance_times, consolidate, merge_consolidate
-from ..repr.batch import UpdateBatch, bucket_cap, device_time_scalar
+from ..repr.batch import MIN_CAP, UpdateBatch, bucket_cap, device_time_scalar
 from ..repr.hashing import hash_columns
+
+# Head capacity over the bucket of the delta that starts it. On a TPU v5e the
+# (T, d) merge of a 16,384-row, 9-column delta takes 34 / 112 / 382 ms at
+# ratios 4 / 16 / 64 (PERF.md §6, PR 29): the price of a merge against how
+# often a head spills; 4 against 16 is PERF.md's open question 0e.
+HEAD_RATIO = 16
+
+_HEAD_MERGES = obs_metrics.REGISTRY.counter(
+    "mzt_arrangement_head_merges_total",
+    "deltas merged into an arrangement's fixed-capacity head batch",
+)
+_HEAD_SPILLS = obs_metrics.REGISTRY.counter(
+    "mzt_arrangement_head_spills_total",
+    "head batches appended to the spine (full, or displaced by a larger delta)",
+)
+_HEAD_BYPASS = obs_metrics.REGISTRY.counter(
+    "mzt_arrangement_head_bypass_total",
+    "deltas sent straight to the spine (first batch, bulk load, oversize)",
+)
+
+
+_live_rows = jax.jit(UpdateBatch.count)
+# pad, or cut (sound where the rows beyond are padding: arranged batches
+# keep their live rows in front), as one program per shape
+_resized = jax.jit(UpdateBatch.with_capacity, static_argnums=1)
 
 
 def arrange_batch(
@@ -58,18 +120,75 @@ class Arrangement:
     over live holds — releasing a hold (DROP of a reader) re-arms compaction
     up to the next-slowest reader. Private arrangements never register holds
     and keep the plain `compact` path.
+
+    `head_bound` > 0 says `batches[-1]` is the head (module docstring): it
+    is the sum of the row counts merged into it, an upper bound on its rows.
     """
 
     key_cols: tuple[int, ...]
     batches: list[UpdateBatch] = field(default_factory=list)
     since: int = 0  # logical compaction frontier
     holds: dict = field(default_factory=dict)  # reader id -> held since
+    head_bound: int = 0
+
+    @property
+    def head(self) -> UpdateBatch | None:
+        return self.batches[-1] if self.head_bound else None
 
     def insert(self, delta: UpdateBatch, already_keyed: bool = False) -> None:
-        """Add a delta batch (raw, keyed on the fly) and restore the merge invariant."""
+        """Add a delta batch (raw, keyed on the fly): into the head where one
+        can take it, else onto the spine, restoring the merge invariant."""
         b = delta if already_keyed else arrange_batch(delta, self.key_cols)
-        self.batches.append(b)
-        self._maintain()
+        # The one host read: a delta is sized by the rows it holds, not by
+        # the capacity its producer left it at (an operator's output keeps
+        # the summed capacity of its inputs: the view's own delta at SF1 is
+        # 32,768 rows wide for some 260 rows, its error delta 131,072 for
+        # none), and a delta with no rows is not inserted at all.
+        n = int(_live_rows(b))
+        if n == 0:
+            return
+        d = bucket_cap(n)
+        head = self.head
+        oversize = head is not None and 2 * d > head.cap
+        if head is not None and (oversize or self.head_bound + n > head.cap):
+            self._spill()
+            head = None
+        # a new head gets one bucket of slack where the producer's capacity
+        # allows it: an ingest batch is built at its bucket and keeps it, the
+        # next output of an operator may hold somewhat more rows than this
+        slot = min(2 * d, bucket_cap(b.cap))
+        room = max(self.total_cap(), HEAD_RATIO * MIN_CAP) if self.batches else 0
+        if head is None and not oversize and HEAD_RATIO * slot <= room:
+            # it starts empty and takes the delta through the same (T, d)
+            # program every later merge runs
+            head = UpdateBatch.empty(
+                HEAD_RATIO * slot,
+                tuple(k.dtype for k in b.keys),
+                tuple(v.dtype for v in b.vals),
+            )
+            self.batches.append(head)
+        if head is None:
+            _HEAD_BYPASS.inc()
+            self.batches.append(b)
+            self._maintain()
+            return
+        # one program per (T, d): the delta is cut or padded to its bucket
+        # (a smaller one to the head's own d), the output is held at T
+        self.batches[-1] = merge_consolidate(
+            head,
+            _resized(b, max(d, head.cap // HEAD_RATIO)),
+            since=device_time_scalar(self.since),
+            out_cap=head.cap,
+        )
+        self.head_bound += n
+        _HEAD_MERGES.inc()
+
+    def _spill(self) -> None:
+        """The head becomes a spine batch as it is, at its own capacity."""
+        if self.head_bound:
+            self.head_bound = 0
+            _HEAD_SPILLS.inc()
+            self._maintain()
 
     # -- reader-held compaction (shared-trace protocol) ---------------------
     def hold(self, reader: str, since: int) -> None:
@@ -101,6 +220,7 @@ class Arrangement:
     def _maintain(self) -> None:
         # Merge while the tail batch is at least half the size of its
         # predecessor (geometric spine, amortized O(log) merges per insert).
+        # Spine batches only: callers spill the head first.
         while len(self.batches) >= 2 and (
             self.batches[-1].cap * 2 >= self.batches[-2].cap
         ):
@@ -108,8 +228,14 @@ class Arrangement:
             a = self.batches.pop()
             # spine batches are consolidate outputs (canonical order), so the
             # O(n) searchsorted merge replaces the full re-sort
-            merged = merge_consolidate(a, b, since=device_time_scalar(self.since))
-            self.batches.append(merged.with_capacity(bucket_cap(a.cap + b.cap)))
+            self.batches.append(
+                merge_consolidate(
+                    a,
+                    b,
+                    since=device_time_scalar(self.since),
+                    out_cap=bucket_cap(a.cap + b.cap),
+                )
+            )
 
     def compact(self, since: int) -> None:
         """Advance the logical compaction frontier (AllowCompaction;
@@ -118,6 +244,7 @@ class Arrangement:
 
     def rebucket(self) -> None:
         """Shrink capacities to fit live counts (host sync; call occasionally)."""
+        self.head_bound = 0  # the head is one more batch to shrink
         new = []
         for b in self.batches:
             n = int(b.count())
@@ -197,6 +324,17 @@ class Arrangement:
 
     def total_cap(self) -> int:
         return sum(b.cap for b in self.batches)
+
+    def info_rows(self, name: str) -> list[tuple]:
+        """`state_info` rows (name, batches, capacity, records): the spine,
+        and the head as a row of its own so mz_arrangement_sizes shows it."""
+        spine = self.batches[:-1] if self.head_bound else self.batches
+        rows = [
+            (name, len(spine), sum(b.cap for b in spine), sum(int(b.count()) for b in spine))
+        ]
+        if self.head_bound:
+            rows.append((f"{name}:head", 1, self.head.cap, int(self.head.count())))
+        return rows
 
 
 def _host_value(v):

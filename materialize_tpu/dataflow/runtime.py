@@ -252,7 +252,7 @@ class ArrangeByNode(Node):
         self.arr.compact(since)
 
     def state_info(self):
-        return [("arrange_by", len(self.arr.batches), self.arr.total_cap(), self.arr.count())]
+        return self.arr.info_rows("arrange_by")
 
 
 def _shared_state_info(h) -> tuple:
@@ -283,6 +283,14 @@ def batch_nbytes(b) -> int:
 
 def arrangement_nbytes(arr) -> int:
     return sum(batch_nbytes(b) for b in arr.batches)
+
+
+def _arr_row_nbytes(arr) -> list:
+    """Bytes aligned with `Arrangement.info_rows`: the spine, then the head."""
+    if not arr.head_bound:
+        return [arrangement_nbytes(arr)]
+    head = batch_nbytes(arr.head)
+    return [arrangement_nbytes(arr) - head, head]
 
 
 def accum_state_nbytes(st) -> int:
@@ -434,11 +442,11 @@ class LinearJoinNode(Node):
         for i, (l, r) in enumerate(self.state):
             lh, rh = self.shared[i]
             if l is not None:
-                out.append((f"join_stage{i}_left", len(l.batches), l.total_cap(), l.count()))
+                out += l.info_rows(f"join_stage{i}_left")
             else:
                 out.append((f"join_stage{i}_left:{lh.name()}",) + _shared_state_info(lh))
             if r is not None:
-                out.append((f"join_stage{i}_right", len(r.batches), r.total_cap(), r.count()))
+                out += r.info_rows(f"join_stage{i}_right")
             else:
                 out.append((f"join_stage{i}_right:{rh.name()}",) + _shared_state_info(rh))
         return out
@@ -557,10 +565,9 @@ class DeltaJoinNode(Node):
             arr.compact(since)
 
     def state_info(self):
-        out = [
-            (f"delta_in{inp}_key{list(key)}", len(a.batches), a.total_cap(), a.count())
-            for (inp, key), a in self.arrs.items()
-        ]
+        out = []
+        for (inp, key), a in self.arrs.items():
+            out += a.info_rows(f"delta_in{inp}_key{list(key)}")
         for (inp, key), h in self.shared.items():
             out.append(
                 (f"delta_in{inp}_key{list(key)}:{h.name()}",)
@@ -929,9 +936,7 @@ class TopKNode(Node):
         self.arr.compact(since)
 
     def state_info(self):
-        return [
-            ("topk_input", len(self.arr.batches), self.arr.total_cap(), self.arr.count())
-        ]
+        return self.arr.info_rows("topk_input")
 
 
 class WindowNode(Node):
@@ -958,14 +963,7 @@ class WindowNode(Node):
         self.arr.compact(since)
 
     def state_info(self):
-        return [
-            (
-                "window_input",
-                len(self.arr.batches),
-                self.arr.total_cap(),
-                self.arr.count(),
-            )
-        ]
+        return self.arr.info_rows("window_input")
 
 
 class MonotonicTopKNode(Node):
@@ -1021,14 +1019,7 @@ class MonotonicTopKNode(Node):
         self.out_arr.compact(since)
 
     def state_info(self):
-        return [
-            (
-                "monotonic_topk_winners",
-                len(self.out_arr.batches),
-                self.out_arr.total_cap(),
-                self.out_arr.count(),
-            )
-        ]
+        return self.out_arr.info_rows("monotonic_topk_winners")
 
 
 class TemporalFilterNode(Node):
@@ -1329,17 +1320,17 @@ def _node_state_bytes(node, rows: list) -> list:
     _state_objects: owners charge their arrangements/accumulators, shared
     importers charge zero."""
     if isinstance(node, ArrangeByNode):
-        return [arrangement_nbytes(node.arr)]
+        return _arr_row_nbytes(node.arr)
     if isinstance(node, (SharedArrangeNode, SharedReduceNode)):
         return [_shared_handle_nbytes(node.h)]
     if isinstance(node, LinearJoinNode):
         out = []
         for (l, r), (lh, rh) in zip(node.state, node.shared):
-            out.append(arrangement_nbytes(l) if l is not None else _shared_handle_nbytes(lh))
-            out.append(arrangement_nbytes(r) if r is not None else _shared_handle_nbytes(rh))
+            out += _arr_row_nbytes(l) if l is not None else [_shared_handle_nbytes(lh)]
+            out += _arr_row_nbytes(r) if r is not None else [_shared_handle_nbytes(rh)]
         return out
     if isinstance(node, DeltaJoinNode):
-        return [arrangement_nbytes(a) for a in node.arrs.values()] + [
+        return [n for a in node.arrs.values() for n in _arr_row_nbytes(a)] + [
             _shared_handle_nbytes(h) for h in node.shared.values()
         ]
     if isinstance(node, (ReduceNode, FusedMfpReduceNode, DistinctNode, ThresholdNode)):
@@ -1349,9 +1340,9 @@ def _node_state_bytes(node, rows: list) -> list:
         # rendered-bytes row's record count IS its byte figure
         return [0] + [r[3] for r in rows[1:]]
     if isinstance(node, (WindowNode, TopKNode)):
-        return [arrangement_nbytes(node.arr)]
+        return _arr_row_nbytes(node.arr)
     if isinstance(node, MonotonicTopKNode):
-        return [arrangement_nbytes(node.out_arr)]
+        return _arr_row_nbytes(node.out_arr)
     if isinstance(node, TemporalFilterNode):
         return [0 if node.pending is None else batch_nbytes(node.pending)]
     if isinstance(node, LetRecNode):
@@ -1520,30 +1511,12 @@ class Dataflow:
                 nbytes = _node_state_bytes(node, rows)
                 for (name, nb, cap, rec), b in zip(rows, nbytes):
                     out.append((obj_id, op_i, name, nb, cap, int(rec), int(b)))
-        for idx_id, arr in self.index_traces.items():
-            out.append(
-                (
-                    idx_id,
-                    -1,
-                    "index_trace",
-                    len(arr.batches),
-                    arr.total_cap(),
-                    int(arr.count()),
-                    arrangement_nbytes(arr),
-                )
-            )
-        for idx_id, arr in self.index_errs.items():
-            out.append(
-                (
-                    idx_id,
-                    -1,
-                    "index_errs",
-                    len(arr.batches),
-                    arr.total_cap(),
-                    int(arr.count()),
-                    arrangement_nbytes(arr),
-                )
-            )
+        for name, traces in (
+            ("index_trace", self.index_traces), ("index_errs", self.index_errs)
+        ):
+            for idx_id, arr in traces.items():
+                for row, b in zip(arr.info_rows(name), _arr_row_nbytes(arr)):
+                    out.append((idx_id, -1) + row + (b,))
         return out
 
     # -- rendering ---------------------------------------------------------

@@ -177,9 +177,9 @@ def consolidate(batch: UpdateBatch, compact: bool = True) -> UpdateBatch:
     return _consolidate(batch, compact, kernels.active_backend())
 
 
-@partial(jax.jit, static_argnames=("backend",))
+@partial(jax.jit, static_argnames=("backend", "out_cap"))
 def _merge_consolidate(
-    a: UpdateBatch, b: UpdateBatch, since, backend: str
+    a: UpdateBatch, b: UpdateBatch, since, backend: str, out_cap: int | None = None
 ) -> UpdateBatch:
     with kernels.using_backend(backend):
         ka_hi, ka_lo = pack_sort_key(a)
@@ -197,11 +197,17 @@ def _merge_consolidate(
         cat = batch_permute(UpdateBatch.concat(a, b), perm)
         if since is not None:
             cat = advance_times(cat, since)
-        return _consolidate_sorted(cat, compact=True)
+        out = _consolidate_sorted(cat, compact=True)
+        # pad or truncate inside the program: no eager per-column programs
+        # after the merge, and the output capacity is part of the one key
+        return out if out_cap is None else out.with_capacity(out_cap)
 
 
 def merge_consolidate(
-    a: UpdateBatch, b: UpdateBatch, since: jnp.ndarray | None = None
+    a: UpdateBatch,
+    b: UpdateBatch,
+    since: jnp.ndarray | None = None,
+    out_cap: int | None = None,
 ) -> UpdateBatch:
     """Merge two batches that are ALREADY in canonical order, in O(n).
 
@@ -211,7 +217,9 @@ def merge_consolidate(
     the packed keys — the differential spine's cursor merge
     (src/compute/src/render/join/mz_join_core.rs-adjacent batch merger),
     vectorized. Output capacity = a.cap + b.cap, live rows compacted to the
-    front (callers truncate with with_capacity after checking counts).
+    front, or `out_cap` when given: padded, or truncated, which is sound only
+    if the caller knows the live rows fit (rows beyond `out_cap` are dropped
+    unseen — the spine's head keeps a host-side bound, arrangement/spine.py).
 
     With `since`, times first advance to the compaction frontier so +/- pairs
     at bygone times cancel. Annihilation nuance: within one packed-key
@@ -220,7 +228,7 @@ def merge_consolidate(
     still cancel once `since` passes both (times then collapse equal), so
     this costs capacity transiently, never correctness (multiset semantics).
     """
-    return _merge_consolidate(a, b, since, kernels.active_backend())
+    return _merge_consolidate(a, b, since, kernels.active_backend(), out_cap)
 
 
 def _cmp_view(c: jnp.ndarray) -> jnp.ndarray:

@@ -123,6 +123,37 @@ def test_consolidate_sort_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
 
 
+def test_head_merge_compiles_for_v5e(one_chip):
+    """The one program a refresh runs per arrangement (arrangement/spine.py):
+    a 16,384-row lineitem delta merged into the fixed-capacity head, padded
+    and truncated to the head's capacity inside the program."""
+    from materialize_tpu.arrangement.spine import HEAD_RATIO
+
+    d = 1 << 14
+
+    def lineitem(n):
+        return UpdateBatch(
+            _col(one_chip, U32, n),
+            (_col(one_chip, I64, n),),
+            tuple(_col(one_chip, I64, n) for _ in range(6)),
+            _col(one_chip, TIME_DTYPE, n),
+            _col(one_chip, DIFF_DTYPE, n),
+        )
+
+    consolidate_mod = importlib.import_module("materialize_tpu.ops.consolidate")
+    compiled = consolidate_mod._merge_consolidate.lower(
+        lineitem(HEAD_RATIO * d),
+        lineitem(d),
+        jax.ShapeDtypeStruct((), TIME_DTYPE, sharding=one_chip),
+        backend="xla",
+        out_cap=HEAD_RATIO * d,
+    ).compile()
+    out = jax.tree_util.tree_leaves(compiled.out_info)
+    assert {o.shape for o in out} == {(HEAD_RATIO * d,)}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1 << 30
+
+
 # The chip compiler's refusal per Pallas program (JAX 0.9.0, v5e:2x2). Every
 # program also lacks a grid/BlockSpec, so a whole column would have to sit
 # in fast memory even if it lowered.

@@ -1,0 +1,289 @@
+"""The fixed-capacity head of an arrangement spine (arrangement/spine.py).
+
+What the head must keep: the arrangement's contents (against a plain Python
+multiset), the truncation bound, the two views of a shared trace. What it
+must give: inserts and probes that ask XLA for no program once the head's
+(T, d) shapes are met.
+"""
+
+import importlib
+
+import numpy as np
+import pytest
+
+from materialize_tpu.arrangement import Arrangement, arrange_batch
+from materialize_tpu.arrangement.spine import HEAD_RATIO
+from materialize_tpu.arrangement.trace_manager import SharedTrace
+from materialize_tpu.ops.join import join_against
+
+from test_join import collect, mkbatch, oracle_join
+
+
+def keyed(rows, tick):
+    """rows: [((k, v), diff)] -> a batch keyed by column 0 at time `tick`."""
+    ks = [r[0][0] for r in rows]
+    vs = [r[0][1] for r in rows]
+    return arrange_batch(
+        mkbatch([ks, vs], [tick] * len(rows), [r[1] for r in rows]), (0,)
+    )
+
+
+def contents(batches, since):
+    """Multiset {(data, max(time, since)): diff} of a list of batches."""
+    acc = {}
+    for b in batches:
+        for data, t, d in b.to_rows():
+            k = (data, max(t, since))
+            acc[k] = acc.get(k, 0) + d
+    return {k: v for k, v in acc.items() if v != 0}
+
+
+class Reference:
+    """The arrangement as a plain list of (data, time, diff)."""
+
+    def __init__(self):
+        self.rows = []
+
+    def insert(self, rows, tick):
+        self.rows += [(data, tick, diff) for data, diff in rows]
+
+    def contents(self, since):
+        acc = {}
+        for data, t, d in self.rows:
+            k = (data, max(t, since))
+            acc[k] = acc.get(k, 0) + d
+        return {k: v for k, v in acc.items() if v != 0}
+
+    def live(self):
+        acc = {}
+        for data, _t, d in self.rows:
+            acc[data] = acc.get(data, 0) + d
+        return [data for data, d in acc.items() for _ in range(max(d, 0))]
+
+
+def head_counter(kind):
+    from materialize_tpu.obs.metrics import REGISTRY
+
+    fams = {f.name: f for f in REGISTRY.families()}
+    return sum(v for _l, v in fams[f"mzt_arrangement_head_{kind}_total"].samples)
+
+
+def check_head(arr):
+    """The bound that makes truncation to T safe without a device read."""
+    if arr.head_bound:
+        assert arr.head is arr.batches[-1]
+        assert arr.head_bound <= arr.head.cap
+        assert int(arr.head.count()) <= arr.head_bound
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_head_holds_the_reference_multiset(seed):
+    """Random inserts and retractions with `since` advancing, full heads
+    spilling, deltas too large for the head and deltas smaller than the
+    head's own bucket: after every insert the arrangement holds what the
+    reference holds, and a join over its batches is the reference join."""
+    rng = np.random.default_rng(seed)
+    arr, ref = Arrangement(key_cols=(0,)), Reference()
+    sizes = [300] + [6] * 18 + [100, 12, 5, 5, 40, 5, 5] + [6] * 16
+    counted = {k: head_counter(k) for k in ("merges", "spills", "bypass")}
+    for tick, n in enumerate(sizes):
+        live = ref.live()
+        n_del = min(n // 3, len(live)) if tick else 0
+        picks = rng.choice(len(live), size=n_del, replace=False) if n_del else []
+        rows = [(live[i], -1) for i in picks]
+        rows += [
+            ((int(rng.integers(0, 12)), int(rng.integers(0, 1000))), 1)
+            for _ in range(n - n_del)
+        ]
+        arr.insert(keyed(rows, tick), already_keyed=True)
+        ref.insert(rows, tick)
+        check_head(arr)
+        if tick % 5 == 4:
+            arr.compact(tick - 2)
+        assert contents(arr.batches, arr.since) == ref.contents(arr.since)
+        if tick % 4 == 0:
+            probe_rows = [((int(k), 7), 1) for k in rng.integers(0, 12, 5)]
+            got = collect(join_against(keyed(probe_rows, tick), arr.batches))
+            want = oracle_join(
+                [(data, tick, d) for data, d in probe_rows],
+                [(data, t, d) for (data, t), d in contents(arr.batches, 0).items()],
+                (0,), (0,),
+            )
+            assert got == want
+    # the sequence met a merge, a full head, an oversize delta and a bulk load
+    assert all(head_counter(k) > n for k, n in counted.items())
+    arr.compact(len(sizes))
+    final = {}
+    for data in ref.live():
+        final[data] = final.get(data, 0) + 1
+    assert {data: d for data, _t, d in arr.rows_host()} == final
+
+
+def test_head_is_spilled_before_the_bound_passes_its_capacity():
+    """Every row of every delta live: the head fills to exactly T rows, the
+    next delta spills it whole, and no row is ever truncated away."""
+    arr = Arrangement(key_cols=(0,))
+    arr.insert(keyed([((k, 0), 1) for k in range(200)], 0), already_keyed=True)
+    assert arr.head is None  # the first batch is the spine's
+    T = HEAD_RATIO * 8
+    n = 200
+    for tick in range(1, 2 * HEAD_RATIO + 2):
+        arr.insert(
+            keyed([((1000 + tick, v), 1) for v in range(8)], tick), already_keyed=True
+        )
+        n += 8
+        check_head(arr)
+        assert arr.head.cap == T
+        # delta number HEAD_RATIO + 1 (and 2 * HEAD_RATIO + 1) meets a full head
+        fills = (tick - 1) % HEAD_RATIO + 1
+        assert arr.head_bound == 8 * fills
+        assert int(arr.head.count()) == 8 * fills
+        assert arr.count() == n
+    # two full heads went to the spine at their own capacity; the first met
+    # the geometric rule there (256 + T -> 512), the second did not
+    assert [b.cap for b in arr.batches] == [512, T, T]
+
+
+def test_oversize_and_first_deltas_go_to_the_spine():
+    arr = Arrangement(key_cols=(0,))
+    arr.insert(keyed([((k, 0), 1) for k in range(300)], 0), already_keyed=True)
+    arr.insert(keyed([((1, v), 1) for v in range(8)], 1), already_keyed=True)
+    assert arr.head is not None and arr.head.cap == HEAD_RATIO * 8
+    # a delta whose bucket exceeds T/2: the head is spilled first (order kept)
+    arr.insert(keyed([((2, v), 1) for v in range(100)], 2), already_keyed=True)
+    assert arr.head is None
+    assert arr.count() == 408
+    # a delta whose head would outgrow the spine it fronts is a bulk load
+    arr.insert(keyed([((3, v), 1) for v in range(200)], 3), already_keyed=True)
+    assert arr.head is None
+    # and rebucket sees the head as one more batch
+    arr.insert(keyed([((4, v), 1) for v in range(8)], 4), already_keyed=True)
+    assert arr.head is not None
+    arr.rebucket()
+    assert arr.head is None and arr.count() == 616
+    # a table filled row by row: the smallest head there is is always allowed
+    small = Arrangement(key_cols=(0,))
+    small.insert(keyed([((1, 1), 1)], 0), already_keyed=True)
+    small.insert(keyed([((2, 1), 1)], 1), already_keyed=True)
+    assert [b.cap for b in small.batches] == [8, HEAD_RATIO * 8]
+    assert small.head_bound == 1 and small.count() == 2
+    # a delta is sized by its rows, not by the capacity its producer left it
+    # at, and a delta with no rows is not inserted
+    small.insert(keyed([((3, 1), 1), ((4, 1), 1)], 2).with_capacity(1024), already_keyed=True)
+    small.insert(keyed([((9, 9), 1), ((9, 9), -1)], 3), already_keyed=True)
+    assert [b.cap for b in small.batches] == [8, HEAD_RATIO * 8]
+    assert small.head_bound == 3 and small.count() == 4
+
+
+def test_inserts_and_probes_build_no_program_once_the_head_is_met(programs_built):
+    """One bulk insert and two delta inserts of one bucket meet every shape;
+    the next 12 inserts and probes ask XLA for nothing. (At the parent the
+    spine walked d, 2d, 4d, ... and every step built merge and join programs.)"""
+    arr = Arrangement(key_cols=(0,))
+    ticks = range(1, 15)
+    bulk = keyed([((k, 0), 1) for k in range(200)], 0)
+    # every delta: 5 rows of fresh keys; the first also 3 rows of key 0, so a
+    # probe of keys 0 and 3 meets the bulk batch and the head while it fills
+    # (the join's own output bucket follows its match count, which stays put)
+    deltas = [
+        keyed(
+            [((10_000 + t, v), 1) for v in range(5)]
+            + [((0, 100 + v), 1) for v in range(3 if t == 1 else 0)],
+            t,
+        )
+        for t in ticks
+    ]
+    probes = [keyed([((0, 7), 1), ((3, 7), 1)], t) for t in ticks]
+    arr.insert(bulk, already_keyed=True)
+    for i in range(len(ticks)):
+        if i == 2:
+            built = programs_built()
+        arr.insert(deltas[i], already_keyed=True)
+        outs = join_against(probes[i], arr.batches)
+        assert [int(o.count()) for o in outs] == [2, 3]
+    assert programs_built() - built == 0
+    assert arr.head_bound == 14 * 5 + 3 and arr.count() == 200 + 14 * 5 + 3
+
+
+def test_shared_trace_views_with_a_head_and_a_staged_delta():
+    """`batches_thru` / `batches_before` read what they read before the
+    head: the spine, the head, and the staged delta by its tick."""
+    tr = SharedTrace("u1", (0,), "mv")
+    rows = {
+        0: [((k, 0), 1) for k in range(200)],
+        1: [((1, v), 1) for v in range(4)],
+        2: [((2, v), 1) for v in range(4)] + [((1, 0), -1)],
+        3: [((3, v), 1) for v in range(4)],
+    }
+    ref = Reference()
+    for t in range(4):
+        before = ref.contents(0)
+        tr.offer(t, keyed(rows[t], t))
+        ref.insert(rows[t], t)
+        assert contents(tr.batches_thru(t), 0) == ref.contents(0)
+        assert contents(tr.batches_before(t), 0) == before
+        # a later tick's view of `before` takes the staged delta in
+        assert contents(tr.batches_before(t + 1), 0) == ref.contents(0)
+    # ticks 1 and 2 are sealed into the head; tick 3 is staged behind it
+    assert tr.arr.head is not None and tr.arr.head_bound == 4 + 5
+    assert tr.batches_thru(3)[-2] is tr.arr.head
+    assert tr.batches_thru(3)[-1] is tr.delta
+    nb, cap, rec = tr.state_info()
+    assert (nb, rec) == (3, 200 + 4 + 5 + 4)
+
+
+KERNELS = {
+    "materialize_tpu.ops.consolidate": ("_merge_consolidate", "_consolidate"),
+    "materialize_tpu.ops.join": ("_join_total", "_join_materialize"),
+    "materialize_tpu.ops.fused_reduce": ("_fused_mfp_reduce_step",),
+}
+
+
+def kernel_programs() -> dict:
+    """Programs each of the five kernel functions holds (its jit cache)."""
+    return {
+        name: getattr(importlib.import_module(mod), name)._cache_size()
+        for mod, names in KERNELS.items()
+        for name in names
+    }
+
+
+def test_served_q3_refreshes_build_no_kernel_program():
+    """Q3 through SQL: after hydration and two refreshes, refreshes 3-10
+    build no merge, consolidate, join or fused-reduce program, and the view
+    equals the oracle after each."""
+    import tpch_q3
+    from materialize_tpu.adapter import Coordinator
+    from materialize_tpu.models import tpch
+
+    c = Coordinator()
+    c.execute(tpch_q3.SOURCE_SQL_STEADY)
+    c.execute(tpch_q3.VIEW_SQL)
+    gen = c.generators[0][0]
+    seg_code = c.catalog.dict.lookup("BUILDING")
+
+    def check():
+        rows = c.execute("SELECT * FROM q3").rows
+        want = tpch.q3_oracle(
+            gen._customer_cols(),
+            tuple(gen._orders_store),
+            tuple(gen._lineitem_store),
+            building_code=seg_code,
+        )
+        got = {(lk, od, sp): round(rev * 10_000) for (lk, rev, od, sp) in rows}
+        assert got == {k: v for k, v in want.items() if v != 0}
+
+    for _ in range(2):
+        c.advance()
+    check()  # the peek's own programs are met before the count starts
+    merges = head_counter("merges")
+    for refresh in range(3, 11):
+        before = kernel_programs()
+        c.advance()
+        assert kernel_programs() == before, f"refresh {refresh}"
+        check()
+    assert head_counter("merges") - merges >= 8 * 5  # every arrangement, every refresh
+    heads = c.execute(
+        "SELECT arrangement FROM mz_arrangement_sizes WHERE arrangement LIKE '%:head'"
+    ).rows
+    assert heads
