@@ -63,7 +63,7 @@ for tick in range(1, 8):
     delta = UpdateBatch.build((), (ids, aucs, amts), [tick] * len(diffs), diffs)
 
     # (1) reduce
-    sumcount_state, out, _errs = accumulable_step(sumcount_state, delta, (1,), AGGS, tick)
+    sumcount_state, out, _errs, _counts = accumulable_step(sumcount_state, delta, (1,), AGGS, tick)
     sumcount_state = sumcount_state.with_capacity(bucket_cap(int(sumcount_state.count())))
     for d, _t, df in out.to_rows():
         sum_out[d] = sum_out.get(d, 0) + df

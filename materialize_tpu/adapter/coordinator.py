@@ -25,6 +25,7 @@ from ..dataflow import plan as lir
 from ..expr import relation as mir
 from ..obs import log as obs_log
 from ..obs import metrics as obs_metrics
+from ..obs.spans import TRACER
 from ..ops.consolidate import advance_times, consolidate
 from ..repr.batch import UpdateBatch
 from ..repr.types import ColType, ColumnDesc, RelationDesc
@@ -1776,7 +1777,8 @@ class Coordinator:
                         corrections[mv_gid] = corr
                 continue
             _t0 = _monotonic()
-            results = df.step(ts, deltas)
+            with TRACER.span("dataflow.tick"):  # parent of the render's operator spans
+                results = df.step(ts, deltas)
             _TICK_NS.observe((_monotonic() - _t0) * 1e9, dataflow=mv_gid)
             out = results.get(mv_gid)
             if out is not None and out[0] is not None:
@@ -2766,6 +2768,10 @@ def _eval_scalar_on_row(e, row: list):
             if r == 0:
                 raise PlanError("division by zero")
             return l // r if e.func == "fdiv" else l - r * (l // r)
+        if e.func == "mul_exact":
+            if abs(l * r) >= 1 << 63:
+                raise PlanError("numeric overflow")
+            return l * r
         if e.func == "add_months":
             from ..expr.scalar import add_months_int
 

@@ -43,6 +43,11 @@ REQUIRED_FAMILIES = (
     "mzt_arrangement_head_merges_total",
     "mzt_arrangement_head_spills_total",
     "mzt_arrangement_head_bypass_total",
+    # the accumulable reduce operators (dataflow/runtime.py): step wall,
+    # groups whose output changed, live groups, per (dataflow, operator)
+    "mzt_reduce_step_duration_ns",
+    "mzt_reduce_groups_changed_total",
+    "mzt_reduce_state_groups",
 )
 
 _BUMP = re.compile(r'(?:\.bump|\.record_max)\(\s*"([a-z_]+)"')

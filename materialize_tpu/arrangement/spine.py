@@ -28,8 +28,10 @@ always the last element of `batches`, so readers see one more batch.
   the delta's capacity is wider than its rows: an operator's next output
   may hold somewhat more). Later deltas merge into it with ONE program,
   `merge_consolidate` at (T, d) with the output held at T (a smaller delta
-  is padded to d; a larger one of bucket d' <= T/2 takes its own (T, d')
-  program).
+  is padded to d; a larger one of bucket d' <= T/2 goes in d rows at a
+  time: a handful of rows crosses its bucket by chance from tick to tick,
+  and a (T, d') program of its own costs the chip's compiler seconds in
+  whichever tick first meets it).
 - Bound: the host keeps `head_bound`, the sum of the row counts merged into
   the head since it was empty. A merge never creates rows, so the head's
   live rows never exceed it, and while it stays <= T, truncating the merge's
@@ -51,12 +53,13 @@ always the last element of `batches`, so readers see one more batch.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
-from ..ops.consolidate import advance_times, consolidate, merge_consolidate
+from ..ops.consolidate import advance_times, compact_to, consolidate, merge_consolidate
 from ..repr.batch import MIN_CAP, UpdateBatch, bucket_cap, device_time_scalar
 from ..repr.hashing import hash_columns
 
@@ -84,6 +87,32 @@ _live_rows = jax.jit(UpdateBatch.count)
 # pad, or cut (sound where the rows beyond are padding: arranged batches
 # keep their live rows in front), as one program per shape
 _resized = jax.jit(UpdateBatch.with_capacity, static_argnums=1)
+
+
+@partial(jax.jit, static_argnums=2)
+def _rows_from(batch: UpdateBatch, lo, n: int) -> UpdateBatch:
+    """Rows [lo, lo + n) of a batch: one program whatever `lo` is."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_slice_in_dim(x, lo, n), batch
+    )
+
+
+def sized(
+    batch: UpdateBatch | None, rows: int | None = None, slack: int = HEAD_RATIO
+) -> UpdateBatch | None:
+    """A batch sized by the rows it holds: its live rows, in their order, at
+    their pow2 bucket where it is `slack` times wider or more, else as it is
+    (a batch nearer its bucket keeps the capacity its producer repeats).
+    `rows` is an upper bound of the live rows that the caller holds on the
+    host; without one it costs ONE host read, and an empty batch is None."""
+    if batch is None:
+        return None
+    if rows is None:
+        rows = int(_live_rows(batch))
+        if rows == 0:
+            return None
+    d = bucket_cap(rows)
+    return compact_to(batch, d)[0] if slack * d <= batch.cap else batch
 
 
 def arrange_batch(
@@ -172,14 +201,23 @@ class Arrangement:
             self.batches.append(b)
             self._maintain()
             return
-        # one program per (T, d): the delta is cut or padded to its bucket
-        # (a smaller one to the head's own d), the output is held at T
-        self.batches[-1] = merge_consolidate(
-            head,
-            _resized(b, max(d, head.cap // HEAD_RATIO)),
-            since=device_time_scalar(self.since),
-            out_cap=head.cap,
-        )
+        # one program per head, (T, d) with d the head's own: a smaller delta
+        # is cut or padded to d, a larger one goes in d rows at a time (an
+        # arranged batch keeps its live rows in front and in order, so every
+        # piece is a canonical batch); the output is held at T
+        step = head.cap // HEAD_RATIO
+        if d <= step:
+            pieces = [_resized(b, step)]
+        else:
+            whole = b if b.cap >= d else _resized(b, d)  # the last piece ends by d
+            pieces = [_rows_from(whole, lo, step) for lo in range(0, n, step)]
+        for piece in pieces:
+            self.batches[-1] = merge_consolidate(
+                self.batches[-1],
+                piece,
+                since=device_time_scalar(self.since),
+                out_cap=head.cap,
+            )
         self.head_bound += n
         _HEAD_MERGES.inc()
 

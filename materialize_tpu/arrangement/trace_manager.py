@@ -142,18 +142,17 @@ class SharedReduceTrace:
         self.err_arr = Arrangement(key_cols=())
         self.frontier = -1
         self.cached: tuple = (None, None)  # (out, errs) at `frontier`
+        self.groups = 0  # live groups, and groups whose output changed, as the
+        self.changed = 0  # step to `frontier` read them
 
-    def step(self, tick: int, oks: UpdateBatch):
+    def step(self, tick: int, oks: UpdateBatch, drive):
         """Advance the shared state to `tick` (first reader computes; the
-        rest replay the cached emission). Returns (out, errs)."""
+        rest replay the cached emission). Returns (out, errs). `drive` is the
+        render's `_reduce_in_slices`: it hands `_step_one` the input whole or
+        slice by slice, as it does for a private reduce."""
         if tick <= self.frontier:
             return self.cached
-        from ..ops.reduce import accumulable_step
-
-        self.state, out, errs = accumulable_step(
-            self.state, oks, self.key_cols, self.aggs, tick
-        )
-        self.state = self.state.rebucketed()
+        out, errs, self.changed = drive(self, tick, oks)
         if out is not None:
             self.out_arr.insert(out)
         if errs is not None:
@@ -161,6 +160,19 @@ class SharedReduceTrace:
         self.frontier = tick
         self.cached = (out, errs)
         return self.cached
+
+    def _step_one(self, tick: int, delta: UpdateBatch):
+        import numpy as np
+
+        from ..ops.reduce import accumulable_step
+        from ..repr.batch import bucket_cap
+
+        self.state, out, errs, counts = accumulable_step(
+            self.state, delta, self.key_cols, self.aggs, tick
+        )
+        self.groups, changed = (int(c) for c in np.asarray(counts))
+        self.state = self.state.with_capacity(bucket_cap(self.groups))
+        return out, errs, changed
 
     def snapshot(self, at: int):
         """Cumulative (out, errs) contents through `at`, times advanced to

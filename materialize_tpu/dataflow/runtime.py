@@ -17,13 +17,17 @@ src/compute/src/render.rs:30-101.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..arrangement.spine import Arrangement, arrange_batch
+from ..arrangement.spine import HEAD_RATIO, Arrangement, arrange_batch, sized
 from ..obs import log as obs_log
+from ..obs import metrics as obs_metrics
+from ..obs.spans import TRACER
 from ..ops.consolidate import consolidate
 from ..ops.join import join_against
 from ..ops.reduce import AccumState, accumulable_step, agg_out_dtype
@@ -105,6 +109,14 @@ def _union(parts: list[UpdateBatch]) -> Optional[UpdateBatch]:
     for p in parts[1:]:
         acc = UpdateBatch.concat(acc, p)
     return consolidate(acc)
+
+
+def _probe(probe: UpdateBatch, batches: list, swap: bool = False) -> list:
+    """`join_against` for the join operators: matches that fit a
+    HEAD_RATIO-th of the probe's capacity in all come back at that one size.
+    A delta's bucket repeats from tick to tick (an arrangement's head is
+    built on that), the few rows a selective stage leaves of it do not."""
+    return join_against(probe, batches, swap, floor=bucket_cap(probe.cap // HEAD_RATIO))
 
 
 def _project(batch: UpdateBatch, cols: tuple[int, ...]) -> UpdateBatch:
@@ -397,12 +409,12 @@ class LinearJoinNode(Node):
             rh.offer(tick, drk)
         if dlk is not None:
             right_batches = rh.thru(tick) if rh is not None else right_arr.batches
-            outs += join_against(dlk, right_batches)
+            outs += _probe(dlk, right_batches)
         if drk is not None:
             left_batches = lh.before(tick) if lh is not None else left_arr.batches
-            outs += join_against(drk, left_batches, swap=True)
+            outs += _probe(drk, left_batches, swap=True)
         if rh is None and dlk is not None and drk is not None:
-            outs += join_against(dlk, [drk])  # arrange_batch consolidated drk
+            outs += _probe(dlk, [drk])  # arrange_batch consolidated drk
         if lh is None and dlk is not None:
             left_arr.insert(dlk, already_keyed=True)
         if rh is None and drk is not None:
@@ -534,7 +546,7 @@ class DeltaJoinNode(Node):
                     continue
                 probe = arrange_batch(stream, st.stream_key)
                 stream = _union(
-                    join_against(probe, self._lookup_batches(k, st, tick))
+                    _probe(probe, self._lookup_batches(k, st, tick))
                 )
             if stream is not None:
                 outs.append(_project(stream, self.plan.permutations[k]))
@@ -576,6 +588,60 @@ class DeltaJoinNode(Node):
         return out
 
 
+# Past this many rows of capacity a batch is BULK: a hydration snapshot, a bulk
+# load, and what operators make of one. Ordinary deltas are far narrower (at
+# TPC-H SF1 Q3's and Q17's are 16,384 to 131,072 rows wide). Two rules hold for
+# bulk batches only, so an ordinary tick's programs and host reads are what
+# they were:
+# - an accumulable reduce steps a bulk input slice by slice: the step's programs
+#   hold several times their input in temporaries (the chip's compiler asks
+#   9.2 GB for the 8,388,608-row snapshot of lineitem at SF1 under sum + count
+#   by l_partkey, 2.3 GB for a slice; PERF.md section 6, PR 30), and the
+#   self-correcting emission makes slices sound: a later slice retracts what an
+#   earlier one emitted for the same group, and the union consolidates;
+# - an operator's bulk output is sized by the rows it holds (`spine.sized`,
+#   one host read) before the next operator sees it: outputs live until the
+#   tick ends and every operator downstream costs by capacity, and a join's
+#   filter or a reduce leaves a few rows, or no error at all, at the width of
+#   the snapshot (Q17's hydration held 22 GB of such outputs at SF1).
+# Not half of this: the chip's compiler builds the reduce step over 1,048,576
+# rows as 753 MB of code in 429 s, over 2,097,152 rows as 171 MB in 110 s
+# (v5e, asked without the chip; on the chip a bound of 2^20 took Q17's
+# hydration from 560 s to over 1,150).
+BULK_ROWS = 1 << 21
+
+
+def _bulk_sized(batch: Optional[UpdateBatch]) -> Optional[UpdateBatch]:
+    return sized(batch) if batch is not None and batch.cap > BULK_ROWS else batch
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _rows(batch: UpdateBatch, lo: int, hi: int) -> UpdateBatch:
+    return jax.tree_util.tree_map(lambda x: x[lo:hi], batch)
+
+
+def _reduce_in_slices(reducer, tick: int, oks: UpdateBatch):
+    """Steps `reducer` (its accumulator table `state`, its `_step_one(tick,
+    delta) -> (out, errs, changed)`) over `oks`: as one step where it is no
+    wider than BULK_ROWS (every ordinary tick), else over its slices. Every
+    slice meets the table at BULK_ROWS of capacity or more (the step cuts it
+    back to its groups): one program for all slices, where a table growing
+    from slice to slice would ask for one each, at two minutes of the chip's
+    compiler apiece. A slice's output is sized by the rows it can hold (a
+    changed group emits at most a retraction and an insertion; the kernels
+    leave an output at twice its input's capacity). Returns the same triple."""
+    if oks.cap <= BULK_ROWS:
+        return reducer._step_one(tick, oks)
+    outs, errs, changed = [], [], 0
+    for lo in range(0, oks.cap, BULK_ROWS):
+        reducer.state = reducer.state.with_capacity(max(reducer.state.cap, BULK_ROWS))
+        out, e, n = reducer._step_one(tick, _rows(oks, lo, min(lo + BULK_ROWS, oks.cap)))
+        outs.append(sized(out, 2 * n, slack=1))
+        errs.append(_bulk_sized(e))
+        changed += n  # a group counts once per slice that changed it
+    return _union(outs), _union(errs), changed
+
+
 class ReduceNode(Node):
     def __init__(self, expr: lir.Reduce, in_dtypes: tuple):
         self.key_cols = expr.key_cols
@@ -583,22 +649,32 @@ class ReduceNode(Node):
         key_dtypes = tuple(in_dtypes[i] for i in expr.key_cols)
         accum_dtypes = tuple(np.dtype(a.accum_dtype) for a in expr.aggs)
         self.state = AccumState.empty(8, key_dtypes, accum_dtypes)
+        self.groups = 0  # live groups, as the last step read them
+        self.changed = None  # groups whose output changed in the last step (None: no step)
 
     def step(self, tick, ins):
+        self.changed = None
         d = ins[0]
         if d is None:
             return None
         oks, errs = d
         if oks is None:
             return None if errs is None else (None, errs)
-        self.state, out, agg_errs = accumulable_step(
-            self.state, oks, self.key_cols, self.aggs, tick
-        )
-        self.state = self.state.rebucketed()
+        out, agg_errs, self.changed = _reduce_in_slices(self, tick, oks)
         return out, _union([errs, agg_errs])
 
+    def _step_one(self, tick, delta):
+        self.state, out, agg_errs, counts = accumulable_step(
+            self.state, delta, self.key_cols, self.aggs, tick
+        )
+        self.groups, changed = (int(c) for c in np.asarray(counts))
+        # the step leaves the table at cap(state) + cap(delta): back to the
+        # pow2 bucket of its groups (AccumState.rebucketed, on the count read above)
+        self.state = self.state.with_capacity(bucket_cap(self.groups))
+        return out, agg_errs, changed
+
     def state_info(self):
-        return [("reduce_accums", 1, self.state.cap, int(self.state.count()))]
+        return [("reduce_accums", 1, self.state.cap, self.groups)]
 
 
 class SharedReduceNode(Node):
@@ -610,8 +686,14 @@ class SharedReduceNode(Node):
 
     def __init__(self, handle):
         self.h = handle
+        self.changed = None
+
+    @property
+    def groups(self) -> int:
+        return self.h.trace.groups
 
     def step(self, tick, ins):
+        self.changed = None
         d = ins[0]
         if self.h._hydrating(tick):
             if self.h.trusted:
@@ -634,7 +716,10 @@ class SharedReduceNode(Node):
         oks, errs = d
         if oks is None:
             return None if errs is None else (None, errs)
-        out, agg_errs = self.h.trace.step(tick, oks)
+        stepped = tick > self.h.trace.frontier  # else another reader's step is replayed
+        out, agg_errs = self.h.trace.step(tick, oks, _reduce_in_slices)
+        if stepped:
+            self.changed = self.h.trace.changed
         return out, _union([errs, agg_errs])
 
     def _private_hydration(self, tick, d):
@@ -642,15 +727,13 @@ class SharedReduceNode(Node):
         accumulator (exactly what a private ReduceNode would emit)."""
         if d is None or d[0] is None:
             return None, None
-        from ..ops.reduce import AccumState, accumulable_step
-
         tr = self.h.trace
         scratch = AccumState.empty(
             8,
             tuple(k.dtype for k in tr.state.keys),
             tuple(a.dtype for a in tr.state.accums),
         )
-        _state, out, errs = accumulable_step(
+        _state, out, errs, _counts = accumulable_step(
             scratch, d[0], tr.key_cols, tr.aggs, tick
         )
         return out, errs
@@ -676,28 +759,53 @@ class FusedMfpReduceNode(Node):
         accum_dtypes = tuple(np.dtype(a.accum_dtype) for a in expr.aggs)
         self.state = _AS.empty(8, key_dtypes, accum_dtypes)
         self.state_cap = 8
+        self.groups = 0
+        self.changed = None
 
     def step(self, tick, ins):
-        from ..ops.fused_reduce import fused_mfp_reduce_step
-
+        self.changed = None
         d = ins[0]
         if d is None:
             return None
         oks, errs = d
         if oks is None:
             return None if errs is None else (None, errs)
-        self.state, out, agg_errs = fused_mfp_reduce_step(
-            self.state, oks, tick, self.mfp, self.key_cols, self.aggs
-        )
-        n = int(self.state.count())
-        if bucket_cap(n) > self.state_cap:
-            self.state_cap = bucket_cap(n)
-        self.state = self.state.with_capacity(self.state_cap)
+        out, agg_errs, self.changed = _reduce_in_slices(self, tick, oks)
         return out, _union([errs, agg_errs])
 
-    def state_info(self):
-        return [("fused_reduce_accums", 1, self.state.cap, int(self.state.count()))]
+    def _step_one(self, tick, delta):
+        from ..ops.fused_reduce import fused_mfp_reduce_step
 
+        self.state, out, agg_errs, counts = fused_mfp_reduce_step(
+            self.state, delta, tick, self.mfp, self.key_cols, self.aggs
+        )
+        self.groups, changed = (int(c) for c in np.asarray(counts))
+        if bucket_cap(self.groups) > self.state_cap:
+            self.state_cap = bucket_cap(self.groups)
+        self.state = self.state.with_capacity(self.state_cap)
+        return out, agg_errs, changed
+
+    def state_info(self):
+        return [("fused_reduce_accums", 1, self.state.cap, self.groups)]
+
+
+_REDUCE_NODES = (ReduceNode, SharedReduceNode, FusedMfpReduceNode)
+_REDUCE_LABELS = ("dataflow", "operator")
+_REDUCE_STEP_NS = obs_metrics.REGISTRY.histogram(
+    "mzt_reduce_step_duration_ns",
+    "host wall of one accumulable reduce operator's step that had input",
+    labels=_REDUCE_LABELS,
+)
+_REDUCE_CHANGED = obs_metrics.REGISTRY.counter(
+    "mzt_reduce_groups_changed_total",
+    "groups whose output row changed (appeared, vanished or took a new value) in a reduce step",
+    labels=_REDUCE_LABELS,
+)
+_REDUCE_GROUPS = obs_metrics.REGISTRY.gauge(
+    "mzt_reduce_state_groups",
+    "live groups in a reduce operator's accumulator table after its last step",
+    labels=_REDUCE_LABELS,
+)
 
 _ABSENT = object()
 
@@ -1837,14 +1945,29 @@ class Dataflow:
                 ins = [
                     (env.get(r) if isinstance(r, str) else slots[r]) for r in in_refs
                 ]
+                is_reduce = isinstance(node, _REDUCE_NODES)
                 t0 = _time.perf_counter_ns()
-                slots.append(node.step(tick, ins))
+                if is_reduce:
+                    with TRACER.span("reduce.step"):
+                        slots.append(node.step(tick, ins))
+                else:
+                    slots.append(node.step(tick, ins))
+                if slots[-1] is not None:
+                    oks, errs = (_bulk_sized(b) for b in slots[-1])
+                    slots[-1] = None if oks is None and errs is None else (oks, errs)
+                elapsed = _time.perf_counter_ns() - t0
                 m = self.metrics.setdefault(
                     (obj_id, op_i),
                     {"type": type(node).__name__, "elapsed_ns": 0, "invocations": 0},
                 )
-                m["elapsed_ns"] += _time.perf_counter_ns() - t0
+                m["elapsed_ns"] += elapsed
                 m["invocations"] += 1
+                if is_reduce and node.changed is not None:
+                    # per call of a step that had input, never per trace
+                    labels = {"dataflow": obj_id, "operator": f"{op_i}:{m['type']}"}
+                    _REDUCE_STEP_NS.observe(elapsed, **labels)
+                    _REDUCE_CHANGED.inc(node.changed, **labels)
+                    _REDUCE_GROUPS.set(node.groups, **labels)
                 if self.operator_logging:
                     # row counts need a device sync per delta — gated so the
                     # default tick path does no per-row work (the

@@ -135,7 +135,7 @@ class CallUnary:
 
 @dataclass(frozen=True)
 class CallBinary:
-    func: str  # add sub mul div floordiv mod eq ne lt lte gt gte and or min max
+    func: str  # add sub mul mul_exact div floordiv mod eq ne lt lte gt gte and or min max
     left: Any
     right: Any
 
@@ -274,6 +274,13 @@ def eval_expr3(expr: ScalarExpr, cols: list[jnp.ndarray], n: int):
             return lv - rv, null, err
         if f == "mul":
             return lv * rv, null, err
+        if f == "mul_exact":
+            # an i64 product that would wrap is an error, not a value (the
+            # NUMERIC pre-scale of a dividend, sql/plan.py::_numeric_div)
+            lv, rv = lv.astype(jnp.int64), rv.astype(jnp.int64)
+            room = jnp.iinfo(jnp.int64).max // jnp.maximum(jnp.abs(rv), 1)
+            over = (jnp.abs(lv) > room) & ~null
+            return lv * rv, null, jnp.where(over, jnp.int32(EvalErr.NUMERIC_OVERFLOW), err)
         if f in ("div", "floordiv"):
             zero = (rv == 0) & ~null
             safe = jnp.where(rv == 0, jnp.ones_like(rv), rv)

@@ -28,6 +28,7 @@ from .reduce import (
     _emit_output,
     consolidate_accums,
     lookup_accums,
+    step_counts,
 )
 
 
@@ -39,7 +40,9 @@ def fused_mfp_reduce_step(
     key_cols: tuple[int, ...],
     aggs: tuple,
 ):
-    """(state, Δin, t) → (state', Δout, Δerrs) in one XLA program."""
+    """(state, Δin, t) → (state', Δout, Δerrs, counts) in one XLA program;
+    `counts` is `reduce.step_counts` (live groups, groups whose output
+    changed), so the caller's one host read needs no program of its own."""
     from . import kernels
 
     return _fused_mfp_reduce_step(
@@ -89,4 +92,4 @@ def _fused_mfp_reduce_step_body(
     out = consolidate(_emit_output(contrib, old_accums, old_nrows, time, aggs))
     new_state = consolidate_accums(AccumState.concat(state, contrib))
     errs = errs2 if errs1 is None else consolidate(UpdateBatch.concat(errs1, errs2))
-    return new_state, out, errs
+    return new_state, out, errs, step_counts(new_state, contrib, old_nrows)

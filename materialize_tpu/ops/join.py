@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ..repr.batch import PAD_TIME, UpdateBatch, bucket_cap
 from ..repr.hashing import PAD_HASH
 from . import kernels
+from .consolidate import compact_to
 from .search import searchsorted
 
 
@@ -126,17 +127,28 @@ def _join_materialize_body(
     )
 
 
-def join_against(probe: UpdateBatch, batches: list[UpdateBatch], swap: bool = False):
+def join_against(
+    probe: UpdateBatch, batches: list[UpdateBatch], swap: bool = False, floor: int = 0
+):
     """Join a probe batch against every batch of an arrangement (host driver).
 
     Returns a list of raw output batches (possibly empty). Sizes outputs by a
     count pass per spine batch; capacities are pow2-bucketed to bound
-    recompilation.
+    recompilation. Where all of the probe's matches together fit `floor` rows
+    they come back as ONE batch of that capacity, through the same programs
+    whichever batches hold them: counts that small split over the batches, and
+    cross their small buckets, by chance from tick to tick, and every program
+    downstream would follow them.
     """
-    outs = []
-    for arr in batches:
-        total = int(join_total(probe, arr))
-        if total == 0:
-            continue
-        outs.append(join_materialize(probe, arr, bucket_cap(total), swap))
-    return outs
+    totals = [int(join_total(probe, arr)) for arr in batches]
+    if 0 < sum(totals) <= floor:
+        acc = None
+        for arr in batches:  # also one that matched nothing: its turn comes
+            out = join_materialize(probe, arr, floor, swap)
+            acc = out if acc is None else UpdateBatch.concat(acc, out)
+        return [acc if len(batches) == 1 else compact_to(acc, floor)[0]]
+    return [
+        join_materialize(probe, arr, bucket_cap(t), swap)
+        for arr, t in zip(batches, totals)
+        if t
+    ]

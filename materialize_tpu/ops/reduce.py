@@ -505,6 +505,23 @@ def _emit_output(
     return UpdateBatch(hashes, (), vals, times, diffs)
 
 
+def step_counts(new_state: AccumState, contrib: AccumState, old_nrows) -> jnp.ndarray:
+    """i32[2] a reduce step hands the host in ONE read: the live groups of
+    the table after the step, and the groups whose output row changed in it
+    (appeared, vanished, or present on both sides with an accumulator that
+    moved; `contrib` is consolidated, so its live rows are the groups the
+    tick touched, and one whose deltas cancel is not among them)."""
+    was, now = old_nrows > 0, old_nrows + contrib.nrows > 0
+    moved = was != was  # varying-typed False
+    for d in contrib.accums:
+        moved = moved | (d != 0)
+    changed = contrib.live & ((was != now) | (was & now & moved))
+    return jnp.stack([new_state.count(), jnp.sum(changed.astype(jnp.int32))])
+
+
+_step_counts = jax.jit(step_counts)
+
+
 def accumulable_step(
     state: AccumState,
     delta: UpdateBatch,
@@ -512,11 +529,14 @@ def accumulable_step(
     aggs: tuple[AggregateExpr, ...],
     time: int,
 ):
-    """One tick of an accumulable reduce: (state, Δin, t) → (state', Δout, Δerrs).
+    """One tick of an accumulable reduce: (state, Δin, t) → (state', Δout, Δerrs, counts).
 
-    Host driver around jitted kernels; Δout is consolidated (no-op pairs
+    Δout holds retractions of changed groups' old rows and insertions of
+    their new rows, at time t (groups whose accumulators didn't change
     cancel). Rows whose aggregate input expression errors land in Δerrs.
-    The state comes back at cap(state) + cap(Δ); callers `rebucketed()` it.
+    The state comes back at cap(state) + cap(Δ); callers cut it back to the
+    bucket of its groups, which `counts` (`step_counts`, still on the
+    device) hands them with the changed groups in one read.
     """
     raw_contrib, errs = _contributions(delta, key_cols, aggs)
     contrib = consolidate_accums(raw_contrib)
@@ -532,4 +552,4 @@ def accumulable_step(
     if ov is not None:
         errs = consolidate(UpdateBatch.concat(errs, ov))
     new_state = consolidate_accums(AccumState.concat(state, contrib))
-    return new_state, out, errs
+    return new_state, out, errs, _step_counts(new_state, contrib, old_nrows)

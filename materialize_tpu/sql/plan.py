@@ -11,6 +11,21 @@ NUMERIC is fixed-point i64 with a tracked decimal scale: literals like 0.05
 plan as Literal(5)@scale2, multiplication adds scales, addition aligns them —
 exact arithmetic on device, mirroring the reference's libdecnumber NUMERIC
 without an f64 dependency (TPUs have no f64 ALU).
+
+NUMERIC division (one rule, `_numeric_div`): `l / r` with either side NUMERIC
+returns NUMERIC at scale max(scale(l), scale(r), NUMERIC_DIV_SCALE = 6), the
+exact quotient TRUNCATED toward zero at that digit (the i64 `div` kernel's
+rounding; no half-up step, so no second multiply that could overflow):
+`1850 / 7.0` is 264.285714, `sum(cents) / 7.0` keeps six digits. The
+dividend is scaled up by 10^(target + scale(r) - scale(l)) first (`mul_exact`):
+past 2^63 / 10^that (a scale-2 sum over 7.0: 9.2e13 in cents; an integer
+`avg`: a sum of 9.2e12) the row is a `numeric overflow` error in the
+errs stream, never a wrapped value.
+int / int stays SQL integer division. `avg` over an integer or NUMERIC
+column is that same division of the group's exact i64 sum by its non-null
+count (scale max(scale(x), 6), NULL for an empty group); only `avg` over a
+float column is a float. A NUMERIC operand that meets a FLOAT one in `*` or
+`/` is descaled to its value first (`0.2 * f` is a fifth of `f`).
 """
 
 from __future__ import annotations
@@ -21,7 +36,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ..expr import relation as mir
-from ..expr.scalar import CallBinary, CallUnary, CallVariadic, Column, Literal
+from ..expr.scalar import CallBinary, CallUnary, CallVariadic, Column, Literal, expr_columns
 from ..repr.types import ColType, ColumnDesc, RelationDesc
 from . import ast
 
@@ -211,6 +226,23 @@ def _rescale(e, from_scale: int, to_scale: int):
     return CallBinary("floordiv", e, Literal(10 ** (from_scale - to_scale)))
 
 
+NUMERIC_DIV_SCALE = 6  # least number of fractional digits a NUMERIC quotient keeps
+
+
+def _numeric_div(l, lt: "PType", r, rt: "PType"):
+    """`l / r` under the module note's one NUMERIC division rule."""
+    target = max(lt.scale, rt.scale, NUMERIC_DIV_SCALE)  # a PType that is not NUMERIC has scale 0
+    num = CallBinary("mul_exact", l, Literal(10 ** (target + rt.scale - lt.scale)))
+    return CallBinary("div", num, r), PType(ColType.NUMERIC, target)
+
+
+def _avg_type(vt: "PType") -> "PType":
+    """What `avg` over a column of type `vt` returns."""
+    if vt.col == ColType.FLOAT64:
+        return FLOAT
+    return PType(ColType.NUMERIC, max(vt.scale, NUMERIC_DIV_SCALE))
+
+
 class Planner:
     def __init__(self, catalog):
         self.catalog = catalog
@@ -245,13 +277,15 @@ class Planner:
             null = Literal(None, e.vt.dtype.name)
             return CallVariadic("if", (guard, Column(e.sum_col), null)), e.vt
         if isinstance(e, _PostAvg):
-            num = _to_float(Column(e.sum_col), e.vt)
             # nullif guard: a group whose inputs are all NULL has non-null
             # count 0 and must yield NULL, not divide by zero
+            if e.vt.col != ColType.FLOAT64:
+                den = CallVariadic("nullif", (Column(e.cnt_col), Literal(0)))
+                return _numeric_div(Column(e.sum_col), e.vt, den, INT)
             den = CallVariadic(
                 "nullif", (CallUnary("cast_float", Column(e.cnt_col)), Literal(0.0, "float32"))
             )
-            return CallBinary("div", num, den), FLOAT
+            return CallBinary("div", _to_float(Column(e.sum_col), e.vt), den), FLOAT
         if isinstance(e, _PostStat):
             # var = (sum_sq - sum^2/n) / (n - ddof); stddev = sqrt(var)
             s_ = _to_float(Column(e.sum_col), e.vt)
@@ -462,16 +496,15 @@ class Planner:
             t = self._arith_type(lt, rt)
             if t.col == ColType.NUMERIC:
                 return CallBinary("mul", l, r), PType(ColType.NUMERIC, lt.scale + rt.scale)
+            if t.col == ColType.FLOAT64:
+                return CallBinary("mul", _descaled(l, lt), _descaled(r, rt)), FLOAT
             return CallBinary("mul", l, r), t
         if op == "/":
             t = self._arith_type(lt, rt)
             if t.col == ColType.FLOAT64:
-                return CallBinary("div", l, r), FLOAT
+                return CallBinary("div", _descaled(l, lt), _descaled(r, rt)), FLOAT
             if t.col == ColType.NUMERIC:
-                # numeric division: scale result to max(l,r) scale
-                target = max(lt.scale, rt.scale)
-                num = CallBinary("mul", l, Literal(10 ** (target + rt.scale - lt.scale)))
-                return CallBinary("div", num, r), PType(ColType.NUMERIC, target)
+                return _numeric_div(l, lt, r, rt)
             return CallBinary("div", l, r), INT
         if op == "%":
             return CallBinary("mod", l, r), INT
@@ -1185,14 +1218,47 @@ class Planner:
                     equivs.append(set(pair))
             else:
                 residual.append(c)
+        scope = full_scope
+        filters = [self.plan_scalar(c, scope)[0] for c in residual]
         if len(factors) == 1:
             rel = factors[0]
+        elif lifter.extra_conjuncts and not pending_fm:
+            # a decorrelated subquery is a per-key aggregate joined back on
+            # its keys: what it joins is the outer relation, the FROM items
+            # under the WHERE's own predicates (the dependent join's left
+            # side), so those are joined and filtered first. One flat join
+            # with every filter above it would stream each changed aggregate
+            # through all of the outer rows of its key before any filter.
+            n_outer = offsets[n_factors_pre_lift]
+            outer = factors[0]
+            if n_factors_pre_lift > 1:
+                outer = mir.MirJoin(
+                    inputs=tuple(factors[:n_factors_pre_lift]),
+                    equivalences=tuple(
+                        o for o in (tuple(sorted(i for i in c if i < n_outer)) for c in equivs)
+                        if len(o) > 1
+                    ),
+                )
+            own = [p for p in filters if all(i < n_outer for i in expr_columns(p))]
+            filters = [p for p in filters if p not in own]
+            for p in own:
+                outer = mir.MirFilter(outer, (p,))
+            # the outer columns of a class are equal already: one stands for them
+            rel = mir.MirJoin(
+                inputs=(outer, *factors[n_factors_pre_lift:]),
+                equivalences=tuple(
+                    x for x in (
+                        tuple(sorted([i for i in c if i < n_outer][:1] + [i for i in c if i >= n_outer]))
+                        for c in equivs
+                    )
+                    if len(x) > 1
+                ),
+            )
         else:
             rel = mir.MirJoin(
                 inputs=tuple(factors),
                 equivalences=tuple(tuple(sorted(c)) for c in equivs),
             )
-        scope = full_scope
         # correlated table functions fan out on top of the joined factors
         for k, (fname, fargs, _alias, _si) in enumerate(pending_fm):
             prefix = Scope(list(full_scope.cols[: flat_start + k]))
@@ -1200,8 +1266,7 @@ class Planner:
             if len(planned_args) == 2:
                 planned_args.append(Literal(1))
             rel = mir.MirFlatMap(rel, fname, tuple(planned_args))
-        for c in residual:
-            p, _t = self.plan_scalar(c, scope)
+        for p in filters:
             rel = mir.MirFilter(rel, (p,))
         if temporal:
             rel = self._plan_temporal(rel, temporal, scope)
@@ -1985,7 +2050,7 @@ class Planner:
                 sum_i = emit(bid, mir.MirAggregate("sum", v))
                 # avg divides by the NON-NULL input count
                 cnt_i = emit(bid, mir.MirAggregate("count", v))
-                post_agg_exprs.append(("avg", (sum_i, cnt_i, vt), FLOAT))
+                post_agg_exprs.append(("avg", (sum_i, cnt_i, vt), _avg_type(vt)))
                 agg_types.extend([vt, INT])
             elif fname in ("stddev", "stddev_samp", "stddev_pop", "variance", "var_samp", "var_pop"):
                 if a.distinct:
@@ -2255,6 +2320,12 @@ def _to_float(e, t: PType):
     return f
 
 
+def _descaled(e, t: PType):
+    """A scaled NUMERIC operand as its float value, where it meets a FLOAT in
+    `*` or `/`; anything else as it is (the kernels promote int to float)."""
+    return _to_float(e, t) if t.col == ColType.NUMERIC and t.scale else e
+
+
 class _SubqueryLifter:
     """Rewrite uncorrelated subqueries into extra join factors.
 
@@ -2303,21 +2374,22 @@ class _SubqueryLifter:
         sel = q.body
         if not isinstance(sel, ast.Select) or sel.group_by or sel.having or len(sel.items) != 1:
             raise PlanError("unsupported correlated subquery shape")
-        # inner alias universe (syntactic correlation detection)
-        inner_names: set = set()
-        def collect(f):
-            if isinstance(f, ast.TableRef):
-                inner_names.add(f.alias or f.name)
-            elif isinstance(f, ast.JoinClause):
-                collect(f.left)
-                collect(f.right)
-            elif isinstance(f, ast.SubqueryRef):
-                inner_names.add(f.alias)
+        # the subquery's own FROM: its aliases, and its column names, because an
+        # unqualified name binds there first (SQL scoping), so Q17's published
+        # text (`l_partkey = p_partkey`, no aliases) finds its correlation the
+        # same way the aliased form does
+        inner_scopes: list[Scope] = []
+        outer_fm, self.planner._pending_fm = getattr(self.planner, "_pending_fm", None), []
         for f in sel.from_:
-            collect(f)
+            self.planner._flatten_from(f, [], inner_scopes, [])
+        self.planner._pending_fm = outer_fm
+        inner_names = {c.qualifier for s in inner_scopes for c in s.cols}
+        inner_cols = {c.name for s in inner_scopes for c in s.cols}
 
         def is_inner(i: ast.Ident) -> bool:
-            return i.qualifier is not None and i.qualifier in inner_names
+            if i.qualifier is not None:
+                return i.qualifier in inner_names
+            return i.name in inner_cols
 
         corr: list[tuple[ast.Ident, ast.Ident]] = []  # (inner, outer)
         residual: list = []

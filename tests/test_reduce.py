@@ -30,7 +30,7 @@ def run_ticks(ticks):
     out_acc = {}
     for t, (ks, vs, ds) in enumerate(ticks):
         delta = mkbatch([ks, vs], [t] * len(ks), ds)
-        state, out, _errs = accumulable_step(state, delta, (0,), AGGS, t)
+        state, out, _errs, _counts = accumulable_step(state, delta, (0,), AGGS, t)
         n = int(state.count())
         state = consolidate_accums(state).with_capacity(bucket_cap(n))
         for data, tt, d in out.to_rows():
@@ -108,7 +108,7 @@ def test_sum_error_routes_to_err_stream():
     aggs = (AggregateExpr("sum", CallBinary("div", Column(1), Column(2))),)
     state = AccumState.empty(8, (np.dtype(np.int64),), (np.dtype(np.int64),))
     delta = mkbatch([[1, 1], [10, 7], [2, 0]], [0, 0], [1, 1])
-    state, out, errs = accumulable_step(state, delta, (0,), aggs, 0)
+    state, out, errs, _counts = accumulable_step(state, delta, (0,), aggs, 0)
     assert [r[0] for r in out.to_rows()] == [(1, 5)]  # only the clean row
     err_rows = errs.to_rows()
     assert len(err_rows) == 1 and err_rows[0][2] == 1  # one err row, diff 1

@@ -205,6 +205,37 @@ def test_inserts_and_probes_build_no_program_once_the_head_is_met(programs_built
     assert arr.head_bound == 14 * 5 + 3 and arr.count() == 200 + 14 * 5 + 3
 
 
+@pytest.mark.parametrize("wide", [False, True])
+def test_a_delta_past_the_heads_bucket_goes_in_pieces_through_the_same_program(wide):
+    """A head started by a delta of 5 rows (d = 8: T = 16 x 8, or 16 x 16 with
+    the bucket of slack an operator's wide output gets) later meets deltas of
+    17 to 33 rows, as a handful of rows does by chance (Q17's filtered
+    lineitems at SF1: 12 a refresh, give or take). They go in d rows at a
+    time: the contents are the reference's, and no insert after the one that
+    started the head asks for a merge program (a (T, d') program of their own
+    cost the chip's compiler seconds in whichever refresh met it)."""
+    from materialize_tpu.ops.consolidate import _merge_consolidate
+
+    arr, ref = Arrangement(key_cols=(0,)), Reference()
+    sizes = [200, 5, 7, 17, 20, 9, 33, 12, 18]
+    for tick, n in enumerate(sizes):
+        rows = [((1000 * tick + k, k), 1) for k in range(n)]
+        if tick > 2:
+            rows[0] = ((1000 * (tick - 1), 0), -1)  # a retraction of the last delta's first row
+        delta = keyed(rows, tick)
+        if wide and tick:  # an operator's output: far wider than its rows
+            delta = delta.with_capacity(1024)
+        if tick == 2:
+            built = _merge_consolidate._cache_size()
+        arr.insert(delta, already_keyed=True)
+        ref.insert(rows, tick)
+        assert contents(arr.batches, arr.since) == ref.contents(arr.since)
+        if tick:
+            assert arr.head is not None and arr.head.cap == HEAD_RATIO * (16 if wide else 8)
+    assert _merge_consolidate._cache_size() == built
+    assert arr.head_bound == sum(sizes[1:])
+
+
 def test_shared_trace_views_with_a_head_and_a_staged_delta():
     """`batches_thru` / `batches_before` read what they read before the
     head: the spine, the head, and the staged delta by its tick."""

@@ -117,7 +117,16 @@ def test_correlated_scalar_subquery_decorrelation(coord):
     assert r.rows == [(1, 2), (1, 10), (1, 30), (2, 5)]
 
 
-def test_correlated_q17_shape(coord):
+@pytest.mark.parametrize(
+    "predicate",
+    [
+        "l.qty * 5 < (SELECT avg(l2.qty) FROM l l2 WHERE l2.pk = l.pk)",
+        # the published form (until PR 30 `0.2 * avg` was `2 * avg`)
+        "l.qty < (SELECT 0.2 * avg(l2.qty) FROM l l2 WHERE l2.pk = l.pk)",
+    ],
+    ids=["qty_times_5", "published"],
+)
+def test_correlated_q17_shape(coord, predicate):
     """0.2 * avg correlated threshold with an outer join filter."""
     coord.execute("CREATE TABLE l (pk int, price int, qty int)")
     coord.execute("CREATE TABLE p (pk int, brand int)")
@@ -126,9 +135,8 @@ def test_correlated_q17_shape(coord):
     )
     coord.execute("INSERT INTO p VALUES (1, 7), (2, 8)")
     r = coord.execute(
-        """SELECT sum(l.price) FROM l, p
-           WHERE p.pk = l.pk AND p.brand = 7
-             AND l.qty * 5 < (SELECT avg(l2.qty) FROM l l2 WHERE l2.pk = l.pk)"""
+        f"""SELECT sum(l.price) FROM l, p
+           WHERE p.pk = l.pk AND p.brand = 7 AND {predicate}"""
     )
     # group 1 avg qty = 25.5; rows with qty*5 < 25.5: qty=1 -> price 100
     assert r.rows == [(100,)]
