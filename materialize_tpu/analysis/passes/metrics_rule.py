@@ -48,6 +48,9 @@ REQUIRED_FAMILIES = (
     "mzt_reduce_step_duration_ns",
     "mzt_reduce_groups_changed_total",
     "mzt_reduce_state_groups",
+    # UpdateBatch.build (repr/batch.py) by where its columns lived: `host`
+    # says the ingest asked XLA for no program, `device` should stay 0 there
+    "mzt_batch_build_total",
 )
 
 _BUMP = re.compile(r'(?:\.bump|\.record_max)\(\s*"([a-z_]+)"')

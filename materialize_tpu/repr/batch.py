@@ -33,8 +33,17 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.dtypes import canonicalize_dtype
 
-from .hashing import PAD_HASH, hash_columns
+from ..obs import metrics as obs_metrics
+from .hashing import PAD_HASH, hash_columns, hash_columns_np, host_column
+
+_BUILDS = obs_metrics.REGISTRY.counter(
+    "mzt_batch_build_total",
+    "UpdateBatch.build calls by where the columns lived: host (built in NumPy "
+    "at capacity, one transfer, no XLA program) or device",
+    labels=("path",),
+)
 
 # ---------------------------------------------------------------------------
 # 64-bit boundary allowlist.
@@ -85,6 +94,18 @@ def to_device_time(times) -> jnp.ndarray:
     return t32
 
 
+def _host_time_view(times: np.ndarray) -> np.ndarray:
+    """`to_device_time` in NumPy, for times that live on the host."""
+    if times.dtype == np.uint32:
+        return times
+    if times.dtype.kind in "biu" and times.dtype.itemsize < 8:
+        times = times.astype(np.int64)  # the clip's upper bound needs the room
+    t32 = np.clip(times, 0, MAX_DEVICE_TIME).astype(np.uint32)
+    if times.dtype == np.uint64:
+        t32 = np.where(times == _PAD_TIME_U64, PAD_TIME, t32)
+    return t32
+
+
 def bucket_cap(n: int, minimum: int = MIN_CAP) -> int:
     """Round `n` up to the next power of two (at least `minimum`)."""
     c = minimum
@@ -123,7 +144,53 @@ class UpdateBatch:
 
     @staticmethod
     def build(key_cols, val_cols, times, diffs, cap: int | None = None) -> "UpdateBatch":
-        """Build a padded device batch from host (or device) columns."""
+        """Build a padded device batch from host (or device) columns.
+
+        Host columns (NumPy arrays, lists: every ingest path) are assembled in
+        NumPy at the batch's capacity and transferred once, so no XLA program
+        ever holds the row count in its shape. Columns that already live on
+        the device are cast, hashed and padded there. Both give the same
+        batch bit for bit.
+        """
+        key_cols, val_cols = tuple(key_cols), tuple(val_cols)
+        if any(isinstance(c, jax.Array) for c in (*key_cols, *val_cols, times, diffs)):
+            _BUILDS.inc(path="device")
+            return UpdateBatch._build_on_device(key_cols, val_cols, times, diffs, cap)
+        _BUILDS.inc(path="host")
+        key_cols = tuple(host_column(c) for c in key_cols)
+        val_cols = tuple(host_column(c) for c in val_cols)
+        times = _host_time_view(host_column(times))
+        diffs = np.asarray(diffs, dtype=canonicalize_dtype(DIFF_DTYPE))
+        n = int(times.shape[0])
+        if cap is None:
+            cap = bucket_cap(n)
+        if key_cols:
+            hashes = hash_columns_np(key_cols)
+        else:
+            hashes = np.zeros((n,), dtype=np.uint32)
+
+        def padded(a, fill):
+            # always a fresh buffer: a zero-copy device_put must not alias
+            # an array the caller may write to again
+            if a.shape != (n,):
+                raise ValueError(f"column of shape {a.shape} in a batch of {n} rows")
+            out = np.empty((cap,), dtype=a.dtype)
+            out[:n] = a[:cap]
+            out[n:] = fill
+            return out
+
+        return jax.device_put(
+            UpdateBatch(
+                padded(hashes, PAD_HASH),
+                tuple(padded(k, 0) for k in key_cols),
+                tuple(padded(v, 0) for v in val_cols),
+                padded(times, PAD_TIME),
+                padded(diffs, 0),
+            )
+        )
+
+    @staticmethod
+    def _build_on_device(key_cols, val_cols, times, diffs, cap) -> "UpdateBatch":
         key_cols = tuple(jnp.asarray(c) for c in key_cols)
         val_cols = tuple(jnp.asarray(c) for c in val_cols)
         times = to_device_time(times)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
+from jax.dtypes import canonicalize_dtype
 
 # splitmix64 constants (public domain PRNG finalizer, Steele et al.)
 _C1 = np.uint64(0x9E3779B97F4A7C15)
@@ -41,6 +42,7 @@ _C3 = np.uint64(0x94D049BB133111EB)
 # Reserved sentinel: padding rows hash to PAD_HASH and sort to the end of
 # every batch. Real hashes are clamped below it.
 PAD_HASH = np.uint32(0xFFFFFFFF)
+_F32_TINY = np.finfo(np.float32).tiny
 
 
 def splitmix64(x: jnp.ndarray) -> jnp.ndarray:
@@ -113,8 +115,46 @@ def mix_columns(cols: tuple[jnp.ndarray, ...]) -> jnp.ndarray:
     return (h ^ (h >> np.uint64(32))).astype(jnp.uint32)
 
 
-def hash_columns_np(cols) -> np.ndarray:
-    """NumPy mirror of `hash_columns` (host-side oracle + batch construction)."""
-    import jax
+def host_column(col) -> np.ndarray:
+    """A host column (array, list) at the dtype `jnp.asarray` would give it."""
+    a = np.asarray(col)
+    return a.astype(canonicalize_dtype(a.dtype), copy=False)
 
-    return np.asarray(jax.device_get(hash_columns(tuple(jnp.asarray(c) for c in cols))))
+
+def _col_to_u64_np(col: np.ndarray) -> np.ndarray:
+    """`_col_to_u64` on the host: `value_view`'s canonicalisation, then u64."""
+    if col.dtype == np.bool_:
+        return col.astype(np.uint64)
+    if np.issubdtype(col.dtype, np.floating):
+        with np.errstate(over="ignore"):  # a float64 past float32's range is inf
+            f = col.astype(np.float32)
+        # -0.0 == 0.0; XLA (CPU and TPU) also reads a subnormal as zero there
+        f = np.where(np.abs(f) < _F32_TINY, np.float32(0.0), f)
+        f = np.where(np.isnan(f), np.float32(np.nan), f)  # canonical NaN
+        return f.view(np.uint32).astype(np.uint64)
+    return col.astype(np.uint64)  # signed ints sign-extend, as XLA's convert
+
+
+def _splitmix64_np(x: np.ndarray) -> np.ndarray:
+    x = x + _C1  # u64 array arithmetic wraps, as on the device
+    x = (x ^ (x >> np.uint64(30))) * _C2
+    x = (x ^ (x >> np.uint64(27))) * _C3
+    return x ^ (x >> np.uint64(31))
+
+
+def hash_columns_np(cols) -> np.ndarray:
+    """NumPy mirror of `hash_columns` (host-side oracle + batch construction).
+
+    Bit-identical to the device hash and never touches the device: columns
+    take the dtype `jnp.asarray` would give them, then the same splitmix64
+    mixing in `np.uint64` array arithmetic.
+    """
+    if not cols:
+        raise ValueError("hash_columns_np needs at least one column; use zeros for keyless")
+    cols = [host_column(c) for c in cols]
+    h = np.full(cols[0].shape, np.uint64(0x51ED270B_9B1F8C33), dtype=np.uint64)
+    for i, col in enumerate(cols):
+        salt = np.uint64(((i + 1) * int(_C1)) % (1 << 64))
+        h = _splitmix64_np(h ^ _splitmix64_np(_col_to_u64_np(col) + salt))
+    h32 = (h ^ (h >> np.uint64(32))).astype(np.uint32)  # fold to 32 bits
+    return np.where(h32 == PAD_HASH, PAD_HASH - np.uint32(1), h32)
