@@ -383,15 +383,7 @@ class Coordinator:
                 if stmt.system or getattr(self, "_session", None) is None
                 else self._session
             )
-            if stmt.name == "kernel_backend":
-                from ..ops.kernels import KERNEL_MODES
-
-                if str(stmt.value) not in KERNEL_MODES:
-                    raise PlanError(
-                        f"invalid value for kernel_backend: {stmt.value!r} "
-                        f"(expected one of {', '.join(KERNEL_MODES)})"
-                    )
-            elif stmt.name == "exchange_backend":
+            if stmt.name == "exchange_backend":
                 from ..parallel.devicemesh import EXCHANGE_MODES
 
                 if str(stmt.value) not in EXCHANGE_MODES:
@@ -420,12 +412,6 @@ class Coordinator:
                     bool(self._cfg().get("enable_jax_profiler")),
                     str(self._cfg().get("jax_profiler_dir")),
                 )
-            elif stmt.name == "kernel_backend":
-                from ..ops import kernels
-
-                # in-process dataflows pick the new backend up at their next
-                # tick render; remote clusterd replicas at CreateInstance
-                kernels.set_kernel_backend(str(self._cfg().get("kernel_backend")))
             return ExecResult("status", status="SET")
         if isinstance(stmt, ast.ResetVariable):
             if stmt.name not in self.configs.names():

@@ -269,19 +269,6 @@ JAX_PROFILER_DIR = Config(
     "trace collection)",
 )
 
-# -- kernel backend (ops/kernels/: Pallas vs XLA hot-path kernels) -----------
-KERNEL_BACKEND = Config(
-    "kernel_backend",
-    "auto",
-    "which implementation the registered hot-path kernels (run_sum, "
-    "multi_take, probe, probe2, route_dest, bucket_rank; ops/kernels/) "
-    "dispatch to: 'auto' picks xla on every platform (no registered pallas "
-    "program compiles for the chip yet), 'xla'/'pallas' force a backend "
-    "(pallas off-TPU runs in interpret mode — correct but slow, for "
-    "differential testing; on a TPU it raises the chip compiler's error); "
-    "takes effect at the next tick render, no restart",
-)
-
 # -- frontend backend (serve/: reactor vs thread-per-connection serving) -----
 FRONTEND_BACKEND = Config(
     "frontend_backend",
@@ -350,7 +337,6 @@ ALL_CONFIGS = [
     INTROSPECTION_INTERVAL,
     ENABLE_JAX_PROFILER,
     JAX_PROFILER_DIR,
-    KERNEL_BACKEND,
     EXCHANGE_BACKEND,
 ]
 

@@ -119,11 +119,6 @@ INTROSPECTION_TABLES = {
         ("emitted_updates", ColType.INT64),
         ("emitted_bytes", ColType.INT64),
     ),
-    "mz_kernel_dispatch": _desc(
-        ("kernel", ColType.STRING),
-        ("backend", ColType.STRING),
-        ("dispatches", ColType.INT64),
-    ),
     "mz_device_mesh": _desc(
         ("position", ColType.INT64),
         ("device", ColType.STRING),
@@ -278,19 +273,6 @@ def introspection_rows(coord, name: str) -> list[tuple]:
                 snk.frontier, snk.emitted_updates, snk.emitted_bytes,
             )
             for gid, snk in sorted(coord.sinks.items())
-        ]
-    if name == "mz_kernel_dispatch":
-        # per-(kernel, backend) dispatch counts from the ops/kernels registry.
-        # Counts TRACES, not executions (dispatch runs at trace time inside
-        # jit; cached executions don't re-dispatch) — so a nonzero pallas row
-        # proves the Pallas path actually compiled into the running programs.
-        from ..ops import kernels as _kernels
-
-        return [
-            (kernel, backend, count)
-            for (kernel, backend), count in sorted(
-                _kernels.dispatch_counts().items()
-            )
         ]
     if name == "mz_device_mesh":
         # one row per local device: mesh membership of the exchange plane

@@ -5,7 +5,6 @@ from .collective_rule import CollectiveCoherence
 from .crashsafety import CrashSwallow, DurableCleanup
 from .dtype64 import Dtype64
 from .hygiene import ListenerHygiene
-from .kernels_rule import KernelDispatchCoherence
 from .metrics_rule import MetricsCoherence
 from .races import LockDiscipline
 from .reactor_rule import ReactorDiscipline
@@ -25,7 +24,6 @@ ALL_RULES = [
     SqlstateCoherence(),
     CtpCoherence(),
     ListenerHygiene(),
-    KernelDispatchCoherence(),
     CollectiveCoherence(),
     MetricsCoherence(),
     ReactorDiscipline(),

@@ -49,7 +49,6 @@ SCOPE_DIRS = (
     "materialize_tpu/persist/",
     "materialize_tpu/storage/",
     "materialize_tpu/obs/",
-    "materialize_tpu/ops/kernels/",
 )
 
 

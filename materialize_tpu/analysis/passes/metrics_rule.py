@@ -30,7 +30,6 @@ REQUIRED_FAMILIES = (
     "mzt_mesh_exchange_bytes_total",
     "mzt_heartbeat_rtt_seconds",
     "mzt_dataflow_tick_duration_ns",
-    "mzt_kernel_dispatch_total",
     "mzt_device_exchange_programs_total",
     "mzt_device_exchange_mesh_devices",
     "mzt_device_exchange_retries_total",

@@ -59,7 +59,6 @@ SCOPE_DIRS = (
     "materialize_tpu/storage/",
     "materialize_tpu/obs/",
     "materialize_tpu/orchestrator/",
-    "materialize_tpu/ops/kernels/",
 )
 
 

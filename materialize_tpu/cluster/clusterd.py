@@ -179,14 +179,6 @@ class ClusterState:
                 bool(cfg.get("enable_jax_profiler", False)),
                 str(cfg.get("jax_profiler_dir", "")),
             )
-            # kernel backend likewise: the hot-path dispatches happen in this
-            # process's tick renders, so the mode must land here
-            from ..ops import kernels
-
-            try:
-                kernels.set_kernel_backend(str(cfg.get("kernel_backend", "auto")))
-            except ValueError:
-                pass  # unknown value in an old snapshot: keep the default
             # exchange backend is read per-render (_create_dataflow), not set
             # globally; sanitize here so an unknown value in an old snapshot
             # degrades to auto instead of failing every later render

@@ -790,19 +790,7 @@ class FusedDataflow:
             _collect_constants(bd.plan, self.consts)
         self.source_ids = list(self.desc.source_imports) + list(self.consts)
 
-        # capture the kernel backend at build time: every dispatch inside the
-        # tick trace resolves through this thread-local, so the backend is
-        # part of the compiled program — `step()` rebuilds (fresh jit cache)
-        # when the dyncfg mode flips, never serving a stale-backend trace
-        from ..ops import kernels
-
-        backend = self._kernel_backend = kernels.resolve_backend()
-
         def tick(state, deltas, time, since):
-            with kernels.using_backend(backend):
-                return tick_body(state, deltas, time, since)
-
-        def tick_body(state, deltas, time, since):
             ctx = _Ctx(
                 state_in=state,
                 state_out=dict(state),
@@ -929,13 +917,6 @@ class FusedDataflow:
         from ..obs import profiler as _prof
 
         t0 = _time.perf_counter_ns()
-        from ..ops import kernels as _kernels
-
-        if _kernels.resolve_backend() != self._kernel_backend:
-            # kernel_backend flipped since the last build: recompile so the
-            # next trace dispatches through the new backend (state shapes are
-            # unchanged, so no migration)
-            self._build()
         delta_cap = self._delta_cap()
         deltas: dict[str, UpdateBatch] = {}
         rows_in = 0

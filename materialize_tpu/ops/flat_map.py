@@ -61,20 +61,11 @@ def flat_map_total(batch: UpdateBatch, exprs) -> jnp.ndarray:
 
 def flat_map_materialize(batch: UpdateBatch, exprs, out_cap: int):
     """Returns (out, errs, overflow): out rows = input vals ++ series value."""
-    from . import kernels
-
-    return _flat_map_materialize(batch, exprs, out_cap, kernels.active_backend())
+    return _flat_map_materialize(batch, exprs, out_cap)
 
 
-@partial(jax.jit, static_argnames=("exprs", "out_cap", "backend"))
-def _flat_map_materialize(batch: UpdateBatch, exprs, out_cap: int, backend: str):
-    from . import kernels
-
-    with kernels.using_backend(backend):
-        return _flat_map_materialize_body(batch, exprs, out_cap)
-
-
-def _flat_map_materialize_body(batch: UpdateBatch, exprs, out_cap: int):
+@partial(jax.jit, static_argnames=("exprs", "out_cap"))
+def _flat_map_materialize(batch: UpdateBatch, exprs, out_cap: int):
     lo, st, count, err = _series_bounds(batch, exprs)
     cum = jnp.cumsum(count)
     total = cum[-1] if count.shape[0] > 0 else jnp.zeros((), dtype=cum.dtype)

@@ -43,30 +43,13 @@ def fused_mfp_reduce_step(
     """(state, Δin, t) → (state', Δout, Δerrs, counts) in one XLA program;
     `counts` is `reduce.step_counts` (live groups, groups whose output
     changed), so the caller's one host read needs no program of its own."""
-    from . import kernels
-
-    return _fused_mfp_reduce_step(
-        state, delta, time, mfp, key_cols, aggs, kernels.active_backend()
-    )
+    # forwards only: the harness wraps this un-jitted name and reads the
+    # device program `jit__fused_mfp_reduce_step` (chipbench/metrics/)
+    return _fused_mfp_reduce_step(state, delta, time, mfp, key_cols, aggs)
 
 
-@partial(jax.jit, static_argnames=("mfp", "key_cols", "aggs", "backend"))
+@partial(jax.jit, static_argnames=("mfp", "key_cols", "aggs"))
 def _fused_mfp_reduce_step(
-    state: AccumState,
-    delta: UpdateBatch,
-    time,
-    mfp: MapFilterProject,
-    key_cols: tuple[int, ...],
-    aggs: tuple,
-    backend: str,
-):
-    from . import kernels
-
-    with kernels.using_backend(backend):
-        return _fused_mfp_reduce_step_body(state, delta, time, mfp, key_cols, aggs)
-
-
-def _fused_mfp_reduce_step_body(
     state: AccumState,
     delta: UpdateBatch,
     time,

@@ -31,7 +31,8 @@ from ..repr.batch import (
     to_device_time,
 )
 from ..repr.hashing import PAD_HASH, hash_columns
-from . import kernels
+from .consolidate import _stable_partition_perm, row_equal_prev, run_sum
+from .permute import multi_take
 from .search import searchsorted, searchsorted2, sort_perm
 
 # Fast-path scan width for hash-bucket lookups. u32 row hashes make small
@@ -182,7 +183,7 @@ def _accum_take(s: AccumState, idx: jnp.ndarray) -> AccumState:
     """Gather every AccumState column at `idx` via the fused multi-column
     gather — one dtype-grouped pass instead of one XLA gather per column."""
     nk = len(s.keys)
-    g = kernels.multi_take((s.hashes, *s.keys, *s.accums, s.nrows), idx)
+    g = multi_take((s.hashes, *s.keys, *s.accums, s.nrows), idx)
     return AccumState(g[0], tuple(g[1 : 1 + nk]), tuple(g[1 + nk : -1]), g[-1])
 
 
@@ -195,11 +196,9 @@ def _consolidate_accums_sorted(s: AccumState):
     same-key rows that survived unmerged (possible only via a packed-key
     double collision between sources in the merge path) — callers surface
     it as a failed tick."""
-    from .consolidate import _stable_partition_perm, row_equal_prev
-
     run_start = ~row_equal_prev((s.hashes, *s.keys))
-    # segmented-sum-by-run kernel over every accumulator plus nrows at once
-    summed = kernels.dispatch("run_sum", run_start, (*s.accums, s.nrows))
+    # segmented sum by run over every accumulator plus nrows at once
+    summed = run_sum(run_start, (*s.accums, s.nrows))
     accums, nrows = summed[:-1], summed[-1]
     nonzero = nrows != 0
     for a in accums:
@@ -225,40 +224,36 @@ def _consolidate_accums_sorted(s: AccumState):
     return out, dup
 
 
-@partial(jax.jit, static_argnames=("backend",))
-def _consolidate_accums(s: AccumState, backend: str) -> AccumState:
-    with kernels.using_backend(backend):
-        p_hi, p_lo = _accum_pack(s)
-        order = sort_perm((*(k for k in reversed(s.keys)), p_lo, p_hi))
-        out, _dup = _consolidate_accums_sorted(_accum_take(s, order))
-        return out
+@jax.jit
+def _consolidate_accums(s: AccumState) -> AccumState:
+    p_hi, p_lo = _accum_pack(s)
+    order = sort_perm((*(k for k in reversed(s.keys)), p_lo, p_hi))
+    out, _dup = _consolidate_accums_sorted(_accum_take(s, order))
+    return out
 
 
 def consolidate_accums(s: AccumState) -> AccumState:
     """Order by (packed key, keys), sum accumulators of equal keys, drop
     empty groups. Keys tiebreak the sort, so equal keys are always adjacent
     here (no collision exposure on this path)."""
-    return _consolidate_accums(s, kernels.active_backend())
+    return _consolidate_accums(s)
 
 
-@partial(jax.jit, static_argnames=("backend",))
-def _merge_consolidate_accums(a: AccumState, b: AccumState, backend: str):
-    with kernels.using_backend(backend):
-        ka_hi, ka_lo = _accum_pack(a)
-        kb_hi, kb_lo = _accum_pack(b)
-        na, nb = a.cap, b.cap
-        pa = jnp.arange(na, dtype=jnp.int32) + searchsorted2(
-            kb_hi, kb_lo, ka_hi, ka_lo, side="left"
-        )
-        pb = jnp.arange(nb, dtype=jnp.int32) + searchsorted2(
-            ka_hi, ka_lo, kb_hi, kb_lo, side="right"
-        )
-        pos = jnp.concatenate([pa, pb])
-        iota = jnp.arange(na + nb, dtype=jnp.int32)
-        perm = (pos * 0).at[pos].set(iota)
-        return _consolidate_accums_sorted(
-            _accum_take(AccumState.concat(a, b), perm)
-        )
+@jax.jit
+def _merge_consolidate_accums(a: AccumState, b: AccumState):
+    ka_hi, ka_lo = _accum_pack(a)
+    kb_hi, kb_lo = _accum_pack(b)
+    na, nb = a.cap, b.cap
+    pa = jnp.arange(na, dtype=jnp.int32) + searchsorted2(
+        kb_hi, kb_lo, ka_hi, ka_lo, side="left"
+    )
+    pb = jnp.arange(nb, dtype=jnp.int32) + searchsorted2(
+        ka_hi, ka_lo, kb_hi, kb_lo, side="right"
+    )
+    pos = jnp.concatenate([pa, pb])
+    iota = jnp.arange(na + nb, dtype=jnp.int32)
+    perm = (pos * 0).at[pos].set(iota)
+    return _consolidate_accums_sorted(_accum_take(AccumState.concat(a, b), perm))
 
 
 def merge_consolidate_accums(a: AccumState, b: AccumState):
@@ -269,7 +264,7 @@ def merge_consolidate_accums(a: AccumState, b: AccumState):
     `dup` is the loud-failure flag for the 2^-64 packed-key double collision
     (see _accum_pack) — treated like a capacity overflow by callers, never a
     silent mis-aggregation."""
-    return _merge_consolidate_accums(a, b, kernels.active_backend())
+    return _merge_consolidate_accums(a, b)
 
 
 @partial(jax.jit, static_argnames=("key_cols", "aggs"))
@@ -345,16 +340,11 @@ def lookup_accums(state: AccumState, probe: AccumState):
     unsound and callers MUST surface an error rather than use it (the
     detect-and-error stance; silently treating the group as absent would be
     a wrong answer)."""
-    return _lookup_accums(state, probe, kernels.active_backend())
+    return _lookup_accums(state, probe)
 
 
-@partial(jax.jit, static_argnames=("backend",))
-def _lookup_accums(state: AccumState, probe: AccumState, backend: str):
-    with kernels.using_backend(backend):
-        return _lookup_accums_body(state, probe)
-
-
-def _lookup_accums_body(state: AccumState, probe: AccumState):
+@jax.jit
+def _lookup_accums(state: AccumState, probe: AccumState):
     lo = searchsorted(state.hashes, probe.hashes, side="left")
     hi = searchsorted(state.hashes, probe.hashes, side="right")
     from ..repr.hashing import value_view
@@ -388,7 +378,7 @@ def _lookup_accums_body(state: AccumState, probe: AccumState):
         lambda: scan(_WIDE_HASH_COLLISIONS),
         lambda: (found, idx),
     )
-    g = kernels.multi_take((*state.accums, state.nrows), idx)
+    g = multi_take((*state.accums, state.nrows), idx)
     accums = tuple(jnp.where(found, a, 0) for a in g[:-1])
     nrows = jnp.where(found, g[-1], 0)
     missed = probe.live & ~found & ((hi - lo) > _WIDE_HASH_COLLISIONS)

@@ -10,12 +10,11 @@ steps with no control flow at all — the accelerator-native scan formulation
 (cf. arXiv:2505.15112) and the gather-structured probe shape of
 hash-partitioned join hardware (cf. arXiv:1905.13376).
 
-Since PR 15 both searches are registry kernels (`probe` / `probe2`,
-ops/kernels/probe.py): the unrolled XLA lowering stays as the reference
-oracle, and the Pallas backend runs the identical loop with the sorted keys
-VMEM-resident. Dispatch resolves at trace time, so jitted callers must carry
-the active backend in their cache key (ops entry points thread a static
-``backend`` argument; see ops/kernels/registry.py).
+`searchsorted` is the single-key u32 search (join `_probe_ranges`, reduce
+`lookup_accums`, output-slot owner searches); `searchsorted2` is the two-key
+(hi, lo) pair search backing `merge_consolidate` / `merge_consolidate_accums`.
+Invariant per step: the insertion point lies in [pos, pos + cur]; all
+positions i32.
 
 `sort_perm` is the 32-bit `jnp.lexsort`: under x64, jnp's argsort/lexsort
 carry an i64 iota operand through the sort — a 64-bit operand the TPU splits
@@ -28,7 +27,17 @@ from __future__ import annotations
 import jax.lax as lax
 import jax.numpy as jnp
 
-from .kernels import dispatch
+
+def _pred(a_elem: jnp.ndarray, q: jnp.ndarray, side: str) -> jnp.ndarray:
+    return (a_elem < q) if side == "left" else (a_elem <= q)
+
+
+def _pred2(a_hi, a_lo, q_hi, q_lo, side: str) -> jnp.ndarray:
+    """(hi, lo) pair comparison: a < q (left) / a <= q (right) on the packed
+    64-bit order, evaluated entirely in 32-bit lanes."""
+    if side == "left":
+        return (a_hi < q_hi) | ((a_hi == q_hi) & (a_lo < q_lo))
+    return (a_hi < q_hi) | ((a_hi == q_hi) & (a_lo <= q_lo))
 
 
 def searchsorted(a: jnp.ndarray, q: jnp.ndarray, side: str = "left") -> jnp.ndarray:
@@ -36,9 +45,17 @@ def searchsorted(a: jnp.ndarray, q: jnp.ndarray, side: str = "left") -> jnp.ndar
 
     Returns i32 insertion points in [0, n]. ceil(log2(n)) + 1 unrolled
     steps; no data-dependent control flow (vectorizes on XLA:CPU and the
-    TPU VPU alike). Dispatches to the active kernel backend.
+    TPU VPU alike).
     """
-    return dispatch("probe", a, q, side=side)
+    n = int(a.shape[0])
+    pos = jnp.zeros(q.shape, dtype=jnp.int32)
+    cur = n
+    while cur > 1:
+        half = cur >> 1
+        mid = pos + (half - 1)  # compare a[pos + half - 1]
+        pos = jnp.where(_pred(a[mid], q, side), pos + half, pos)
+        cur -= half
+    return pos + _pred(a[pos], q, side).astype(jnp.int32)
 
 
 def searchsorted2(
@@ -52,9 +69,17 @@ def searchsorted2(
 
     The 32-bit replacement for searching a packed u64 key `(hi << 32) | lo`
     — same order, two u32 gathers per step instead of one split u64.
-    Dispatches to the active kernel backend.
     """
-    return dispatch("probe2", a_hi, a_lo, q_hi, q_lo, side=side)
+    n = int(a_hi.shape[0])
+    pos = jnp.zeros(q_hi.shape, dtype=jnp.int32)
+    cur = n
+    while cur > 1:
+        half = cur >> 1
+        mid = pos + (half - 1)
+        go = _pred2(a_hi[mid], a_lo[mid], q_hi, q_lo, side)
+        pos = jnp.where(go, pos + half, pos)
+        cur -= half
+    return pos + _pred2(a_hi[pos], a_lo[pos], q_hi, q_lo, side).astype(jnp.int32)
 
 
 def sort_perm(cols) -> jnp.ndarray:

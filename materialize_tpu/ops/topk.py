@@ -31,9 +31,8 @@ from ..repr.batch import (
     to_device_time,
 )
 from ..repr.hashing import PAD_HASH
-from . import kernels
 from .consolidate import advance_times, consolidate, row_equal_prev
-from .kernels import batch_permute
+from .permute import batch_permute, multi_take
 from .search import searchsorted, sort_perm
 
 
@@ -61,20 +60,15 @@ def distinct_keys(delta_keyed: UpdateBatch) -> UpdateBatch:
 
     Diffs are replaced by 1 (presence marker); vals dropped.
     """
-    return _distinct_keys(delta_keyed, kernels.active_backend())
+    return _distinct_keys(delta_keyed)
 
 
-@partial(jax.jit, static_argnames=("backend",))
-def _distinct_keys(delta_keyed: UpdateBatch, backend: str) -> UpdateBatch:
-    with kernels.using_backend(backend):
-        return _distinct_keys_body(delta_keyed)
-
-
-def _distinct_keys_body(delta_keyed: UpdateBatch) -> UpdateBatch:
+@jax.jit
+def _distinct_keys(delta_keyed: UpdateBatch) -> UpdateBatch:
     b = delta_keyed
     cols = [*(k for k in reversed(b.keys)), b.hashes]
     order = sort_perm(cols)
-    g = kernels.multi_take((b.hashes, *b.keys, b.live), order)
+    g = multi_take((b.hashes, *b.keys, b.live), order)
     h, ks, live_in = g[0], tuple(g[1:-1]), g[-1]
     same = row_equal_prev((h, *ks))
     # first live row of each (hash,key) run survives; a run may mix live and
@@ -91,7 +85,7 @@ def _distinct_keys_body(delta_keyed: UpdateBatch) -> UpdateBatch:
     hashes = jnp.where(first_live, h, PAD_HASH)
     keys = tuple(jnp.where(first_live, k, jnp.zeros_like(k)) for k in ks)
     perm = sort_perm((~first_live,))
-    g = kernels.multi_take(
+    g = multi_take(
         (
             hashes,
             *keys,
@@ -104,31 +98,23 @@ def _distinct_keys_body(delta_keyed: UpdateBatch) -> UpdateBatch:
 
 
 def _gather_total(probes: UpdateBatch, arr: UpdateBatch) -> jnp.ndarray:
-    return _gather_total_jit(probes, arr, kernels.active_backend())
+    return _gather_total_jit(probes, arr)
 
 
-@partial(jax.jit, static_argnames=("backend",))
-def _gather_total_jit(probes: UpdateBatch, arr: UpdateBatch, backend: str):
-    with kernels.using_backend(backend):
-        lo = searchsorted(arr.hashes, probes.hashes, side="left")
-        hi = searchsorted(arr.hashes, probes.hashes, side="right")
-        return jnp.sum(jnp.where(probes.live, hi - lo, 0))
+@jax.jit
+def _gather_total_jit(probes: UpdateBatch, arr: UpdateBatch):
+    lo = searchsorted(arr.hashes, probes.hashes, side="left")
+    hi = searchsorted(arr.hashes, probes.hashes, side="right")
+    return jnp.sum(jnp.where(probes.live, hi - lo, 0))
 
 
 def _gather_materialize(probes: UpdateBatch, arr: UpdateBatch, out_cap: int) -> UpdateBatch:
     """All arrangement rows whose key matches a probe key (collision-checked)."""
-    return _gather_materialize_jit(probes, arr, out_cap, kernels.active_backend())
+    return _gather_materialize_jit(probes, arr, out_cap)
 
 
-@partial(jax.jit, static_argnames=("out_cap", "backend"))
+@partial(jax.jit, static_argnames=("out_cap",))
 def _gather_materialize_jit(
-    probes: UpdateBatch, arr: UpdateBatch, out_cap: int, backend: str
-) -> UpdateBatch:
-    with kernels.using_backend(backend):
-        return _gather_materialize_body(probes, arr, out_cap)
-
-
-def _gather_materialize_body(
     probes: UpdateBatch, arr: UpdateBatch, out_cap: int
 ) -> UpdateBatch:
     lo = searchsorted(arr.hashes, probes.hashes, side="left")
@@ -145,7 +131,7 @@ def _gather_materialize_body(
 
     # one fused dtype-grouped gather for the whole arrangement payload
     a_row = batch_permute(arr, ai)
-    p_keys = kernels.multi_take(probes.keys, pi) if probes.keys else ()
+    p_keys = multi_take(probes.keys, pi)
     eq = jnp.ones((out_cap,), dtype=jnp.bool_)
     for pk, ak in zip(p_keys, a_row.keys):
         eq = eq & (value_view(pk) == value_view(ak))
@@ -187,24 +173,12 @@ def topk_select(
     boundary keeps the in-window portion of its diff. `nulls_last` per order
     column; None = pg default (last when ascending, first when descending).
     """
-    return _topk_select(
-        rows, order_by, limit, offset, time, nulls_last, kernels.active_backend()
-    )
+    return _topk_select(rows, order_by, limit, offset, time, nulls_last)
 
 
-@partial(
-    jax.jit,
-    static_argnames=("order_by", "limit", "offset", "nulls_last", "backend"),
-)
+@partial(jax.jit, static_argnames=("order_by", "limit", "offset", "nulls_last"))
 def _topk_select(
-    rows: UpdateBatch, order_by, limit, offset: int, time, nulls_last, backend: str
-) -> UpdateBatch:
-    with kernels.using_backend(backend):
-        return _topk_select_body(rows, order_by, limit, offset, time, nulls_last)
-
-
-def _topk_select_body(
-    rows: UpdateBatch, order_by, limit, offset: int, time, nulls_last=None
+    rows: UpdateBatch, order_by, limit, offset: int, time, nulls_last
 ) -> UpdateBatch:
     n = rows.cap
     d = jnp.maximum(rows.diffs, 0) * rows.live  # negative multiplicities ignored

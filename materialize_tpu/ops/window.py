@@ -34,6 +34,7 @@ import numpy as np
 from ..repr.batch import DIFF_DTYPE, I64_DTYPE, PAD_TIME, UpdateBatch, bucket_cap, to_device_time
 from ..repr.hashing import PAD_HASH, value_view
 from .consolidate import row_equal_prev
+from .permute import batch_permute
 from .search import searchsorted, sort_perm
 from .topk import _ord_view, distinct_keys, gather_groups, negate
 
@@ -100,22 +101,11 @@ def window_compute(rows: UpdateBatch, plan: WindowPlan, time, out_cap: int) -> U
     full row). Output: one instance per unit of multiplicity, vals = original
     row columns ++ one column per plan.funcs entry, every diff = 1.
     """
-    from . import kernels
-
-    return _window_compute(rows, plan, time, out_cap, kernels.active_backend())
+    return _window_compute(rows, plan, time, out_cap)
 
 
-@partial(jax.jit, static_argnames=("plan", "out_cap", "backend"))
+@partial(jax.jit, static_argnames=("plan", "out_cap"))
 def _window_compute(
-    rows: UpdateBatch, plan: WindowPlan, time, out_cap: int, backend: str
-) -> UpdateBatch:
-    from . import kernels
-
-    with kernels.using_backend(backend):
-        return _window_compute_body(rows, plan, time, out_cap)
-
-
-def _window_compute_body(
     rows: UpdateBatch, plan: WindowPlan, time, out_cap: int
 ) -> UpdateBatch:
     n = rows.cap
@@ -134,8 +124,6 @@ def _window_compute_body(
         sort_cols.append(value_view(k))
     sort_cols.append(rows.hashes)
     order = sort_perm(sort_cols)
-    from .kernels import batch_permute
-
     b = batch_permute(rows, order)
     d = (jnp.maximum(b.diffs, 0) * b.live).astype(DIFF_DTYPE)
 

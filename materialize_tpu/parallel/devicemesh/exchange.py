@@ -10,9 +10,9 @@ collective-coherence mzlint pass enforces that.
 
 Routing is static-shape: each device packs its rows into `n_dest` buckets of
 fixed capacity (destination = the shared `parallel/routing.route_mod` rule,
-rank-within-destination computed by one sort + segmented arange; both are
-registered kernels in `ops/kernels/route.py`), sends bucket i to device i,
-and flattens what it receives. Overflow (more rows for one destination than
+rank-within-destination computed by one sort + segmented arange:
+`route_dest` and `bucket_rank` below), sends bucket i to device i, and
+flattens what it receives. Overflow (more rows for one destination than
 bucket capacity) is detected and reported as a flag the host reacts to by
 re-running the tick with bigger buckets — the same pad-sentinel bucketing
 discipline used everywhere else in the engine (`repr/batch.py`).
@@ -28,11 +28,11 @@ import jax
 import jax.numpy as jnp
 
 from ...obs import metrics as obs_metrics
-from ...ops import kernels as _kernels
 from ...ops.search import sort_perm
 from ...repr.batch import PAD_TIME, UpdateBatch
 from ...repr.hashing import PAD_HASH
 from ..mesh import WORKERS
+from ..routing import route_mod
 
 try:
     _shard_map = jax.shard_map
@@ -63,6 +63,24 @@ def note_overflow_retry() -> None:
     _RETRIES.inc()
 
 
+def route_dest(hashes: jnp.ndarray, n_dest: int) -> jnp.ndarray:
+    """u32 hash → i32 destination shard: the rule the host mesh partitioner
+    uses (`parallel/routing.route_mod`), so device and host routing agree."""
+    return route_mod(hashes, n_dest).astype(jnp.int32)
+
+
+def bucket_rank(key_s: jnp.ndarray) -> jnp.ndarray:
+    """Rank of each row within its equal-key run of a sorted vector (the
+    bucket slot it scatters to): ``idx - cummax(run_start ? idx : -1)``."""
+    n = int(key_s.shape[0])
+    idx = jnp.arange(n, dtype=jnp.int32)
+    run_start = jnp.concatenate(
+        [jnp.ones((1,), dtype=jnp.bool_), key_s[1:] != key_s[:-1]]
+    )
+    first_idx = jax.lax.cummax(jnp.where(run_start, idx, -1))
+    return idx - first_idx
+
+
 def route_to_buckets(batch: UpdateBatch, n_dest: int, bucket_cap: int):
     """Pack rows into [n_dest, bucket_cap] buckets by hash % n_dest.
 
@@ -70,12 +88,12 @@ def route_to_buckets(batch: UpdateBatch, n_dest: int, bucket_cap: int):
     Dead rows (padding / diff 0) are not routed.
     """
     live = batch.live
-    dest = _kernels.dispatch("route_dest", batch.hashes, n_dest)
+    dest = route_dest(batch.hashes, n_dest)
     key = jnp.where(live, dest, n_dest)  # dead rows to a discard bucket
     order = sort_perm((key,))  # stable, i32 iota — no 64-bit sort operand
     key_s = key[order]
     # rank within each destination run
-    rank = _kernels.dispatch("bucket_rank", key_s)
+    rank = bucket_rank(key_s)
     overflow = jnp.any((key_s < n_dest) & (rank >= bucket_cap))
     ok = (key_s < n_dest) & (rank < bucket_cap)
     # non-routed rows scatter OUT OF BOUNDS so mode="drop" discards them —

@@ -2,11 +2,11 @@
 
 A row's destination shard is ``u32_key_hash % n_dest``, computed in u32 —
 never widened, never re-hashed. `netexchange.route_dests` (host-staged
-cross-process partitioning) and the device plane's exchange kernels
-(`ops/kernels/route.py`, dispatched from `parallel/devicemesh/exchange.py`)
-both call :func:`route_mod`, so device and host partitioning are provably
-identical: an insert routed by the host mesh and its retraction routed by an
-on-device `all_to_all` land on the same owner (the bit-equal-routing
+cross-process partitioning) and the device plane's `route_dest`
+(`parallel/devicemesh/exchange.py`) both call :func:`route_mod`, so device
+and host partitioning are provably identical: an insert routed by the host
+mesh and its retraction routed by an on-device `all_to_all` land on the same
+owner (the bit-equal-routing
 invariant the mixed-mesh differentials rely on; motivated by the pure-
 hash-function routing discipline of multiway hash joins on reconfigurable
 hardware, PAPERS.md).

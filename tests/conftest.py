@@ -45,10 +45,6 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Pallas registers its tpu-platform lowering rules at import time; import it
-# here so every test module sees one consistent registration order.
-from jax.experimental import pallas as _pallas  # noqa: E402,F401
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

@@ -470,74 +470,6 @@ def test_ctp_coherence_quiet_when_dispatched():
     )
 
 
-# -- kernel-dispatch-coherence ------------------------------------------------
-
-KERNELS_OK = """
-    from . import registry
-
-    def _xla_take(cols, idx):
-        return cols
-
-    def _pallas_take(cols, idx):
-        import jax
-        from jax.experimental import pallas as pl
-
-        return pl.pallas_call(
-            lambda i, o: None,
-            out_shape=jax.ShapeDtypeStruct((1, 1), int),
-            interpret=registry.pallas_interpret(),
-        )(cols)
-
-    registry.register_kernel("take", xla=_xla_take, pallas=_pallas_take)
-
-    def take(cols, idx):
-        return registry.dispatch("take", cols, idx)
-"""
-
-
-def test_kernel_coherence_quiet_on_dual_backend_registration():
-    assert not run(
-        proj(materialize_tpu__ops__kernels__take=KERNELS_OK),
-        "kernel-dispatch-coherence",
-    )
-
-
-def test_kernel_coherence_flags_single_backend_registration():
-    src = KERNELS_OK.replace(", pallas=_pallas_take", "")
-    fs = run(
-        proj(materialize_tpu__ops__kernels__take=src),
-        "kernel-dispatch-coherence",
-    )
-    assert any("pallas=" in f.message for f in fs), fs
-
-
-def test_kernel_coherence_flags_bare_interpret_constant():
-    src = KERNELS_OK.replace("interpret=registry.pallas_interpret()", "interpret=True")
-    fs = run(
-        proj(materialize_tpu__ops__kernels__take=src),
-        "kernel-dispatch-coherence",
-    )
-    assert any("pallas_interpret" in f.message for f in fs), fs
-
-
-def test_kernel_coherence_flags_pallas_call_outside_kernels_dir():
-    fs = run(
-        proj(materialize_tpu__ops__rogue=KERNELS_OK),
-        "kernel-dispatch-coherence",
-    )
-    assert any("outside" in f.message for f in fs), fs
-
-
-def test_kernel_coherence_flags_dispatch_registration_mismatch():
-    src = KERNELS_OK.replace('dispatch("take"', 'dispatch("tkae"')
-    fs = run(
-        proj(materialize_tpu__ops__kernels__take=src),
-        "kernel-dispatch-coherence",
-    )
-    msgs = " | ".join(f.message for f in fs)
-    assert "never registered" in msgs and "never dispatched" in msgs, fs
-
-
 # -- collective-coherence ------------------------------------------------------
 
 MESH_DEF = """

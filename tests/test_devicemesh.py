@@ -3,8 +3,8 @@
 Runs on the 8-device virtual CPU mesh (conftest forces
 ``--xla_force_host_platform_device_count=8``), the stand-in for real
 multi-chip ICI. Fast tier: the routing invariant (device destinations ==
-host destinations for every dtype mix), route-kernel backend bit-identity,
-exchange-mode resolution, dyncfg validation, and the host force-disable.
+host destinations for every dtype mix), exchange-mode resolution, dyncfg
+validation, and the host force-disable.
 Slow tier: the Q3 SQL differential across {host, single fused, 8-device
 device mesh} with durable MV shard comparison, the mid-run
 ``exchange_backend`` flip, the zero-host-transfer guard, and the
@@ -59,12 +59,12 @@ DTYPE_MIXES = [
 @pytest.mark.parametrize("mix", DTYPE_MIXES, ids=["_".join(m) for m in DTYPE_MIXES])
 def test_route_dests_device_matches_host(mix, n_workers):
     """The ONE routing rule (parallel/routing.route_mod): destinations the
-    device plane computes through the route_dest kernel are identical to the
+    device plane computes through `route_dest` are identical to the
     host plane's netexchange.route_dests for every supported dtype mix —
     including the float canonicalizations (NaN = the float NULL sentinel,
     -0.0 == 0.0) that make an insert and its retraction co-locate even when
     one is routed by each plane."""
-    from materialize_tpu.ops import kernels
+    from materialize_tpu.parallel.devicemesh.exchange import route_dest
     from materialize_tpu.parallel.netexchange import route_dests
     from materialize_tpu.repr.hashing import hash_columns
 
@@ -89,38 +89,11 @@ def test_route_dests_device_matches_host(mix, n_workers):
         host = route_dests(host_cols, key_cols, n_workers)
         picked = cols if key_cols is None else [cols[i] for i in key_cols]
         hashes = hash_columns(tuple(jnp.asarray(c) for c in picked))
-        dev = kernels.dispatch("route_dest", hashes, n_workers)
+        dev = route_dest(hashes, n_workers)
         assert (np.asarray(dev) == host).all(), (mix, n_workers, key_cols)
         assert (host >= 0).all() and (host < n_workers).all()
     # keyless groups co-locate on worker 0 in both planes
     assert (route_dests(host_cols, (), n_workers) == 0).all()
-
-
-def test_route_kernels_pallas_bit_identical():
-    """route_dest / bucket_rank: Pallas programs == their XLA oracles bit for
-    bit (the PR 15 registry contract), including a non-power-of-two length
-    for the bucket_rank max-scan."""
-    from materialize_tpu.ops import kernels
-
-    rng = np.random.default_rng(7)
-    h = jnp.asarray(rng.integers(0, 2**32, 513, dtype=np.uint64).astype(np.uint32))
-    key_s = jnp.asarray(np.sort(rng.integers(0, 9, 129).astype(np.uint32)))
-    out = {}
-    for backend in ("xla", "pallas"):
-        with kernels.using_backend(backend):
-            out[backend] = (
-                kernels.dispatch("route_dest", h, 5),
-                kernels.dispatch("bucket_rank", key_s),
-            )
-    for a, b in zip(out["xla"], out["pallas"]):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        assert (np.asarray(a) == np.asarray(b)).all()
-    # slot ranks really are per-run ranks: 0,1,2,... within each key run
-    ranks = np.asarray(out["xla"][1])
-    keys = np.asarray(key_s)
-    for i in range(len(keys)):
-        expect = i - int(np.searchsorted(keys, keys[i], side="left"))
-        assert ranks[i] == expect
 
 
 # -- mode resolution + introspection rows -------------------------------------
