@@ -64,9 +64,11 @@ from ..repr.batch import MIN_CAP, UpdateBatch, bucket_cap, device_time_scalar
 from ..repr.hashing import hash_columns
 
 # Head capacity over the bucket of the delta that starts it. On a TPU v5e the
-# (T, d) merge of a 16,384-row, 9-column delta takes 34 / 112 / 382 ms at
-# ratios 4 / 16 / 64 (PERF.md §6, PR 29): the price of a merge against how
-# often a head spills; 4 against 16 is PERF.md's open question 0e.
+# (T, d) merge of a 16,384-row, 10-column delta takes 19 / 54 / 147 ms at
+# ratios 4 / 16 / 64 (PERF.md §6, PR 33; 34 / 112 / 378 ms before
+# `merge_perm`, when the head's rows were searched into the delta too): the
+# price of a merge against how often a head spills; 4 against 16 is PERF.md's
+# open question 0e.
 HEAD_RATIO = 16
 
 _HEAD_MERGES = obs_metrics.REGISTRY.counter(

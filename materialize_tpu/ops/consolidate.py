@@ -6,11 +6,11 @@ order by a packed u64 (key_hash<<32 | row_hash) with time as tiebreak,
 segmented prefix-sum of diffs over equal-row runs, annihilated (diff==0) rows
 masked to padding and compacted to the front. O(n log n) once per batch —
 and, critically, NOT per merge: two batches that are already in canonical
-order merge in O(n) via `merge_consolidate` (searchsorted interleave, no
-sort), and live rows compact in O(n) via a cumsum stable partition instead of
-an argsort. The r4 profile showed the per-tick consolidation sorts were ~70%
-of tick time; the merge/compact paths remove the sorts whose inputs are
-already ordered.
+order merge in O(n) via `merge_consolidate` (the shorter side ranked into
+the longer, no sort), and live rows compact in O(n) via a cumsum stable
+partition instead of an argsort. The r4 profile showed the per-tick
+consolidation sorts were ~70% of tick time; the merge/compact paths remove
+the sorts whose inputs are already ordered.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..repr.batch import PAD_TIME, UpdateBatch
 from ..repr.hashing import PAD_HASH
 from .permute import batch_permute
-from .search import searchsorted2, sort_perm
+from .search import merge_perm, sort_perm
 
 
 def row_equal_prev(cols) -> jnp.ndarray:
@@ -195,16 +195,7 @@ def _merge_consolidate(
 ) -> UpdateBatch:
     ka_hi, ka_lo = pack_sort_key(a)
     kb_hi, kb_lo = pack_sort_key(b)
-    na, nb = a.cap, b.cap
-    pa = jnp.arange(na, dtype=jnp.int32) + searchsorted2(
-        kb_hi, kb_lo, ka_hi, ka_lo, side="left"
-    )
-    pb = jnp.arange(nb, dtype=jnp.int32) + searchsorted2(
-        ka_hi, ka_lo, kb_hi, kb_lo, side="right"
-    )
-    pos = jnp.concatenate([pa, pb])
-    iota = jnp.arange(na + nb, dtype=jnp.int32)
-    perm = (pos * 0).at[pos].set(iota)
+    perm = merge_perm(ka_hi, ka_lo, kb_hi, kb_lo)
     cat = batch_permute(UpdateBatch.concat(a, b), perm)
     if since is not None:
         cat = advance_times(cat, since)
@@ -224,10 +215,12 @@ def merge_consolidate(
 
     The LSM merge fast path: both inputs are `consolidate` outputs (every
     spine level and every arranged delta is), so instead of re-sorting the
-    concatenation the merged order comes from two searchsorted passes over
-    the packed keys — the differential spine's cursor merge
-    (src/compute/src/render/join/mz_join_core.rs-adjacent batch merger),
-    vectorized. Output capacity = a.cap + b.cap, live rows compacted to the
+    concatenation the merged order comes from `merge_perm` over the packed
+    keys: one searchsorted pass of the SHORTER side into the longer, a mark
+    of the slots it takes and a prefix sum — the differential spine's cursor
+    merge (src/compute/src/render/join/mz_join_core.rs-adjacent batch
+    merger), vectorized. A head merge (T, T/16) searches its delta's rows
+    only. Output capacity = a.cap + b.cap, live rows compacted to the
     front, or `out_cap` when given: padded, or truncated, which is sound only
     if the caller knows the live rows fit (rows beyond `out_cap` are dropped
     unseen — the spine's head keeps a host-side bound, arrangement/spine.py).

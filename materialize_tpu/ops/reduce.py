@@ -33,7 +33,7 @@ from ..repr.batch import (
 from ..repr.hashing import PAD_HASH, hash_columns
 from .consolidate import _stable_partition_perm, row_equal_prev, run_sum
 from .permute import multi_take
-from .search import searchsorted, searchsorted2, sort_perm
+from .search import merge_perm, searchsorted, sort_perm
 
 # Fast-path scan width for hash-bucket lookups. u32 row hashes make small
 # buckets routine at scale (birthday collisions from ~2^16 keys), so lookups
@@ -243,16 +243,7 @@ def consolidate_accums(s: AccumState) -> AccumState:
 def _merge_consolidate_accums(a: AccumState, b: AccumState):
     ka_hi, ka_lo = _accum_pack(a)
     kb_hi, kb_lo = _accum_pack(b)
-    na, nb = a.cap, b.cap
-    pa = jnp.arange(na, dtype=jnp.int32) + searchsorted2(
-        kb_hi, kb_lo, ka_hi, ka_lo, side="left"
-    )
-    pb = jnp.arange(nb, dtype=jnp.int32) + searchsorted2(
-        ka_hi, ka_lo, kb_hi, kb_lo, side="right"
-    )
-    pos = jnp.concatenate([pa, pb])
-    iota = jnp.arange(na + nb, dtype=jnp.int32)
-    perm = (pos * 0).at[pos].set(iota)
+    perm = merge_perm(ka_hi, ka_lo, kb_hi, kb_lo)
     return _consolidate_accums_sorted(_accum_take(AccumState.concat(a, b), perm))
 
 

@@ -12,9 +12,13 @@ hash-partitioned join hardware (cf. arXiv:1905.13376).
 
 `searchsorted` is the single-key u32 search (join `_probe_ranges`, reduce
 `lookup_accums`, output-slot owner searches); `searchsorted2` is the two-key
-(hi, lo) pair search backing `merge_consolidate` / `merge_consolidate_accums`.
-Invariant per step: the insertion point lies in [pos, pos + cur]; all
-positions i32.
+(hi, lo) pair search. Invariant per step: the insertion point lies in
+[pos, pos + cur]; all positions i32.
+
+`merge_perm` is the stable-merge permutation of two (hi, lo)-sorted sides
+backing `merge_consolidate` / `merge_consolidate_accums`: one `searchsorted2`
+of the SHORTER side into the longer, a scatter of that many marks and one
+prefix sum. The longer side is never searched.
 
 `sort_perm` is the 32-bit `jnp.lexsort`: under x64, jnp's argsort/lexsort
 carry an i64 iota operand through the sort — a 64-bit operand the TPU splits
@@ -80,6 +84,37 @@ def searchsorted2(
         pos = jnp.where(go, pos + half, pos)
         cur -= half
     return pos + _pred2(a_hi[pos], a_lo[pos], q_hi, q_lo, side).astype(jnp.int32)
+
+
+def merge_perm(
+    a_hi: jnp.ndarray, a_lo: jnp.ndarray, b_hi: jnp.ndarray, b_lo: jnp.ndarray
+) -> jnp.ndarray:
+    """Gather permutation of the stable merge of two (hi, lo)-sorted sides.
+
+    `concat(a, b)[perm]` is sorted by (hi, lo) with `a`'s rows before `b`'s
+    among equal pairs; i32, length na + nb. Only the shorter side is ranked:
+    its rows' output slots `j + #{rows of the other side before it}` are
+    strictly increasing, the other side fills the remaining slots in its own
+    order, so a 0/1 mark of those slots and its inclusive prefix sum `c` name
+    every slot's source: the c-th row of the short side where marked, the
+    (p - c)-th row of the long side elsewhere. A head merge (T, T/16) thus
+    searches T/16 rows, not T + T/16. (The mark's zeros derive from the data
+    so varying manual axes match under shard_map.)
+    """
+    na, nb = int(a_hi.shape[0]), int(b_hi.shape[0])
+    b_short = nb <= na
+    if b_short:  # b's rows go after a's equal ones
+        rank = searchsorted2(a_hi, a_lo, b_hi, b_lo, side="right")
+    else:  # a's rows go before b's equal ones
+        rank = searchsorted2(b_hi, b_lo, a_hi, a_lo, side="left")
+    slot = lax.iota(jnp.int32, rank.shape[0]) + rank
+    zeros = jnp.broadcast_to((a_hi[:1] * 0).astype(jnp.int32), (na + nb,))
+    mark = zeros.at[slot].set(1, indices_are_sorted=True, unique_indices=True)
+    c = jnp.cumsum(mark)
+    p = lax.iota(jnp.int32, na + nb)
+    # rows of `b` sit behind `a`'s na in the concatenation
+    short_row, long_row = (na + c - 1, p - c) if b_short else (c - 1, na + p - c)
+    return jnp.where(mark != 0, short_row, long_row)
 
 
 def sort_perm(cols) -> jnp.ndarray:

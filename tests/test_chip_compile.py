@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from materialize_tpu.ops.consolidate import run_sum
 from materialize_tpu.ops.permute import multi_take
-from materialize_tpu.ops.search import searchsorted, searchsorted2
+from materialize_tpu.ops.search import merge_perm, searchsorted, searchsorted2
 from materialize_tpu.parallel.devicemesh.exchange import bucket_rank, route_dest
 from materialize_tpu.repr import UpdateBatch
 from materialize_tpu.repr.batch import DIFF_DTYPE, TIME_DTYPE
@@ -80,6 +80,12 @@ def _primitives(s):
         "searchsorted2": (
             searchsorted2, tuple(_col(s, U32) for _ in range(4)), {"side": "right"}
         ),
+        # a head merge's order: the N-row head against its N / 16-row delta
+        "merge_perm": (
+            merge_perm,
+            (_col(s, U32), _col(s, U32), _col(s, U32, N // 16), _col(s, U32, N // 16)),
+            {},
+        ),
         "route_dest": (route_dest, (_col(s, U32),), {"n_dest": 4}),
         "bucket_rank": (bucket_rank, (_col(s, I32),), {}),
     }
@@ -87,7 +93,10 @@ def _primitives(s):
 
 @pytest.mark.parametrize(
     "name",
-    ("run_sum", "multi_take", "searchsorted", "searchsorted2", "route_dest", "bucket_rank"),
+    (
+        "run_sum", "multi_take", "searchsorted", "searchsorted2", "merge_perm",
+        "route_dest", "bucket_rank",
+    ),
 )
 def test_xla_lowering_compiles_for_v5e(one_chip, name):
     fn, args, static = _primitives(one_chip)[name]
@@ -113,27 +122,32 @@ def test_consolidate_sort_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
 
 
-def test_head_merge_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize(
+    "vals,d",
+    [(6, 1 << 14), (1, 1 << 15)],
+    ids=["lineitem_262144_16384", "q17_averages_524288_32768"],
+)
+def test_head_merge_compiles_for_v5e(one_chip, vals, d):
     """The one program a refresh runs per arrangement (arrangement/spine.py):
-    a 16,384-row lineitem delta merged into the fixed-capacity head, padded
-    and truncated to the head's capacity inside the program."""
+    a delta merged into the fixed-capacity head, padded and truncated to the
+    head's capacity inside the program. At the two widest shapes the
+    benchmark's cells run: lineitem's 16,384-row delta, and the 32,768-row
+    delta of Q17's per-part averages."""
     from materialize_tpu.arrangement.spine import HEAD_RATIO
 
-    d = 1 << 14
-
-    def lineitem(n):
+    def rows(n):  # hash, one i64 key, `vals` i64 columns, time, diff
         return UpdateBatch(
             _col(one_chip, U32, n),
             (_col(one_chip, I64, n),),
-            tuple(_col(one_chip, I64, n) for _ in range(6)),
+            tuple(_col(one_chip, I64, n) for _ in range(vals)),
             _col(one_chip, TIME_DTYPE, n),
             _col(one_chip, DIFF_DTYPE, n),
         )
 
     consolidate_mod = importlib.import_module("materialize_tpu.ops.consolidate")
     compiled = consolidate_mod._merge_consolidate.lower(
-        lineitem(HEAD_RATIO * d),
-        lineitem(d),
+        rows(HEAD_RATIO * d),
+        rows(d),
         jax.ShapeDtypeStruct((), TIME_DTYPE, sharding=one_chip),
         out_cap=HEAD_RATIO * d,
     ).compile()

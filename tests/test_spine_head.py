@@ -8,6 +8,8 @@ must give: inserts and probes that ask XLA for no program once the head's
 
 import importlib
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -261,6 +263,155 @@ def test_shared_trace_views_with_a_head_and_a_staged_delta():
     assert tr.batches_thru(3)[-1] is tr.delta
     nb, cap, rec = tr.state_info()
     assert (nb, rec) == (3, 200 + 4 + 5 + 4)
+
+
+def _canonical_batch(rng, n, cap, keys=40, ticks=4):
+    """A `consolidate` output: `n` random updates over few keys (heavy ties in
+    the packed key, rows equal but for their time) padded to `cap`."""
+    ks = rng.integers(0, keys, n)
+    vs = rng.integers(0, 3, n)
+    b = mkbatch([ks, vs], rng.integers(0, ticks, n), rng.choice([-1, 1, 2], n))
+    return arrange_batch(b, (0,)).with_capacity(cap)
+
+
+def _same_arrays(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("out_cap", [None, "padded", "truncated"])
+@pytest.mark.parametrize("since", [None, 2])
+@pytest.mark.parametrize("na,nb", [(256, 16), (16, 256), (64, 64)])
+def test_merge_consolidate_is_bit_identical_to_the_two_search_merge(
+    rng, na, nb, since, out_cap
+):
+    """`merge_perm` changed how the merge order is built, not the order: the
+    output equals, array for array, the program of PRs 29-32 (its position
+    construction frozen in test_search32.py), and holds the multiset of
+    `consolidate(concat(a, b))`."""
+    from materialize_tpu.ops import consolidate
+    from materialize_tpu.ops.consolidate import (
+        _consolidate_sorted,
+        advance_times,
+        merge_consolidate,
+        pack_sort_key,
+    )
+    from materialize_tpu.ops.permute import batch_permute
+    from materialize_tpu.repr import UpdateBatch
+    from materialize_tpu.repr.batch import TIME_DTYPE
+    from test_search32 import two_search_merge_perm
+
+    a = _canonical_batch(rng, na // 4, na)
+    b = _canonical_batch(rng, nb // 4, nb)
+    cap = {None: None, "padded": 2 * (na + nb), "truncated": max(na, nb)}[out_cap]
+    frontier = None if since is None else jnp.asarray(since, TIME_DTYPE)
+    got = merge_consolidate(a, b, frontier, cap)
+
+    old = batch_permute(
+        UpdateBatch.concat(a, b),
+        two_search_merge_perm(*pack_sort_key(a), *pack_sort_key(b)),
+    )
+    if frontier is not None:
+        old = advance_times(old, frontier)
+    old = _consolidate_sorted(old, compact=True)
+    _same_arrays(got, old if cap is None else old.with_capacity(cap))
+
+    cat = UpdateBatch.concat(a, b)
+    if frontier is not None:
+        cat = advance_times(cat, frontier)
+    assert contents([got], 0) == contents([consolidate(cat)], 0)
+    assert got.cap == (na + nb if cap is None else cap)
+
+
+@pytest.mark.parametrize("na,nb", [(256, 16), (16, 256), (64, 64)])
+def test_merge_consolidate_accums_is_bit_identical_to_the_two_search_merge(rng, na, nb):
+    from materialize_tpu.ops.reduce import (
+        AccumState,
+        _accum_pack,
+        _accum_take,
+        _consolidate_accums_sorted,
+        consolidate_accums,
+        merge_consolidate_accums,
+    )
+    from materialize_tpu.repr import hash_columns
+    from test_search32 import two_search_merge_perm
+
+    def table(n, cap):
+        ks = jnp.asarray(rng.permutation(3 * n)[:n].astype(np.int64))
+        s = AccumState(
+            hash_columns((ks,)),
+            (ks,),
+            (jnp.asarray(rng.integers(-5, 6, n)), jnp.asarray(rng.integers(1, 4, n))),
+            jnp.asarray(rng.choice([-1, 1, 2], n)),
+        )
+        return consolidate_accums(s.with_capacity(cap))
+
+    # keys overlap between the sides, some sums cancel to empty groups
+    a, b = table(na // 2, na), table(nb // 2, nb)
+    got, dup = merge_consolidate_accums(a, b)
+    old, old_dup = _consolidate_accums_sorted(
+        _accum_take(
+            AccumState.concat(a, b),
+            two_search_merge_perm(*_accum_pack(a), *_accum_pack(b)),
+        )
+    )
+    _same_arrays(got, old)
+    assert bool(dup) == bool(old_dup) is False
+    def groups(s):
+        live = np.asarray(s.live)
+        cols = [np.asarray(c)[live] for c in (*s.keys, *s.accums, s.nrows)]
+        return sorted(zip(*(c.tolist() for c in cols)))
+
+    want = groups(consolidate_accums(AccumState.concat(a, b)))
+    assert groups(got) == want and len(want) > max(na, nb) // 2
+
+
+def _gathers_and_scatters(jaxpr):
+    """(rows gathered, ...) and (rows scattered, ...) of every gather and
+    scatter in a jaxpr, nested programs included."""
+    gathers, scatters = [], []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather":
+            gathers.append(eqn.outvars[0].aval.shape[-1])  # (n,) or stacked (k, n)
+        elif name.startswith("scatter"):
+            scatters.append(eqn.invars[2].aval.shape[0])  # the updates
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (tuple, list)) else (sub,):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    g, sc = _gathers_and_scatters(inner)
+                    gathers += g
+                    scatters += sc
+    return gathers, scatters
+
+
+def test_a_head_merge_searches_the_delta_only():
+    """The shape of the head-merge program, counted on the CPU: at (16 d, d)
+    no binary-search step gathers T rows (the program of PRs 29-32 had 30
+    such gathers: every head row searched into the delta), the only gathers
+    of T rows or more are the payload's, and one scatter fewer runs over
+    T + d rows (the inverse of `pos` is gone; the mark scatters d)."""
+    from materialize_tpu.ops.consolidate import _merge_consolidate
+    from materialize_tpu.repr.batch import MIN_CAP
+
+    d = MIN_CAP
+    t = HEAD_RATIO * d
+    a = _canonical_batch(np.random.default_rng(0), t // 4, t)
+    b = _canonical_batch(np.random.default_rng(1), d // 2, d)
+    jaxpr = jax.make_jaxpr(lambda x, y: _merge_consolidate(x, y, None, out_cap=t))(a, b)
+    gathers, scatters = _gathers_and_scatters(jaxpr.jaxpr)
+    steps = t.bit_length()  # ceil(log2(t)) + 1 steps of the search into the head
+    assert sum(g == d for g in gathers) == 2 * steps  # (hi, lo) per step
+    assert sum(g == t for g in gathers) == 0  # was 2 * (ceil(log2(d)) + 1)
+    # what is left at T + d rows is `_consolidate_sorted`'s and the payload's,
+    # gathered by dtype group into merged order and again compacted
+    # (ops/permute.py); the position arithmetic gathers nothing at this size
+    long_gathers = sum(g >= t for g in gathers)
+    assert 0 < long_gathers <= 2 * len(jax.tree_util.tree_leaves(a))
+    assert len(gathers) == 2 * steps + long_gathers
+    assert sorted(scatters) == [d, t + d, t + d]  # mark; run ends; compaction
 
 
 KERNELS = {
