@@ -32,13 +32,14 @@ def build_tpu_side(sf, ticks, frac, seed, scale=1):
 
     import materialize_tpu  # noqa: F401
     from materialize_tpu.models.fused_q3 import Q3Caps, Q3State, q3_tick_single
+    from materialize_tpu.models.tpch import Q3_COLUMNS
     from materialize_tpu.repr.batch import bucket_cap
     from materialize_tpu.storage import TpchGenerator
 
-    gen = TpchGenerator(sf=sf, seed=seed, val_dtype=np.int32)
+    gen = TpchGenerator(sf=sf, seed=seed, val_dtype=np.int32, columns=Q3_COLUMNS)
     init = gen.initial_batches(1)
     n_orders = gen.n_orders
-    n_li = len(gen._lineitem_store[0]) if gen._lineitem_store else int(4 * n_orders)
+    n_li = len(gen.live()["lineitem"]["l_orderkey"])
     per_tick = (int(n_orders * frac * 2 * 5.5) + 64) * scale
     caps = Q3Caps(
         cust=bucket_cap(max(gen.n_customer // 4, 64) * scale),
@@ -180,15 +181,15 @@ class NumpyQ3:
 
 
 def run_cpu_baseline(sf, ticks, frac, seed=0):
-    from materialize_tpu.models.tpch import BUILDING, Q3_DATE
+    from materialize_tpu.models.tpch import BUILDING, Q3_COLUMNS, Q3_DATE
     from materialize_tpu.storage import TpchGenerator
 
-    gen = TpchGenerator(sf=sf, seed=seed)
+    gen = TpchGenerator(sf=sf, seed=seed, columns=Q3_COLUMNS)
     t = gen.initial()
-    maintainer = NumpyQ3(t.customer, Q3_DATE, BUILDING)
-    n0 = len(t.orders[0])
-    maintainer.tick(t.orders, np.ones(n0, dtype=np.int64), t.lineitem,
-                    np.ones(len(t.lineitem[0]), dtype=np.int64))
+    maintainer = NumpyQ3(t["customer"], Q3_DATE, BUILDING)
+    n0 = len(t["orders"][0])
+    maintainer.tick(t["orders"], np.ones(n0, dtype=np.int64), t["lineitem"],
+                    np.ones(len(t["lineitem"][0]), dtype=np.int64))
 
     refreshes = []
     for tk in range(2, 2 + ticks):

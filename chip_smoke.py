@@ -276,9 +276,7 @@ class Served:
         building = self.coord.catalog.dict.lookup("BUILDING")
         check(building is not None, "BUILDING is not in the catalog dictionary")
         want = tpch.q3_oracle(
-            gen._customer_cols(),
-            tuple(gen._orders_store),
-            tuple(gen._lineitem_store),
+            *tpch.q3_inputs(gen.live()),
             building_code=building,
         )
         return {k: v for k, v in want.items() if v != 0}

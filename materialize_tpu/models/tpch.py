@@ -26,6 +26,14 @@ ORDERS_DTYPES = (I64, I64, I64, I64)  # orderkey, custkey, orderdate, shippriori
 LINEITEM_DTYPES = (I64, I64, I64, I64, I64, I64)
 # orderkey, extendedprice(cents), discount(pct), shipdate, quantity, partkey
 
+# the columns of storage/generator.py::TpchGenerator that q3() reads, in its
+# order: pass as the generator's `columns`
+Q3_COLUMNS = {
+    "customer": ("c_custkey", "c_mktsegment", "c_nationkey"),
+    "orders": ("o_orderkey", "o_custkey", "o_orderdate", "o_shippriority"),
+    "lineitem": ("l_orderkey", "l_extendedprice", "l_discount", "l_shipdate", "l_quantity", "l_partkey"),
+}
+
 BUILDING = 1  # segment code of 'BUILDING' in the generator's segment table
 Q3_DATE = int(date_num(1995, 3, 15))
 
@@ -115,6 +123,11 @@ def q3() -> DataflowDescription:
         ],
         index_exports={"idx_q3": ("mv_q3", (0, 1, 2))},
     )
+
+
+def q3_inputs(live: dict) -> tuple:
+    """`TpchGenerator.live()` as q3_oracle's (customer, orders, lineitem)."""
+    return tuple(tuple(live[t][c] for c in cols) for t, cols in Q3_COLUMNS.items())
 
 
 def q3_oracle(customer, orders, lineitem, building_code: int = BUILDING) -> dict:

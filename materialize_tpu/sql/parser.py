@@ -798,6 +798,17 @@ class Parser:
             lit = self.next()
             if lit.kind != "STRING":
                 raise ParseError("INTERVAL requires a quoted string")
+            # the SQL-standard qualifier, INTERVAL '<n>' <unit> [(<precision>)],
+            # is the same literal as INTERVAL '<n> <unit>': the planner reads
+            # (or refuses) the unit; the precision is accepted and ignored
+            # (the planner's calendar unit is a day)
+            if lit.value.strip().lstrip("+-").isdigit() and self.peek().kind == "IDENT":
+                lit = ast.IntervalLit(f"{lit.value} {self.next().value}")
+                if self.at_op("(") and self.peek(1).kind == "NUMBER":
+                    self.next()
+                    self.next()
+                    self.expect_op(")")
+                return lit
             return ast.IntervalLit(lit.value)
         if self.at_kw("cast"):
             self.next()

@@ -55,7 +55,7 @@ def test_refreshes_after_the_second_request_no_program(programs_built):
         c.advance()
         requested.append(programs_built() - built)
         assert _builds()[0] >= host + 2  # orders and lineitem, at least
-        lineitems.add(len(c.generators[0][0]._lineitem_store[0]))
+        lineitems.add(len(c.generators[0][0].live()["lineitem"]["l_orderkey"]))
     assert requested[0] > 0 and requested[2:] == [0, 0, 0, 0], requested
     assert len(lineitems) > 3, "the refreshes' row counts did not differ: the test shows nothing"
     assert _builds()[1] == device

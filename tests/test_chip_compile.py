@@ -5,7 +5,9 @@ The TPU compiler is installed in the test environment and compiles for a
 A CPU run of the same function proves nothing about that. This file keeps
 the six hot-path primitives, the consolidate sort and the head merge at
 n = 2^22 with the column dtypes the served TPC-H Q3 path passes (u32 hashes
-and device times, i32/i64 values, i64 diffs): all must compile.
+and device times, i32/i64 values, i64 diffs), the full-schema lineitem's
+snapshot consolidate and head merge, and TPC-H Q6's keyless fused reduce step
+at its hydration slice and at a refresh's delta: all must compile.
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU library, and every xdist worker imports
@@ -123,16 +125,39 @@ def test_consolidate_sort_compiles_for_v5e(one_chip):
 
 
 @pytest.mark.parametrize(
+    "vals,n",
+    [(16, 1 << 23)],
+    ids=["lineitem_snapshot_8388608"],
+)
+def test_source_snapshot_consolidate_compiles_for_v5e(one_chip, vals, n):
+    """A view's hydration reads its source's snapshot consolidated
+    (`StorageCollection.snapshot`): at SF1 the full-schema lineitem is 16 i64
+    columns at 8,388,608 rows of capacity, with no key."""
+    batch = UpdateBatch(
+        _col(one_chip, U32, n),
+        (),
+        tuple(_col(one_chip, I64, n) for _ in range(vals)),
+        _col(one_chip, TIME_DTYPE, n),
+        _col(one_chip, DIFF_DTYPE, n),
+    )
+    consolidate_mod = importlib.import_module("materialize_tpu.ops.consolidate")
+    mem = consolidate_mod._consolidate.lower(batch, compact=True).compile().memory_analysis()
+    # the input and the source's own spines (some 2.4 GB at SF1) stay beside it
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize(
     "vals,d",
-    [(6, 1 << 14), (1, 1 << 15)],
-    ids=["lineitem_262144_16384", "q17_averages_524288_32768"],
+    [(6, 1 << 14), (1, 1 << 15), (15, 1 << 14)],
+    ids=["lineitem_262144_16384", "q17_averages_524288_32768", "lineitem_full_262144_16384"],
 )
 def test_head_merge_compiles_for_v5e(one_chip, vals, d):
     """The one program a refresh runs per arrangement (arrangement/spine.py):
     a delta merged into the fixed-capacity head, padded and truncated to the
     head's capacity inside the program. At the two widest shapes the
-    benchmark's cells run: lineitem's 16,384-row delta, and the 32,768-row
-    delta of Q17's per-part averages."""
+    benchmark's cells run: lineitem's 16,384-row delta (at Q3's and Q17's
+    six columns and at the full schema's sixteen, one of them the key), and
+    the 32,768-row delta of Q17's per-part averages."""
     from materialize_tpu.arrangement.spine import HEAD_RATIO
 
     def rows(n):  # hash, one i64 key, `vals` i64 columns, time, diff
@@ -155,3 +180,63 @@ def test_head_merge_compiles_for_v5e(one_chip, vals, d):
     assert {o.shape for o in out} == {(HEAD_RATIO * d,)}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1 << 30
+
+
+@pytest.fixture(scope="module")
+def q6_step():
+    """The arguments of Q6's one render operator, the keyless fused reduce
+    step, as the served path makes them: the benchmark configuration's
+    published text over LOAD GENERATOR TPCH at a toy scale, on the CPU, with
+    the step's call recorded. (table, delta, time, static keywords)."""
+    import json
+    from pathlib import Path
+
+    from materialize_tpu.adapter import Coordinator
+    from materialize_tpu.ops import fused_reduce
+
+    config = Path(__file__).parents[1] / "chipbench" / "configs" / "loadgen_tpch_sf1_q6.json"
+    calls = []
+    real = fused_reduce.fused_mfp_reduce_step
+
+    def recorded(*args):
+        calls.append(args)
+        return real(*args)
+
+    fused_reduce.fused_mfp_reduce_step = recorded
+    try:
+        c = Coordinator()
+        for sql in json.loads(config.read_text())["setup_sql"]:
+            c.execute(sql.format(scale_factor="0.0001"))
+    finally:
+        fused_reduce.fused_mfp_reduce_step = real
+    state, delta, time, mfp, key_cols, aggs = calls[-1]
+    assert key_cols == () and delta.vals  # keyless, over lineitem's columns
+    return state, delta, time, {"mfp": mfp, "key_cols": key_cols, "aggs": aggs}
+
+
+@pytest.mark.parametrize(
+    "state_cap,delta_cap",
+    [(1 << 21, 1 << 21), (8, 1 << 14)],
+    ids=["hydration_slice_2097152", "refresh_delta_16384"],
+)
+def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, delta_cap):
+    """Q6's step as SF1 asks for it: hydration steps lineitem's snapshot in
+    BULK_ROWS slices against a table held at BULK_ROWS (dataflow/runtime.py),
+    a refresh steps the 16,384-row lineitem delta against the one-group table
+    at its bucket of 8."""
+    from materialize_tpu.dataflow.runtime import BULK_ROWS
+    from materialize_tpu.ops.fused_reduce import _fused_mfp_reduce_step
+
+    assert BULK_ROWS == 1 << 21
+    state, delta, time, static = q6_step
+
+    def at(tree, n):
+        return jax.tree_util.tree_map(lambda x: _col(one_chip, x.dtype, n), tree)
+
+    compiled = _fused_mfp_reduce_step.lower(
+        at(state, state_cap), at(delta, delta_cap),
+        jax.ShapeDtypeStruct((), jnp.asarray(time).dtype, sharding=one_chip), **static,
+    ).compile()
+    mem = compiled.memory_analysis()
+    # one slice's temporaries (2.3 GB at the hydration slice) beside the source's spines
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30

@@ -72,9 +72,7 @@ def test_tpch_q3_through_sql():
     seg_code = c.catalog.dict.lookup("BUILDING")
     assert seg_code is not None  # resolved via the shared catalog dictionary
     want = tpch.q3_oracle(
-        gen._customer_cols(),
-        tuple(gen._orders_store),
-        tuple(gen._lineitem_store),
+        *tpch.q3_inputs(gen.live()),
         building_code=seg_code,
     )
     got = {}
@@ -85,7 +83,7 @@ def test_tpch_q3_through_sql():
 
 
 def test_tpch_q3_incremental_vs_oracle():
-    gen = TpchGenerator(sf=0.001, seed=7)
+    gen = TpchGenerator(sf=0.001, seed=7, columns=tpch.Q3_COLUMNS)
     df = Dataflow(tpch.q3())
     init = gen.initial_batches(0)
     df.step(0, {k: init[k] for k in ("customer", "orders", "lineitem")})
@@ -95,10 +93,6 @@ def test_tpch_q3_incremental_vs_oracle():
     got = {}
     for row in df.peek("idx_q3"):
         got[(row[0], row[1], row[2])] = row[3]
-    want = tpch.q3_oracle(
-        tuple(gen._customer_cols()),
-        tuple(c for c in gen._orders_store),
-        tuple(c for c in gen._lineitem_store),
-    )
+    want = tpch.q3_oracle(*tpch.q3_inputs(gen.live()))
     want = {k: v for k, v in want.items() if v != 0}
     assert got == want

@@ -80,7 +80,7 @@ def test_fused_q3_matches_oracle(n_shards, val_dtype):
     caps = Q3Caps(cust=1 << 10, orders=1 << 10, lineitem=1 << 12, delta=delta,
                   bucket=1 << 9, join_out=1 << 12, groups=1 << 11,
                   val_dtype=val_dtype)
-    gen = TpchGenerator(sf=0.0005, seed=11, val_dtype=np.dtype(val_dtype))
+    gen = TpchGenerator(sf=0.0005, seed=11, val_dtype=np.dtype(val_dtype), columns=tpch.Q3_COLUMNS)
     init = gen.initial_batches(1)
 
     def pad_to(b, cap):
@@ -125,9 +125,7 @@ def test_fused_q3_matches_oracle(n_shards, val_dtype):
         )
 
     integrated = {k: v for k, v in out_acc.items() if v != 0}
-    want = tpch.q3_oracle(
-        gen._customer_cols(), tuple(gen._orders_store), tuple(gen._lineitem_store)
-    )
+    want = tpch.q3_oracle(*tpch.q3_inputs(gen.live()))
     want = {k: v for k, v in want.items() if v != 0}
     got = {}
     for (lk, od, sp, rev), cnt in integrated.items():

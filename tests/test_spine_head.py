@@ -447,9 +447,7 @@ def test_served_q3_refreshes_build_no_kernel_program():
     def check():
         rows = c.execute("SELECT * FROM q3").rows
         want = tpch.q3_oracle(
-            gen._customer_cols(),
-            tuple(gen._orders_store),
-            tuple(gen._lineitem_store),
+            *tpch.q3_inputs(gen.live()),
             building_code=seg_code,
         )
         got = {(lk, od, sp): round(rev * 10_000) for (lk, rev, od, sp) in rows}

@@ -15,8 +15,9 @@ def coord():
 
 
 def li_state(c):
-    gen = c.generators[0][0]
-    return gen._lineitem_store  # [orderkey, price_cents, disc_pct, shipdate, qty, partkey]
+    """(orderkey, price in cents, discount in percent, shipdate, quantity, partkey) of the live lineitems."""
+    li = c.generators[0][0].live()["lineitem"]
+    return tuple(li[k] for k in ("l_orderkey", "l_extendedprice", "l_discount", "l_shipdate", "l_quantity", "l_partkey"))
 
 
 def test_q6_forecast_revenue(coord):
@@ -95,7 +96,8 @@ def test_q18_shape_having(coord):
     coord.advance()
     lk, ep, dc, sd, qty, pk = (np.asarray(c) for c in li_state(coord))
     gen = coord.generators[0][0]
-    ok, ock, od, sp = (np.asarray(c) for c in gen._orders_store)
+    orders = gen.live()["orders"]
+    ok, ock = orders["o_orderkey"], orders["o_custkey"]
     cust_of = dict(zip(ok.tolist(), ock.tolist()))
     sums: dict = {}
     for k, q in zip(lk.tolist(), qty.tolist()):
