@@ -43,10 +43,12 @@ REQUIRED_FAMILIES = (
     "mzt_arrangement_head_spills_total",
     "mzt_arrangement_head_bypass_total",
     # the accumulable reduce operators (dataflow/runtime.py): step wall,
-    # groups whose output changed, live groups, per (dataflow, operator)
+    # groups whose output changed, live groups, per (dataflow, operator),
+    # and steps whose own error batch was empty (not handed on) or carried
     "mzt_reduce_step_duration_ns",
     "mzt_reduce_groups_changed_total",
     "mzt_reduce_state_groups",
+    "mzt_reduce_error_batches_total",
     # UpdateBatch.build (repr/batch.py) by where its columns lived: `host`
     # says the ingest asked XLA for no program, `device` should stay 0 there
     "mzt_batch_build_total",

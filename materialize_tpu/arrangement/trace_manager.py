@@ -162,15 +162,13 @@ class SharedReduceTrace:
         return self.cached
 
     def _step_one(self, tick: int, delta: UpdateBatch):
-        import numpy as np
-
-        from ..ops.reduce import accumulable_step
+        from ..ops.reduce import accumulable_step, read_step_counts
         from ..repr.batch import bucket_cap
 
         self.state, out, errs, counts = accumulable_step(
             self.state, delta, self.key_cols, self.aggs, tick
         )
-        self.groups, changed = (int(c) for c in np.asarray(counts))
+        self.groups, changed, errs = read_step_counts(counts, errs)
         self.state = self.state.with_capacity(bucket_cap(self.groups))
         return out, errs, changed
 

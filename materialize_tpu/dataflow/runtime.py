@@ -30,7 +30,7 @@ from ..obs import metrics as obs_metrics
 from ..obs.spans import TRACER
 from ..ops.consolidate import consolidate
 from ..ops.join import join_against
-from ..ops.reduce import AccumState, accumulable_step, agg_out_dtype
+from ..ops.reduce import AccumState, accumulable_step, agg_out_dtype, read_step_counts
 from ..ops.threshold import threshold_step
 from ..ops.topk import negate as negate_batch
 from ..ops.topk import topk_step
@@ -651,6 +651,7 @@ class ReduceNode(Node):
         self.state = AccumState.empty(8, key_dtypes, accum_dtypes)
         self.groups = 0  # live groups, as the last step read them
         self.changed = None  # groups whose output changed in the last step (None: no step)
+        self.errs_carried = None  # the last step handed on an error batch
 
     def step(self, tick, ins):
         self.changed = None
@@ -661,13 +662,14 @@ class ReduceNode(Node):
         if oks is None:
             return None if errs is None else (None, errs)
         out, agg_errs, self.changed = _reduce_in_slices(self, tick, oks)
+        self.errs_carried = agg_errs is not None
         return out, _union([errs, agg_errs])
 
     def _step_one(self, tick, delta):
         self.state, out, agg_errs, counts = accumulable_step(
             self.state, delta, self.key_cols, self.aggs, tick
         )
-        self.groups, changed = (int(c) for c in np.asarray(counts))
+        self.groups, changed, agg_errs = read_step_counts(counts, agg_errs)
         # the step leaves the table at cap(state) + cap(delta): back to the
         # pow2 bucket of its groups (AccumState.rebucketed, on the count read above)
         self.state = self.state.with_capacity(bucket_cap(self.groups))
@@ -687,6 +689,7 @@ class SharedReduceNode(Node):
     def __init__(self, handle):
         self.h = handle
         self.changed = None
+        self.errs_carried = None
 
     @property
     def groups(self) -> int:
@@ -720,6 +723,7 @@ class SharedReduceNode(Node):
         out, agg_errs = self.h.trace.step(tick, oks, _reduce_in_slices)
         if stepped:
             self.changed = self.h.trace.changed
+            self.errs_carried = agg_errs is not None
         return out, _union([errs, agg_errs])
 
     def _private_hydration(self, tick, d):
@@ -761,6 +765,7 @@ class FusedMfpReduceNode(Node):
         self.state_cap = 8
         self.groups = 0
         self.changed = None
+        self.errs_carried = None
 
     def step(self, tick, ins):
         self.changed = None
@@ -771,6 +776,7 @@ class FusedMfpReduceNode(Node):
         if oks is None:
             return None if errs is None else (None, errs)
         out, agg_errs, self.changed = _reduce_in_slices(self, tick, oks)
+        self.errs_carried = agg_errs is not None
         return out, _union([errs, agg_errs])
 
     def _step_one(self, tick, delta):
@@ -779,7 +785,7 @@ class FusedMfpReduceNode(Node):
         self.state, out, agg_errs, counts = fused_mfp_reduce_step(
             self.state, delta, tick, self.mfp, self.key_cols, self.aggs
         )
-        self.groups, changed = (int(c) for c in np.asarray(counts))
+        self.groups, changed, agg_errs = read_step_counts(counts, agg_errs)
         if bucket_cap(self.groups) > self.state_cap:
             self.state_cap = bucket_cap(self.groups)
         self.state = self.state.with_capacity(self.state_cap)
@@ -805,6 +811,11 @@ _REDUCE_GROUPS = obs_metrics.REGISTRY.gauge(
     "mzt_reduce_state_groups",
     "live groups in a reduce operator's accumulator table after its last step",
     labels=_REDUCE_LABELS,
+)
+_REDUCE_ERR_BATCHES = obs_metrics.REGISTRY.counter(
+    "mzt_reduce_error_batches_total",
+    "reduce steps by their own error batch: held rows and was handed on (carried), or held none and was not (empty)",
+    labels=_REDUCE_LABELS + ("outcome",),
 )
 
 _ABSENT = object()
@@ -1968,6 +1979,8 @@ class Dataflow:
                     _REDUCE_STEP_NS.observe(elapsed, **labels)
                     _REDUCE_CHANGED.inc(node.changed, **labels)
                     _REDUCE_GROUPS.set(node.groups, **labels)
+                    outcome = "carried" if node.errs_carried else "empty"
+                    _REDUCE_ERR_BATCHES.inc(1, outcome=outcome, **labels)
                 if self.operator_logging:
                     # row counts need a device sync per delta — gated so the
                     # default tick path does no per-row work (the

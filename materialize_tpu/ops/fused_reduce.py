@@ -42,7 +42,8 @@ def fused_mfp_reduce_step(
 ):
     """(state, Δin, t) → (state', Δout, Δerrs, counts) in one XLA program;
     `counts` is `reduce.step_counts` (live groups, groups whose output
-    changed), so the caller's one host read needs no program of its own."""
+    changed, Δerrs's live rows), so the caller's one host read needs no
+    program of its own. Without key columns Δout is KEYLESS_OUT_CAP rows."""
     # forwards only: the harness wraps this un-jitted name and reads the
     # device program `jit__fused_mfp_reduce_step` (chipbench/metrics/)
     return _fused_mfp_reduce_step(state, delta, time, mfp, key_cols, aggs)
@@ -72,7 +73,9 @@ def _fused_mfp_reduce_step(
     ov = accum_overflow_errs(contrib, old_accums, aggs, time)
     if ov is not None:
         errs2 = consolidate(UpdateBatch.concat(errs2, ov))
-    out = consolidate(_emit_output(contrib, old_accums, old_nrows, time, aggs))
+    out = consolidate(
+        _emit_output(contrib, old_accums, old_nrows, time, aggs, keyless=not key_cols)
+    )
     new_state = consolidate_accums(AccumState.concat(state, contrib))
     errs = errs2 if errs1 is None else consolidate(UpdateBatch.concat(errs1, errs2))
-    return new_state, out, errs, step_counts(new_state, contrib, old_nrows)
+    return new_state, out, errs, step_counts(new_state, contrib, old_nrows, errs)

@@ -432,21 +432,35 @@ def collision_errs(probe: AccumState, missed, time) -> UpdateBatch:
     )
 
 
-@partial(jax.jit, static_argnames=("aggs",))
+# A keyless reduce holds one group, so its output is at most that group's old
+# row retracted and its new row inserted: it leaves a step at this capacity,
+# whatever the capacity of the delta it stepped.
+KEYLESS_OUT_CAP = bucket_cap(2)
+
+
+@partial(jax.jit, static_argnames=("aggs", "keyless"))
 def _emit_output(
     delta_keys: AccumState,
     old_accums,
     old_nrows,
     time: jnp.ndarray,
     aggs: tuple = (),
+    keyless: bool = False,
 ) -> UpdateBatch:
     """Self-correcting output: -old aggregate row, +new aggregate row per key.
 
     delta_keys holds the *delta* contributions; new = old + delta. Output rows
     are (key cols ++ one col per aggregate), diff ±1 at `time`. With `aggs`,
     fixed-point float accumulators descale back to f32 output columns.
+    `keyless` (a reduce with no key columns) emits from the first
+    KEYLESS_OUT_CAP / 2 rows of a consolidated delta only: its one group is
+    the first row, since consolidation puts live rows first.
     """
-    cap = delta_keys.cap
+    if keyless:
+        rows = min(KEYLESS_OUT_CAP // 2, delta_keys.cap)
+        delta_keys = jax.tree_util.tree_map(lambda x: x[:rows], delta_keys)
+        old_accums = tuple(a[:rows] for a in old_accums)
+        old_nrows = old_nrows[:rows]
     live = delta_keys.live
     new_accums = tuple(o + d for o, d in zip(old_accums, delta_keys.accums))
     new_nrows = old_nrows + delta_keys.nrows
@@ -486,21 +500,35 @@ def _emit_output(
     return UpdateBatch(hashes, (), vals, times, diffs)
 
 
-def step_counts(new_state: AccumState, contrib: AccumState, old_nrows) -> jnp.ndarray:
-    """i32[2] a reduce step hands the host in ONE read: the live groups of
-    the table after the step, and the groups whose output row changed in it
+def step_counts(
+    new_state: AccumState, contrib: AccumState, old_nrows, errs: UpdateBatch
+) -> jnp.ndarray:
+    """i32[3] a reduce step hands the host in ONE read: the live groups of
+    the table after the step, the groups whose output row changed in it
     (appeared, vanished, or present on both sides with an accumulator that
     moved; `contrib` is consolidated, so its live rows are the groups the
-    tick touched, and one whose deltas cancel is not among them)."""
+    tick touched, and one whose deltas cancel is not among them), and the
+    live rows of the error batch the step returns."""
     was, now = old_nrows > 0, old_nrows + contrib.nrows > 0
     moved = was != was  # varying-typed False
     for d in contrib.accums:
         moved = moved | (d != 0)
     changed = contrib.live & ((was != now) | (was & now & moved))
-    return jnp.stack([new_state.count(), jnp.sum(changed.astype(jnp.int32))])
+    return jnp.stack(
+        [new_state.count(), jnp.sum(changed.astype(jnp.int32)), errs.count()]
+    )
 
 
 _step_counts = jax.jit(step_counts)
+
+
+def read_step_counts(counts, errs: UpdateBatch):
+    """The host's one read of `step_counts`: (live groups, changed groups,
+    the step's error delta, or None where it holds no row). An empty error
+    delta and none are the same collection, and None is what every operator
+    downstream skips without a program."""
+    groups, changed, n_errs = (int(c) for c in np.asarray(counts))
+    return groups, changed, errs if n_errs else None
 
 
 def accumulable_step(
@@ -514,15 +542,17 @@ def accumulable_step(
 
     Δout holds retractions of changed groups' old rows and insertions of
     their new rows, at time t (groups whose accumulators didn't change
-    cancel). Rows whose aggregate input expression errors land in Δerrs.
+    cancel), at twice cap(Δ), or at KEYLESS_OUT_CAP without key columns.
+    Rows whose aggregate input expression errors land in Δerrs.
     The state comes back at cap(state) + cap(Δ); callers cut it back to the
     bucket of its groups, which `counts` (`step_counts`, still on the
-    device) hands them with the changed groups in one read.
+    device) hands them with the changed groups and Δerrs's live rows in one
+    read (`read_step_counts`).
     """
     raw_contrib, errs = _contributions(delta, key_cols, aggs)
     contrib = consolidate_accums(raw_contrib)
     _found, old_accums, old_nrows, missed = lookup_accums(state, contrib)
-    out = _emit_output(contrib, old_accums, old_nrows, time, aggs)
+    out = _emit_output(contrib, old_accums, old_nrows, time, aggs, keyless=not key_cols)
     from .consolidate import consolidate  # local import to avoid cycle
 
     out = consolidate(out)
@@ -533,4 +563,4 @@ def accumulable_step(
     if ov is not None:
         errs = consolidate(UpdateBatch.concat(errs, ov))
     new_state = consolidate_accums(AccumState.concat(state, contrib))
-    return new_state, out, errs, _step_counts(new_state, contrib, old_nrows)
+    return new_state, out, errs, _step_counts(new_state, contrib, old_nrows, errs)

@@ -7,7 +7,8 @@ the six hot-path primitives, the consolidate sort and the head merge at
 n = 2^22 with the column dtypes the served TPC-H Q3 path passes (u32 hashes
 and device times, i32/i64 values, i64 diffs), the full-schema lineitem's
 snapshot consolidate and head merge, and TPC-H Q6's keyless fused reduce step
-at its hydration slice and at a refresh's delta: all must compile.
+at its hydration slice and at a refresh's delta (its output cut to the two
+rows a keyless reduce can hold): all must compile.
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU library, and every xdist worker imports
@@ -223,9 +224,12 @@ def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, del
     """Q6's step as SF1 asks for it: hydration steps lineitem's snapshot in
     BULK_ROWS slices against a table held at BULK_ROWS (dataflow/runtime.py),
     a refresh steps the 16,384-row lineitem delta against the one-group table
-    at its bucket of 8."""
+    at its bucket of 8. Either way the keyless step's output leaves it at
+    KEYLESS_OUT_CAP rows, beside its three counts (groups, changed groups,
+    error rows) for the host's one read."""
     from materialize_tpu.dataflow.runtime import BULK_ROWS
     from materialize_tpu.ops.fused_reduce import _fused_mfp_reduce_step
+    from materialize_tpu.ops.reduce import KEYLESS_OUT_CAP
 
     assert BULK_ROWS == 1 << 21
     state, delta, time, static = q6_step
@@ -237,6 +241,9 @@ def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, del
         at(state, state_cap), at(delta, delta_cap),
         jax.ShapeDtypeStruct((), jnp.asarray(time).dtype, sharding=one_chip), **static,
     ).compile()
+    _state, out, _errs, counts = compiled.out_info
+    assert {o.shape for o in jax.tree_util.tree_leaves(out)} == {(KEYLESS_OUT_CAP,)}
+    assert counts.shape == (3,)
     mem = compiled.memory_analysis()
     # one slice's temporaries (2.3 GB at the hydration slice) beside the source's spines
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
