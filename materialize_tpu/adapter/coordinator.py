@@ -133,9 +133,10 @@ class StorageCollection:
     materialized view, readable as a snapshot at any time ≤ upper.
     """
 
-    def __init__(self, dtypes: tuple):
+    def __init__(self, dtypes: tuple, merge_empty: bool = False):
         self.dtypes = tuple(dtypes)
-        self.arr = Arrangement(key_cols=())
+        # `merge_empty` for a view whose rows move in few ticks (a TopK's)
+        self.arr = Arrangement(key_cols=(), merge_empty=merge_empty)
         self.upper = 0
 
     def append(self, batch: UpdateBatch, tick: int) -> None:
@@ -1002,7 +1003,7 @@ class Coordinator:
         snaps = {g: self.storage[g].snapshot(as_of) for g in src_gids}
         df = self._make_dataflow(desc, snaps, trace_reader=gid)
         results = df.step(as_of, snaps)
-        self.storage[gid] = StorageCollection(pq.desc.dtypes)
+        self.storage[gid] = StorageCollection(pq.desc.dtypes, merge_empty=gid in getattr(df, "topk_outputs", ()))
         out = results.get(gid)
         item.mir = rel
         # in-memory state completes FIRST: a transient persist failure below
@@ -1538,6 +1539,7 @@ class Coordinator:
         snaps = {g: self.storage[g].snapshot(as_of) for g in src_gids}
         df = self._make_dataflow(desc, snaps, trace_reader=gid)
         results = df.step(as_of, snaps)
+        self.storage[gid].arr.merge_empty = gid in getattr(df, "topk_outputs", ())
         out = results.get(gid)
         if out is not None and out[0] is not None:
             self.storage[gid].append(out[0], as_of)
@@ -2640,6 +2642,11 @@ def explain_lir(e, indent: int = 0) -> str:
     elif isinstance(e, lir.Join):
         kind = "delta" if isinstance(e.plan, lir.DeltaJoinPlan) else "linear"
         extra = f" type={kind}"
+        if kind == "delta":  # each input's path: the inputs its delta meets, in order
+            extra += " paths=" + " ".join(
+                ">".join(str(i) for i in (k, *(st.other_input for st in path)))
+                for k, path in enumerate(e.plan.paths)
+            )
         kids = list(e.inputs)
     elif isinstance(e, lir.Reduce):
         extra = f" keys={list(e.key_cols)} aggs={[a.func for a in e.aggs]}" + (

@@ -49,6 +49,11 @@ REQUIRED_FAMILIES = (
     "mzt_reduce_groups_changed_total",
     "mzt_reduce_state_groups",
     "mzt_reduce_error_batches_total",
+    # the delta join's stages (rows in and out, as the host knew them) and the
+    # TopK operators (step wall, rows gathered back), per (dataflow, operator)
+    "mzt_join_stage_rows_total",
+    "mzt_topk_step_duration_ns",
+    "mzt_topk_rows_regathered_total",
     # UpdateBatch.build (repr/batch.py) by where its columns lived: `host`
     # says the ingest asked XLA for no program, `device` should stay 0 there
     "mzt_batch_build_total",

@@ -22,7 +22,8 @@ always the last element of `batches`, so readers see one more batch.
   its producer left it at: an operator's output keeps the summed capacity of
   its inputs (at TPC-H SF1 the Q3 view's delta is 32,768 rows wide for some
   260 rows, its error delta 131,072 wide for none), and merging costs by
-  capacity. A delta with no rows is not inserted.
+  capacity. A delta with no rows is not inserted, except where the
+  arrangement `merge_empty` (below).
 - A delta whose rows fill bucket d, arriving at a headless, non-empty
   arrangement, starts a head of capacity T = HEAD_RATIO x d (x 2d where
   the delta's capacity is wider than its rows: an operator's next output
@@ -48,6 +49,13 @@ always the last element of `batches`, so readers see one more batch.
   which is the envelope the geometric spine reaches by itself before a full
   merge). The smallest head there is, HEAD_RATIO x MIN_CAP rows, is always
   allowed, so a table filled row by row has one from its second insert.
+- `merge_empty`: for an arrangement fed every tick by a selective operator
+  (a delta join's inputs, a TopK's input, a view's rows where they are a
+  TopK's), whose deltas hold no rows in most ticks and a few in some. Its empty delta, once the arrangement holds
+  a batch, starts the smallest head where there is none and merges into it
+  like any other (counting one row towards `head_bound`), so the tick whose
+  delta first holds rows meets the head, the merge and the readers'
+  programs every tick before it ran.
 """
 
 from __future__ import annotations
@@ -161,6 +169,7 @@ class Arrangement:
     since: int = 0  # logical compaction frontier
     holds: dict = field(default_factory=dict)  # reader id -> held since
     head_bound: int = 0
+    merge_empty: bool = False
 
     @property
     def head(self) -> UpdateBatch | None:
@@ -174,9 +183,10 @@ class Arrangement:
         # the capacity its producer left it at (an operator's output keeps
         # the summed capacity of its inputs: the view's own delta at SF1 is
         # 32,768 rows wide for some 260 rows, its error delta 131,072 for
-        # none), and a delta with no rows is not inserted at all.
+        # none), and a delta with no rows is not inserted (`merge_empty`: once
+        # there is a batch, it is, and starts the smallest head).
         n = int(_live_rows(b))
-        if n == 0:
+        if n == 0 and not (self.merge_empty and self.batches):
             return
         d = bucket_cap(n)
         head = self.head
@@ -187,7 +197,7 @@ class Arrangement:
         # a new head gets one bucket of slack where the producer's capacity
         # allows it: an ingest batch is built at its bucket and keeps it, the
         # next output of an operator may hold somewhat more rows than this
-        slot = min(2 * d, bucket_cap(b.cap))
+        slot = min(2 * d, bucket_cap(b.cap)) if n else MIN_CAP
         room = max(self.total_cap(), HEAD_RATIO * MIN_CAP) if self.batches else 0
         if head is None and not oversize and HEAD_RATIO * slot <= room:
             # it starts empty and takes the delta through the same (T, d)
@@ -220,7 +230,7 @@ class Arrangement:
                 since=device_time_scalar(self.since),
                 out_cap=head.cap,
             )
-        self.head_bound += n
+        self.head_bound += max(n, 1)  # an empty delta counts one: the head is there
         _HEAD_MERGES.inc()
 
     def _spill(self) -> None:

@@ -28,13 +28,13 @@ from ..arrangement.spine import HEAD_RATIO, Arrangement, arrange_batch, sized
 from ..obs import log as obs_log
 from ..obs import metrics as obs_metrics
 from ..obs.spans import TRACER
-from ..ops.consolidate import consolidate
-from ..ops.join import join_against
+from ..ops.consolidate import consolidate, gather_parts
+from ..ops.join import join_against, join_totals
 from ..ops.reduce import AccumState, accumulable_step, agg_out_dtype, read_step_counts
 from ..ops.threshold import threshold_step
 from ..ops.topk import negate as negate_batch
 from ..ops.topk import topk_step
-from ..repr.batch import UpdateBatch, bucket_cap
+from ..repr.batch import MIN_CAP, UpdateBatch, bucket_cap
 from . import plan as lir
 
 _log = obs_log.get_logger("render")
@@ -112,11 +112,16 @@ def _union(parts: list[UpdateBatch]) -> Optional[UpdateBatch]:
 
 
 def _probe(probe: UpdateBatch, batches: list, swap: bool = False) -> list:
-    """`join_against` for the join operators: matches that fit a
+    """`join_against` for a linear join's stage: matches that fit a
     HEAD_RATIO-th of the probe's capacity in all come back at that one size.
     A delta's bucket repeats from tick to tick (an arrangement's head is
-    built on that), the few rows a selective stage leaves of it do not."""
-    return join_against(probe, batches, swap, floor=bucket_cap(probe.cap // HEAD_RATIO))
+    built on that), the few rows a selective stage leaves of it do not. A
+    stage that matched nothing hands on nothing (a keyless reduce's default
+    row is such a stage in most ticks, and its view's delta would widen)."""
+    totals = join_totals(probe, batches)
+    if not sum(totals):
+        return []
+    return join_against(probe, batches, swap, floor=bucket_cap(probe.cap // HEAD_RATIO), totals=totals)
 
 
 def _project(batch: UpdateBatch, cols: tuple[int, ...]) -> UpdateBatch:
@@ -472,6 +477,21 @@ class DeltaJoinNode(Node):
     into k's arrangements after path k runs, so path k sees inputs j<k
     up-to-date and inputs j>k as of the previous paths — the sequential-update
     decomposition that half_join realizes with per-update time comparison.
+
+    A stage's matches that fit a sixteenth of its probe come back as one
+    batch of that floor, none included (`join_against`), and the path's
+    later stages keep that floor; the paths' outputs whose matches fit the
+    join's floor are compacted into one batch of it. So the few rows a
+    selective stage lets through (a semijoin's: none in most ticks, a
+    handful in some) run through the programs every tick before them ran,
+    however many they are and whichever paths they came by.
+
+    Each path that had input is a `join.path` span, and `stage_rows` holds the
+    last step's rows per stage as the host already knew them, for
+    `mzt_join_stage_rows_total`: `(path, stage, "out", n)` with n the count
+    pass's total of rows matched by hash (what sizes the materialize pass),
+    and `(path, stage, "in", n)` from a path's second stage on, the stage
+    before's total. A path's own delta is not counted: that would take a read.
     """
 
     def __init__(
@@ -484,12 +504,13 @@ class DeltaJoinNode(Node):
         # (input, lookup_key) -> TraceHandle for inputs that are imported
         # collections: the per-input index reuse that delta joins exist for
         self.shared: dict = shared or {}
+        self.stage_rows: list = []
         self.arrs: dict[tuple[int, tuple[int, ...]], Arrangement] = {}
         for path in jplan.paths:
             for st in path:
                 key = (st.other_input, st.lookup_key)
                 if key not in self.arrs and key not in self.shared:
-                    self.arrs[key] = Arrangement(key_cols=st.lookup_key)
+                    self.arrs[key] = Arrangement(key_cols=st.lookup_key, merge_empty=True)
         if shard is not None:
             # one channel per half-join hop (the stream re-keys at every
             # stage) plus one per arrangement publish; allocation order is
@@ -512,10 +533,42 @@ class DeltaJoinNode(Node):
             return self.arrs[key].batches
         return h.thru(tick) if st.other_input < k else h.before(tick)
 
+    def _path(self, k: int, path, stream, tick: int):
+        """Path k's stages over its stream (input k's delta); returns the
+        stream as the last stage left it, or None, and the rows that stage
+        matched by hash."""
+        sharded = self.shard is not None
+        floor = matched = 0
+        for si, st in enumerate(path):
+            if sharded:
+                # every worker participates in every hop's exchange —
+                # a worker with no local stream rows still punctuates
+                stream = self.shard.exchange(
+                    self.path_channels[k][si], tick, stream, st.stream_key
+                )
+            elif stream is None:
+                break
+            if stream is None:
+                continue
+            probe = arrange_batch(stream, st.stream_key)
+            batches = self._lookup_batches(k, st, tick)
+            totals = join_totals(probe, batches)
+            if si and not sharded:
+                self.stage_rows.append((k, si, "in", matched))
+            matched = sum(totals)
+            self.stage_rows.append((k, si, "out", matched))
+            # a probe that is the last stage's batch at its floor keeps it
+            floor = floor or bucket_cap(probe.cap // HEAD_RATIO)
+            stream = _union(join_against(probe, batches, floor=floor, totals=totals))
+            if matched > floor:
+                floor = 0
+        return stream, matched
+
     def step(self, tick, ins):
         errs = _union([d[1] for d in ins if d is not None])
-        outs = []
+        outs, matched = [], []
         sharded = self.shard is not None
+        self.stage_rows = []
         # shared arrangements absorb their input's tick delta up front:
         # offers are idempotent (first reader wins) and the thru/before
         # views encode the per-path time split
@@ -532,24 +585,14 @@ class DeltaJoinNode(Node):
             )
         for k, path in enumerate(self.plan.paths):
             dk = ins[k][0] if ins[k] is not None else None
-            stream = dk
-            for si, st in enumerate(path):
-                if sharded:
-                    # every worker participates in every hop's exchange —
-                    # a worker with no local stream rows still punctuates
-                    stream = self.shard.exchange(
-                        self.path_channels[k][si], tick, stream, st.stream_key
-                    )
-                elif stream is None:
-                    break
-                if stream is None:
-                    continue
-                probe = arrange_batch(stream, st.stream_key)
-                stream = _union(
-                    _probe(probe, self._lookup_batches(k, st, tick))
-                )
+            if dk is not None:
+                with TRACER.span("join.path"):
+                    stream, n = self._path(k, path, dk, tick)
+            else:
+                stream, n = self._path(k, path, dk, tick)
             if stream is not None:
                 outs.append(_project(stream, self.plan.permutations[k]))
+                matched.append(n)
             # now publish input k's delta to its PRIVATE arrangements
             # (sharded: the delta is exchanged by each arrangement's key
             # first, so every partitioned arrangement holds exactly the rows
@@ -564,7 +607,11 @@ class DeltaJoinNode(Node):
                     )
                 if routed is not None:
                     arr.insert(arrange_batch(routed, key), already_keyed=True)
-        out = _union(outs)
+        # the join's floor follows its inputs' delta capacities alone (none
+        # where a worker's counts are its own, or in a bulk tick)
+        widest = max((d[0].cap for d in ins if d is not None and d[0] is not None), default=0)
+        floor = 0 if sharded or widest > BULK_ROWS else bucket_cap(widest // HEAD_RATIO)
+        out = _union(gather_parts(lambda i, _cap: outs[i], matched, floor))
         if out is None and errs is None:
             return None
         if out is not None and self.closure is not None:
@@ -746,11 +793,19 @@ class SharedReduceNode(Node):
         return [(self.h.name(),) + _shared_state_info(self.h)]
 
 
+# The least capacity of a reduce's or a DISTINCT's table: one whose groups
+# are few and come and go (a view's answer behind a HAVING or an IN) would
+# give its step a new shape in whichever tick first took it across 8, 16,
+# ..., as the program's first tick at that size. A large table's count moves
+# by a small share of it from tick to tick.
+MIN_TABLE = HEAD_RATIO * MIN_CAP
+
+
 class FusedMfpReduceNode(Node):
     """Mfp→Reduce rendered as one compiled tick (ops/fused_reduce.py).
 
-    State capacity is sticky (grow-only pow2) so shapes recur and the jit
-    cache stays warm across ticks.
+    State capacity is sticky (grow-only pow2, at least MIN_TABLE) so shapes
+    recur and the jit cache stays warm across ticks.
     """
 
     def __init__(self, mfp, expr: lir.Reduce, mfp_out_dtypes: tuple):
@@ -762,7 +817,7 @@ class FusedMfpReduceNode(Node):
         key_dtypes = tuple(mfp_out_dtypes[i] for i in expr.key_cols)
         accum_dtypes = tuple(np.dtype(a.accum_dtype) for a in expr.aggs)
         self.state = _AS.empty(8, key_dtypes, accum_dtypes)
-        self.state_cap = 8
+        self.state_cap = MIN_TABLE
         self.groups = 0
         self.changed = None
         self.errs_carried = None
@@ -816,6 +871,22 @@ _REDUCE_ERR_BATCHES = obs_metrics.REGISTRY.counter(
     "mzt_reduce_error_batches_total",
     "reduce steps by their own error batch: held rows and was handed on (carried), or held none and was not (empty)",
     labels=_REDUCE_LABELS + ("outcome",),
+)
+
+_TOPK_STEP_NS = obs_metrics.REGISTRY.histogram(
+    "mzt_topk_step_duration_ns",
+    "host wall of one TopK operator's step that had input",
+    labels=_REDUCE_LABELS,
+)
+_TOPK_REGATHERED = obs_metrics.REGISTRY.counter(
+    "mzt_topk_rows_regathered_total",
+    "rows of the affected groups a TopK step gathered back from its state (both gathers of a general TopK)",
+    labels=_REDUCE_LABELS,
+)
+_JOIN_STAGE_ROWS = obs_metrics.REGISTRY.counter(
+    "mzt_join_stage_rows_total",
+    "rows into and out of a delta join's stage, as the host already knew them: out is the count pass's matches by hash",
+    labels=_REDUCE_LABELS + ("path", "stage", "side"),
 )
 
 _ABSENT = object()
@@ -991,12 +1062,18 @@ class BasicAggNode(Node):
 
 
 class DistinctNode(Node):
-    """ReducePlan::Distinct — project to key cols, then presence per row."""
+    """ReducePlan::Distinct — project to key cols, then presence per row.
+
+    The table's capacity only grows, from MIN_TABLE (as
+    `FusedMfpReduceNode.state_cap`): a count that moves back and forth
+    across a power of two would give every step program a new shape each
+    time it crossed."""
 
     def __init__(self, key_cols: tuple[int, ...], in_dtypes: tuple):
         self.key_cols = key_cols
         key_dtypes = tuple(in_dtypes[i] for i in key_cols)
         self.state = AccumState.empty(8, key_dtypes, ())
+        self.state_cap = MIN_TABLE
 
     def step(self, tick, ins):
         d = ins[0]
@@ -1009,7 +1086,8 @@ class DistinctNode(Node):
         self.state, out, coll = threshold_step(
             self.state, projected, "distinct", tick
         )
-        self.state = self.state.rebucketed()
+        self.state_cap = max(self.state_cap, bucket_cap(int(self.state.count())))
+        self.state = self.state.with_capacity(self.state_cap)
         return out, _union([errs, coll])
 
     def state_info(self):
@@ -1036,11 +1114,19 @@ class ThresholdNode(Node):
 
 
 class TopKNode(Node):
+    """TopK over a general input (ops/topk.py): the affected groups'
+    contents are gathered from the input arrangement before and after the
+    delta. `regathered` is the rows both gathers matched in the last step
+    that had input (None: no such step), from the totals the host reads to
+    size them."""
+
     def __init__(self, tplan):
         self.plan = tplan
-        self.arr = Arrangement(key_cols=tplan.group_cols)
+        self.arr = Arrangement(key_cols=tplan.group_cols, merge_empty=True)
+        self.regathered = None
 
     def step(self, tick, ins):
+        self.regathered = None
         d = ins[0]
         if d is None:
             return None
@@ -1048,7 +1134,9 @@ class TopKNode(Node):
         if oks is None:
             return None if errs is None else (None, errs)
         keyed = arrange_batch(oks, self.plan.group_cols)
-        out = topk_step(self.arr, keyed, self.plan, tick)
+        totals: list = []
+        out = topk_step(self.arr, keyed, self.plan, tick, totals)
+        self.regathered = sum(totals)
         return out, errs
 
     def compact(self, since):
@@ -1093,6 +1181,7 @@ class MonotonicTopKNode(Node):
     top-k of a group is always a subset of {stored winners} ∪ {new rows}, so
     the node stores the top (offset+limit) rows per touched group instead of
     the whole input — the input arrangement disappears entirely.
+    `regathered` is TopKNode's: the stored winners' rows the gather matched.
     """
 
     def __init__(self, tplan):
@@ -1100,10 +1189,12 @@ class MonotonicTopKNode(Node):
         self.plan = tplan
         self.keep = tplan.offset + tplan.limit
         self.out_arr = Arrangement(key_cols=tplan.group_cols)
+        self.regathered = None
 
     def step(self, tick, ins):
         from ..ops.topk import distinct_keys, gather_groups, negate, topk_select
 
+        self.regathered = None
         d = ins[0]
         if d is None:
             return None
@@ -1117,7 +1208,9 @@ class MonotonicTopKNode(Node):
         keyed = arrange_batch(oks, self.plan.group_cols)
         probes = distinct_keys(keyed)
         vdt = tuple(v.dtype for v in keyed.vals)
-        old_kept = gather_groups(probes, self.out_arr.batches, tick, vdt)
+        totals: list = []
+        old_kept = gather_groups(probes, self.out_arr.batches, tick, vdt, totals)
+        self.regathered = sum(totals)
         cand = consolidate(UpdateBatch.concat(old_kept, keyed))
         nl = self.plan.nulls_last
         new_kept = topk_select(cand, self.plan.order_by, self.keep, 0, tick, nl)
@@ -1139,6 +1232,22 @@ class MonotonicTopKNode(Node):
 
     def state_info(self):
         return self.out_arr.info_rows("monotonic_topk_winners")
+
+
+_TOPK_NODES = (TopKNode, MonotonicTopKNode)
+
+
+def _topk_output(ops: list, ref) -> bool:
+    """Whether the rendered operator at `ref` hands on a TopK's rows,
+    through Mfps."""
+    while isinstance(ref, int):
+        node, in_refs = ops[ref]
+        if isinstance(node, _TOPK_NODES):
+            return True
+        if not isinstance(node, MfpNode):
+            return False
+        ref = in_refs[0]
+    return False
 
 
 class TemporalFilterNode(Node):
@@ -1520,10 +1629,15 @@ class Dataflow:
             out_ref = self._render(bd.plan, ops)
             self.builds.append((bd.id, ops, out_ref))
             self.dtypes[bd.id] = tuple(bd.dtypes)
+        # objects whose rows are a TopK's (through Mfps): they move in few
+        # ticks, and the arrangements that hold them merge empty deltas too
+        self.topk_outputs = {obj_id for obj_id, ops, out_ref in self.builds if _topk_output(ops, out_ref)}
         self.index_traces: dict[str, Arrangement] = {}
         self.index_errs: dict[str, Arrangement] = {}
         for idx_id, (obj_id, key_cols) in desc.index_exports.items():
-            self.index_traces[idx_id] = Arrangement(key_cols=tuple(key_cols))
+            self.index_traces[idx_id] = Arrangement(
+                key_cols=tuple(key_cols), merge_empty=obj_id in self.topk_outputs
+            )
             self.index_errs[idx_id] = Arrangement(key_cols=())
         self.sink_outputs: dict[str, list] = {s: [] for s in desc.sink_exports}
         from .antichain import EMPTY, Antichain
@@ -1957,9 +2071,10 @@ class Dataflow:
                     (env.get(r) if isinstance(r, str) else slots[r]) for r in in_refs
                 ]
                 is_reduce = isinstance(node, _REDUCE_NODES)
+                is_topk = isinstance(node, _TOPK_NODES)
                 t0 = _time.perf_counter_ns()
-                if is_reduce:
-                    with TRACER.span("reduce.step"):
+                if is_reduce or is_topk:
+                    with TRACER.span("reduce.step" if is_reduce else "topk.step"):
                         slots.append(node.step(tick, ins))
                 else:
                     slots.append(node.step(tick, ins))
@@ -1981,6 +2096,15 @@ class Dataflow:
                     _REDUCE_GROUPS.set(node.groups, **labels)
                     outcome = "carried" if node.errs_carried else "empty"
                     _REDUCE_ERR_BATCHES.inc(1, outcome=outcome, **labels)
+                elif is_topk and node.regathered is not None:
+                    labels = {"dataflow": obj_id, "operator": f"{op_i}:{m['type']}"}
+                    _TOPK_STEP_NS.observe(elapsed, **labels)
+                    _TOPK_REGATHERED.inc(node.regathered, **labels)
+                elif isinstance(node, DeltaJoinNode) and node.stage_rows:
+                    op = f"{op_i}:{m['type']}"
+                    for path, stage, side, n in node.stage_rows:
+                        _JOIN_STAGE_ROWS.inc(n, dataflow=obj_id, operator=op, path=str(path),
+                                             stage=str(stage), side=side)
                 if self.operator_logging:
                     # row counts need a device sync per delta — gated so the
                     # default tick path does no per-row work (the

@@ -20,7 +20,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..repr.batch import PAD_TIME, UpdateBatch
+from ..repr.batch import PAD_TIME, UpdateBatch, bucket_cap
 from ..repr.hashing import PAD_HASH
 from .permute import batch_permute
 from .search import merge_perm, sort_perm
@@ -112,6 +112,25 @@ def compact_to(batch: UpdateBatch, cap: int):
         scat(batch.diffs, 0),
     )
     return out, over
+
+
+def gather_parts(fetch, counts: list[int], floor: int = 0) -> list[UpdateBatch]:
+    """The parts `fetch(i, cap)` brings back, at capacity `cap`, of sources
+    whose matching rows the host counted as `counts`. Where those rows fit
+    `floor` in all (none at all too, where there is a source), every
+    source's part comes back at that capacity, one that counted nothing as
+    well, and they are compacted into ONE batch of it: its shape then
+    follows neither how many rows there are nor which sources hold them, so
+    a refresh whose selective stage first lets a row through meets the
+    programs every refresh before it ran. Else each source that counted
+    rows comes back at its own bucket. `floor` 0 asks for the latter."""
+    if floor and sum(counts) <= floor and counts:
+        acc = None
+        for i in range(len(counts)):  # also one that matched nothing: its turn comes
+            part = fetch(i, floor)
+            acc = part if acc is None else UpdateBatch.concat(acc, part)
+        return [acc if acc.cap == floor else compact_to(acc, floor)[0]]
+    return [fetch(i, bucket_cap(n)) for i, n in enumerate(counts) if n]
 
 
 def run_sum(run_start: jnp.ndarray, cols: tuple) -> tuple:

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..repr.batch import PAD_TIME, UpdateBatch, bucket_cap
 from ..repr.hashing import PAD_HASH
-from .consolidate import compact_to
+from .consolidate import gather_parts
 from .permute import multi_take
 from .search import searchsorted
 
@@ -118,28 +118,27 @@ def _join_materialize(
     )
 
 
+def join_totals(probe: UpdateBatch, batches: list[UpdateBatch]) -> list[int]:
+    """The count pass over each batch, read on the host: the rows of each
+    that match the probe by hash (what sizes its materialize pass)."""
+    return [int(join_total(probe, arr)) for arr in batches]
+
+
 def join_against(
-    probe: UpdateBatch, batches: list[UpdateBatch], swap: bool = False, floor: int = 0
+    probe: UpdateBatch, batches: list[UpdateBatch], swap: bool = False, floor: int = 0,
+    totals: list[int] | None = None,
 ):
     """Join a probe batch against every batch of an arrangement (host driver).
 
     Returns a list of raw output batches (possibly empty). Sizes outputs by a
     count pass per spine batch; capacities are pow2-bucketed to bound
-    recompilation. Where all of the probe's matches together fit `floor` rows
-    they come back as ONE batch of that capacity, through the same programs
-    whichever batches hold them: counts that small split over the batches, and
-    cross their small buckets, by chance from tick to tick, and every program
-    downstream would follow them.
+    recompilation. Where all of the probe's matches together fit `floor`
+    rows, none included, they come back as ONE batch of that capacity
+    (`gather_parts`): counts that small split over the batches, and cross
+    their small buckets, by chance from tick to tick, and every program
+    downstream would follow them. `totals` is `join_totals(probe, batches)`
+    where the caller read it already.
     """
-    totals = [int(join_total(probe, arr)) for arr in batches]
-    if 0 < sum(totals) <= floor:
-        acc = None
-        for arr in batches:  # also one that matched nothing: its turn comes
-            out = join_materialize(probe, arr, floor, swap)
-            acc = out if acc is None else UpdateBatch.concat(acc, out)
-        return [acc if len(batches) == 1 else compact_to(acc, floor)[0]]
-    return [
-        join_materialize(probe, arr, bucket_cap(t), swap)
-        for arr, t in zip(batches, totals)
-        if t
-    ]
+    if totals is None:
+        totals = join_totals(probe, batches)
+    return gather_parts(lambda i, cap: join_materialize(probe, batches[i], cap, swap), totals, floor)

@@ -31,7 +31,7 @@ from ..repr.batch import (
     to_device_time,
 )
 from ..repr.hashing import PAD_HASH
-from .consolidate import advance_times, consolidate, row_equal_prev
+from .consolidate import advance_times, consolidate, gather_parts, row_equal_prev
 from .permute import batch_permute, multi_take
 from .search import searchsorted, sort_perm
 
@@ -146,14 +146,20 @@ def _gather_materialize_jit(
 
 
 def gather_groups(
-    probes: UpdateBatch, batches: list[UpdateBatch], as_of: int, val_dtypes=()
+    probes: UpdateBatch, batches: list[UpdateBatch], as_of: int, val_dtypes=(),
+    totals: list | None = None, floor: int = 0,
 ) -> UpdateBatch:
-    """Current contents (as of `as_of`) of every probed group, consolidated."""
-    parts = []
-    for arr in batches:
-        total = int(_gather_total(probes, arr))
-        if total:
-            parts.append(_gather_materialize(probes, arr, bucket_cap(total)))
+    """Current contents (as of `as_of`) of every probed group, consolidated.
+    Where given, `totals` receives each batch's count of matching rows, which
+    the host reads anyway to size the gather. Where they all fit `floor`
+    rows, none included, every batch is gathered at that size and the parts
+    compacted back to it (`gather_parts`): the shapes then follow neither
+    the group's size nor which batches hold it nor whether the step had a
+    group to gather."""
+    counts = [int(_gather_total(probes, arr)) for arr in batches]
+    if totals is not None:
+        totals.extend(counts)
+    parts = gather_parts(lambda i, cap: _gather_materialize(probes, batches[i], cap), counts, floor)
     if not parts:
         dtypes_k = tuple(k.dtype for k in probes.keys)
         return UpdateBatch.empty(8, dtypes_k, val_dtypes)
@@ -256,17 +262,22 @@ def topk_step(
     delta_keyed: UpdateBatch,
     plan: TopKPlan,
     time: int,
+    totals: list | None = None,
 ) -> UpdateBatch:
     """One tick of TopK: emits new_topk − old_topk for affected groups.
 
     `arrangement` is the input Arrangement keyed by plan.group_cols; the delta
     must already be keyed the same way. This function inserts the delta.
+    `totals`, where given, receives the rows both gathers matched (per batch).
+    A group of up to `offset + limit` rows is gathered at that row count's
+    bucket, whatever its size from tick to tick.
     """
     probes = distinct_keys(delta_keyed)
     vdt = tuple(v.dtype for v in delta_keyed.vals)
-    old_rows = gather_groups(probes, arrangement.batches, time, vdt)
+    floor = 0 if plan.limit is None else bucket_cap(plan.offset + plan.limit)
+    old_rows = gather_groups(probes, arrangement.batches, time, vdt, totals, floor)
     arrangement.insert(delta_keyed, already_keyed=True)
-    new_rows = gather_groups(probes, arrangement.batches, time, vdt)
+    new_rows = gather_groups(probes, arrangement.batches, time, vdt, totals, floor)
     old_top = topk_select(
         old_rows, plan.order_by, plan.limit, plan.offset, time, plan.nulls_last
     )

@@ -116,7 +116,19 @@ class Lowerer:
             return lir.Constant(rows, tuple(e.dtypes))
         if isinstance(e, mir.MirJoin):
             impl = e.implementation or plan_join_implementation(e)
-            inputs = tuple(self.lower(i) for i in e.inputs)
+            order = impl.input_order if impl.kind == "linear" else tuple(range(len(e.inputs)))
+            inputs = tuple(self.lower(e.inputs[i]) for i in order)
+            # a linear chain out of input order (a keyed input joined early,
+            # transform/join_implementation.py) produces its inputs' columns
+            # in chain order: `at` maps a column of the inputs' order there,
+            # and the closure projects back
+            arities = [mir.arity(i) for i in e.inputs]
+            at = [
+                sum(arities[j] for j in order[: order.index(i)]) + c
+                for i in range(len(arities))
+                for c in range(arities[i])
+            ]
+            reordered = tuple(order) != tuple(range(len(order)))
             # SQL equality never matches NULLs, but the in-band sentinel
             # representation would (sentinel == sentinel); guard every
             # equivalence column with IS NOT NULL in the join closure
@@ -147,15 +159,16 @@ class Lowerer:
                 )
 
             preds = tuple(
-                CallUnary("is_not_null", Column(c)) for c in guard_cols
+                CallUnary("is_not_null", Column(at[c])) for c in guard_cols
             ) + tuple(
-                res_eq(a, c) for a, c in impl.residual_equalities
+                res_eq(at[a], at[c]) for a, c in impl.residual_equalities
             )
             closure = None
-            if preds:
-                total = sum(mir.arity(i) for i in e.inputs)
-                b = MfpBuilder(total)
+            if preds or reordered:
+                b = MfpBuilder(len(at))
                 b.add_predicates(preds)
+                if reordered:
+                    b.project(at)
                 closure = b.finish()
             return lir.Join(inputs=inputs, plan=impl.lir_plan, closure=closure)
         if isinstance(e, mir.MirReduce):

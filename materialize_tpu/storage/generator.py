@@ -286,8 +286,10 @@ class TpchGenerator:
     Row counts follow the reference Tpch load generator's knobs
     (load_generator.rs:157: count_customer/count_orders/...); per TPC-H spec,
     customer = 150k·SF, orders = 1.5M·SF, lineitems 1–7 per order, part =
-    200k·SF with four partsupp rows each, supplier = 10k·SF. Keys count from 0;
-    dates are day numbers (date_num). The columns Q3's and Q17's models read
+    200k·SF with four partsupp rows each, supplier = 10k·SF. Keys count from 0,
+    and a name is dbgen's rule over the row's own key (`Customer#` and
+    `c_custkey` in nine digits, `Supplier#` likewise); dates are day numbers
+    (date_num). The columns Q3's and Q17's models read
     are drawn first, from one stream, as the generator's first version drew
     them: `l_shipdate` uniform over 1992-1998 and independent of the order's
     date, `l_extendedprice` uniform. Every other column follows dbgen's rules
@@ -452,11 +454,11 @@ class TpchGenerator:
             "region": (np.arange(5, dtype=np.int64), v["region"], v["r_comment"]),
             "nation": (np.arange(25, dtype=np.int64), v["nation"],
                        np.array([r for _, r in _NATIONS], dtype=np.int64), v["n_comment"]),
-            "supplier": (suppkey, self._encode([f"Supplier#{k:09d}" for k in range(1, self.n_supplier + 1)]),
+            "supplier": (suppkey, self._encode([f"Supplier#{k:09d}" for k in suppkey.tolist()]),
                          v["address"][rng.integers(0, len(v["address"]), self.n_supplier)], s_nationkey,
                          self._encode(_phones(rng, s_nationkey)), rng.integers(-999_99, 10_000_00, self.n_supplier),
                          v["s_comment"][rng.integers(0, len(v["s_comment"]), self.n_supplier)]),
-            "customer": (custkey, self._encode([f"Customer#{k:09d}" for k in range(1, self.n_customer + 1)]),
+            "customer": (custkey, self._encode([f"Customer#{k:09d}" for k in custkey.tolist()]),
                          v["address"][rng.integers(0, len(v["address"]), self.n_customer)], c_nationkey,
                          self._encode(_phones(rng, c_nationkey)), rng.integers(-999_99, 10_000_00, self.n_customer),
                          v["segment"][segment], v["c_comment"][rng.integers(0, len(v["c_comment"]), self.n_customer)]),

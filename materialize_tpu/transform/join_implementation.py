@@ -9,9 +9,18 @@ equivalence classes over the flat column space, pick
 - **delta** (one update path per input, no intermediate arrangements —
   delta_join.rs) for 3+ inputs,
 
-and derive per-stage stream/lookup keys by walking the equivalence graph in
-input order. Equality members not consumed as lookup keys are re-asserted as
-residual closure predicates (correct even when classes span 3+ columns).
+and derive per-stage stream/lookup keys by walking the equivalence graph.
+Each stage's next input is one connected to what is done, and among those
+one whose lookup key covers a key the plan knows to be unique (`unique_keys`:
+a Distinct's columns, a Reduce's group key) goes first, since it matches each
+stream row at most once: a semijoin (`IN (SELECT ... GROUP BY ...)`) then
+thins a delta path before its wide stages, as upstream's characteristics rank
+delta-path inputs. Otherwise the lowest-numbered input goes first: a join with
+no such input plans as it always has, a linear chain in input order. A linear
+chain taken out of input order lists its order in `input_order`, and the
+lowering projects its output back to the inputs' order. Equality members not
+consumed as lookup keys are re-asserted as residual closure predicates
+(correct even when classes span 3+ columns).
 """
 
 from __future__ import annotations
@@ -28,7 +37,7 @@ class JoinPlanned:
 
     kind: str  # "linear" | "delta"
     lir_plan: object  # lir.LinearJoinPlan | lir.DeltaJoinPlan
-    input_order: tuple  # for linear: order in which inputs are chained
+    input_order: tuple  # for linear: order in which inputs are chained (stage i joins input_order[i + 1])
     residual_equalities: tuple  # ((global_col_a, global_col_b), ...)
 
 
@@ -38,6 +47,26 @@ def _offsets(arities):
         out.append(off)
         off += a
     return out
+
+
+def unique_keys(e) -> tuple:
+    """Sets of output columns of `e` the plan knows to be unique: a
+    Distinct's columns, a Reduce's group key, seen through Filter, Map and
+    Project. A source's declared key is not among them."""
+    if isinstance(e, mir.MirDistinct):
+        return (frozenset(range(mir.arity(e.input))),)
+    if isinstance(e, mir.MirReduce):
+        return (frozenset(range(len(e.group_key))),)
+    if isinstance(e, (mir.MirFilter, mir.MirMap)):
+        return unique_keys(e.input)
+    if isinstance(e, mir.MirProject):
+        first = {}
+        for i, c in enumerate(e.outputs):
+            first.setdefault(c, i)
+        return tuple(
+            frozenset(first[c] for c in key) for key in unique_keys(e.input) if key <= first.keys()
+        )
+    return ()
 
 
 def plan_join_implementation(
@@ -86,18 +115,30 @@ def plan_join_implementation(
             used.append(ci)
         return tuple(skey), tuple(lkey), used
 
-    def next_input(done: set[int]) -> int:
-        # prefer an input connected to what's done; fall back to input order
-        for k in range(n):
-            if k in done:
-                continue
-            for _cls, bymem in classes:
-                if k in bymem and any(d in bymem for d in done):
-                    return k
-        for k in range(n):
-            if k not in done:
+    keys = [unique_keys(i) for i in join.inputs]
+
+    def connected(done: set[int]) -> list:
+        return [
+            k for k in range(n)
+            if k not in done and any(k in bymem and any(d in bymem for d in done) for _c, bymem in classes)
+        ]
+
+    def keyed_input(done: set[int], stream_cols: list):
+        """The lowest-numbered input connected to `done` whose lookup key
+        covers one of its unique keys, else None."""
+        for k in connected(done):
+            lkey = set(stage_keys(done, k, stream_cols)[1])
+            if any(key <= lkey for key in keys[k]):
                 return k
-        raise AssertionError("no next input")
+        return None
+
+    def next_input(done: set[int], stream_cols: list) -> int:
+        # a keyed input connected to what's done, then any connected input,
+        # then input order
+        k = keyed_input(done, stream_cols)
+        if k is not None:
+            return k
+        return (connected(done) or [k for k in range(n) if k not in done])[0]
 
     residuals = []
     for cls, bymem in classes:
@@ -121,14 +162,19 @@ def plan_join_implementation(
         # README.md:46)
         stages = []
         done = {0}
+        order = [0]
         stream_cols = [(0, j) for j in range(arities[0])]
-        for nxt in range(1, n):
+        for _ in range(1, n):
+            nxt = keyed_input(done, stream_cols)
+            if nxt is None:
+                nxt = min(k for k in range(n) if k not in done)
             skey, lkey, _ = stage_keys(done, nxt, stream_cols)
             stages.append(lir.JoinStage(skey, lkey))
             stream_cols += [(nxt, j) for j in range(arities[nxt])]
             done.add(nxt)
+            order.append(nxt)
         plan = lir.LinearJoinPlan(stages=tuple(stages))
-        return JoinPlanned("linear", plan, tuple(range(n)), tuple(residuals))
+        return JoinPlanned("linear", plan, tuple(order), tuple(residuals))
 
     # delta join: one path per input
     paths, perms = [], []
@@ -138,7 +184,7 @@ def plan_join_implementation(
         stream_cols = [(k, j) for j in range(arities[k])]
         path = []
         for _ in range(n - 1):
-            nxt = next_input(done)
+            nxt = next_input(done, stream_cols)
             skey, lkey, _ = stage_keys(done, nxt, stream_cols)
             path.append(
                 lir.DeltaPathStage(other_input=nxt, stream_key=skey, lookup_key=lkey)

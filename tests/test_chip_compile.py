@@ -6,9 +6,11 @@ A CPU run of the same function proves nothing about that. This file keeps
 the six hot-path primitives, the consolidate sort and the head merge at
 n = 2^22 with the column dtypes the served TPC-H Q3 path passes (u32 hashes
 and device times, i32/i64 values, i64 diffs), the full-schema lineitem's
-snapshot consolidate and head merge, and TPC-H Q6's keyless fused reduce step
+snapshot consolidate and head merge, TPC-H Q6's keyless fused reduce step
 at its hydration slice and at a refresh's delta (its output cut to the two
-rows a keyless reduce can hold): all must compile.
+rows a keyless reduce can hold), and TPC-H Q18's per-order reduce over its
+2,097,152-row table (marked slow: outside tier-1) and the programs of its
+TopK: all must compile.
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU library, and every xdist worker imports
@@ -247,3 +249,100 @@ def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, del
     mem = compiled.memory_analysis()
     # one slice's temporaries (2.3 GB at the hydration slice) beside the source's spines
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
+
+
+@pytest.fixture(scope="module")
+def q18_calls():
+    """The arguments of Q18's per-order reduce step, as the served path makes
+    them, and the plan of its TopK: the benchmark configuration's published
+    text over LOAD GENERATOR TPCH at a toy scale, on the CPU, stopped at the
+    first reduce step of its hydration (the per-order one) once the dataflow,
+    its TopK included, is built. The TopK's input is the view's six columns."""
+    import json
+    from pathlib import Path
+
+    from materialize_tpu.adapter import Coordinator
+    from materialize_tpu.dataflow import runtime
+    from materialize_tpu.ops import fused_reduce
+
+    config = Path(__file__).parents[1] / "chipbench" / "configs" / "loadgen_tpch_sf1_q18.json"
+    source, view = json.loads(config.read_text())["setup_sql"]
+    calls, plans = [], []
+
+    class Recorded(Exception):
+        pass
+
+    def reduce_recorded(*args):
+        calls.append(args)
+        raise Recorded
+
+    def topk_recorded(node, tplan):
+        plans.append(tplan)
+        real_topk(node, tplan)
+
+    real_reduce, real_topk = fused_reduce.fused_mfp_reduce_step, runtime.TopKNode.__init__
+    fused_reduce.fused_mfp_reduce_step, runtime.TopKNode.__init__ = reduce_recorded, topk_recorded
+    try:
+        c = Coordinator()
+        c.execute(source.format(scale_factor="0.0001"))
+        with pytest.raises(Exception):
+            c.execute(view)
+    finally:
+        fused_reduce.fused_mfp_reduce_step, runtime.TopKNode.__init__ = real_reduce, real_topk
+    ((state, delta, time, mfp, key_cols, aggs),) = calls
+    assert len(key_cols) == 1 and len(delta.vals) == 16  # l_orderkey, over lineitem's columns
+    (plan,) = plans
+    return {
+        "reduce": (state, delta, time, {"mfp": mfp, "key_cols": key_cols, "aggs": aggs}),
+        "topk": (UpdateBatch.empty(8, (), (jnp.int64,) * 6), plan, time),
+    }
+
+
+def _shaped(tree, sharding, n):
+    return jax.tree_util.tree_map(lambda x: _col(sharding, x.dtype, n), tree)
+
+
+@pytest.mark.slow
+def test_q18_per_order_reduce_compiles_for_v5e(one_chip, q18_calls):
+    """Q18's subquery, a sum of quantities per order, holds one group per
+    order: 1.5 M at SF1, a table of 2,097,152 rows of capacity, and
+    hydration steps lineitem's snapshot against it in BULK_ROWS slices
+    (dataflow/runtime.py). (A refresh's step, over the 16,384-row delta, is
+    the same program at a smaller slice: 0.27 GB, no test of its own.)
+    Slow: the compile takes some 190 s of five cores alone, and beside Q6's
+    hydration compile in a tier-1 run each passes the 300 s limit."""
+    from materialize_tpu.dataflow.runtime import BULK_ROWS
+    from materialize_tpu.ops.fused_reduce import _fused_mfp_reduce_step
+
+    assert BULK_ROWS == 1 << 21
+    state, delta, time, static = q18_calls["reduce"]
+    compiled = _fused_mfp_reduce_step.lower(
+        _shaped(state, one_chip, 1 << 21), _shaped(delta, one_chip, BULK_ROWS),
+        jax.ShapeDtypeStruct((), jnp.asarray(time).dtype, sharding=one_chip), **static,
+    ).compile()
+    _state, _out, _errs, counts = compiled.out_info
+    assert counts.shape == (3,)
+    mem = compiled.memory_analysis()
+    # the hydration slice's 2.36 GB of temporaries and 0.47 GB of output beside the source's spines
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
+
+
+def test_q18_topk_programs_compile_for_v5e(one_chip, q18_calls):
+    """The TopK over Q18's one group (ORDER BY o_totalprice DESC, o_orderdate
+    LIMIT 100), at shapes past what the cell gives it: the affected groups'
+    keys over a 4,096-row delta, the count pass and the gather over a
+    65,536-row arrangement batch, the window over 1,024 gathered rows."""
+    from materialize_tpu.ops import topk
+
+    delta, plan, time = q18_calls["topk"]
+    assert plan.group_cols == () and plan.limit == 100
+    t = jax.ShapeDtypeStruct((), jnp.asarray(time).dtype, sharding=one_chip)
+    probes = _shaped(delta, one_chip, 1 << 12)
+    arr = _shaped(delta, one_chip, 1 << 16)
+    topk._distinct_keys.lower(probes).compile()
+    topk._gather_total_jit.lower(probes, arr).compile()
+    topk._gather_materialize_jit.lower(probes, arr, out_cap=1 << 10).compile()
+    compiled = topk._topk_select.lower(
+        _shaped(delta, one_chip, 1 << 10), plan.order_by, plan.limit, plan.offset, t, plan.nulls_last
+    ).compile()
+    assert {o.shape for o in jax.tree_util.tree_leaves(compiled.out_info)} == {(1 << 10,)}

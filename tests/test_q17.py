@@ -145,9 +145,10 @@ def test_reduce_stepped_in_slices_equals_one_step(monkeypatch):
 
 def test_matches_that_fit_the_floor_come_back_as_one_batch_of_that_size():
     """`join_against` with a floor (the join operators pass a sixteenth of the
-    probe's capacity): matches that fit it in all are one batch of that
-    capacity however they split over the arrangement's batches; more than
-    that, or no floor, and every batch's output has its own bucket."""
+    probe's capacity): matches that fit it in all, none included, are one
+    batch of that capacity however they split over the arrangement's
+    batches; more than that, or no floor, and every batch's output has its
+    own bucket; no batch to look in, no output."""
     import numpy as np
 
     from materialize_tpu.arrangement.spine import arrange_batch
@@ -170,7 +171,9 @@ def test_matches_that_fit_the_floor_come_back_as_one_batch_of_that_size():
         assert rows(got) == rows(join_against(probe, batches))
     assert [o.cap for o in join_against(probe, [a, b])] == [8, 64]
     assert [o.cap for o in join_against(probe, [a, keyed(list(range(100)))], floor=64)] == [8, 128]
-    assert join_against(probe, [none], floor=64) == []
+    (empty,) = join_against(probe, [none], floor=64)
+    assert empty.cap == 64 and rows([empty]) == []
+    assert join_against(probe, [none]) == [] and join_against(probe, [], floor=64) == []
 
 
 @pytest.mark.parametrize(
