@@ -44,11 +44,13 @@ REQUIRED_FAMILIES = (
     "mzt_arrangement_head_bypass_total",
     # the accumulable reduce operators (dataflow/runtime.py): step wall,
     # groups whose output changed, live groups, per (dataflow, operator),
-    # and steps whose own error batch was empty (not handed on) or carried
+    # steps whose own error batch was empty (not handed on) or carried, and
+    # steps whose table merge was clean or flagged a double collision
     "mzt_reduce_step_duration_ns",
     "mzt_reduce_groups_changed_total",
     "mzt_reduce_state_groups",
     "mzt_reduce_error_batches_total",
+    "mzt_reduce_state_merges_total",
     # the delta join's stages (rows in and out, as the host knew them) and the
     # TopK operators (step wall, rows gathered back), per (dataflow, operator)
     "mzt_join_stage_rows_total",

@@ -144,6 +144,7 @@ class SharedReduceTrace:
         self.cached: tuple = (None, None)  # (out, errs) at `frontier`
         self.groups = 0  # live groups, and groups whose output changed, as the
         self.changed = 0  # step to `frontier` read them
+        self.collided = False  # that step's table merge flagged a double collision
 
     def step(self, tick: int, oks: UpdateBatch, drive):
         """Advance the shared state to `tick` (first reader computes; the
@@ -168,7 +169,8 @@ class SharedReduceTrace:
         self.state, out, errs, counts = accumulable_step(
             self.state, delta, self.key_cols, self.aggs, tick
         )
-        self.groups, changed, errs = read_step_counts(counts, errs)
+        self.groups, changed, errs, dup = read_step_counts(counts, errs)
+        self.collided |= dup
         self.state = self.state.with_capacity(bucket_cap(self.groups))
         return out, errs, changed
 

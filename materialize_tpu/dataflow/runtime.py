@@ -676,7 +676,10 @@ def _reduce_in_slices(reducer, tick: int, oks: UpdateBatch):
     from slice to slice would ask for one each, at two minutes of the chip's
     compiler apiece. A slice's output is sized by the rows it can hold (a
     changed group emits at most a retraction and an insertion; the kernels
-    leave an output at twice its input's capacity). Returns the same triple."""
+    leave an output at twice its input's capacity). Returns the same triple;
+    `reducer.collided` says whether some step's table merge flagged a
+    double collision."""
+    reducer.collided = False
     if oks.cap <= BULK_ROWS:
         return reducer._step_one(tick, oks)
     outs, errs, changed = [], [], 0
@@ -699,6 +702,7 @@ class ReduceNode(Node):
         self.groups = 0  # live groups, as the last step read them
         self.changed = None  # groups whose output changed in the last step (None: no step)
         self.errs_carried = None  # the last step handed on an error batch
+        self.collided = False  # the last step's table merge flagged a double collision
 
     def step(self, tick, ins):
         self.changed = None
@@ -716,7 +720,8 @@ class ReduceNode(Node):
         self.state, out, agg_errs, counts = accumulable_step(
             self.state, delta, self.key_cols, self.aggs, tick
         )
-        self.groups, changed, agg_errs = read_step_counts(counts, agg_errs)
+        self.groups, changed, agg_errs, dup = read_step_counts(counts, agg_errs)
+        self.collided |= dup
         # the step leaves the table at cap(state) + cap(delta): back to the
         # pow2 bucket of its groups (AccumState.rebucketed, on the count read above)
         self.state = self.state.with_capacity(bucket_cap(self.groups))
@@ -737,6 +742,7 @@ class SharedReduceNode(Node):
         self.h = handle
         self.changed = None
         self.errs_carried = None
+        self.collided = False
 
     @property
     def groups(self) -> int:
@@ -771,6 +777,7 @@ class SharedReduceNode(Node):
         if stepped:
             self.changed = self.h.trace.changed
             self.errs_carried = agg_errs is not None
+            self.collided = self.h.trace.collided
         return out, _union([errs, agg_errs])
 
     def _private_hydration(self, tick, d):
@@ -821,6 +828,7 @@ class FusedMfpReduceNode(Node):
         self.groups = 0
         self.changed = None
         self.errs_carried = None
+        self.collided = False
 
     def step(self, tick, ins):
         self.changed = None
@@ -840,7 +848,8 @@ class FusedMfpReduceNode(Node):
         self.state, out, agg_errs, counts = fused_mfp_reduce_step(
             self.state, delta, tick, self.mfp, self.key_cols, self.aggs
         )
-        self.groups, changed, agg_errs = read_step_counts(counts, agg_errs)
+        self.groups, changed, agg_errs, dup = read_step_counts(counts, agg_errs)
+        self.collided |= dup
         if bucket_cap(self.groups) > self.state_cap:
             self.state_cap = bucket_cap(self.groups)
         self.state = self.state.with_capacity(self.state_cap)
@@ -870,6 +879,11 @@ _REDUCE_GROUPS = obs_metrics.REGISTRY.gauge(
 _REDUCE_ERR_BATCHES = obs_metrics.REGISTRY.counter(
     "mzt_reduce_error_batches_total",
     "reduce steps by their own error batch: held rows and was handed on (carried), or held none and was not (empty)",
+    labels=_REDUCE_LABELS + ("outcome",),
+)
+_REDUCE_STATE_MERGES = obs_metrics.REGISTRY.counter(
+    "mzt_reduce_state_merges_total",
+    "reduce steps by their table update: the delta merged into the table (merged), or the merge flagged a packed-key double collision, an error row (collision)",
     labels=_REDUCE_LABELS + ("outcome",),
 )
 
@@ -2096,6 +2110,8 @@ class Dataflow:
                     _REDUCE_GROUPS.set(node.groups, **labels)
                     outcome = "carried" if node.errs_carried else "empty"
                     _REDUCE_ERR_BATCHES.inc(1, outcome=outcome, **labels)
+                    outcome = "collision" if node.collided else "merged"
+                    _REDUCE_STATE_MERGES.inc(1, outcome=outcome, **labels)
                 elif is_topk and node.regathered is not None:
                     labels = {"dataflow": obj_id, "operator": f"{op_i}:{m['type']}"}
                     _TOPK_STEP_NS.observe(elapsed, **labels)

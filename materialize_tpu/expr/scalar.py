@@ -105,7 +105,9 @@ class EvalErr(enum.IntEnum):
     # without resolving the probe: the answer would be unsound, so the tick
     # reports an error instead of silently dropping the group (needs >4
     # distinct live keys sharing one 32-bit hash — rare but plausible at
-    # tens of millions of keys; detected, never silent)
+    # tens of millions of keys; detected, never silent); also a reduce
+    # step's table merge that met two keys under one packed (hash, mix)
+    # pair (a 2^-64 double collision, ops/reduce.py::_accum_pack)
     HASH_COLLISION_EXHAUSTED = 3
     # a string column held a code outside the dictionary (corrupt data);
     # string-function tables cannot resolve it

@@ -33,7 +33,7 @@ from ..repr.batch import (
 from ..repr.hashing import PAD_HASH, hash_columns
 from .consolidate import _stable_partition_perm, row_equal_prev, run_sum
 from .permute import multi_take
-from .search import merge_perm, searchsorted, sort_perm
+from .search import merge_perm, merge_rank, searchsorted, sort_perm
 
 # Fast-path scan width for hash-bucket lookups. u32 row hashes make small
 # buckets routine at scale (birthday collisions from ~2^16 keys), so lookups
@@ -190,28 +190,37 @@ def _accum_take(s: AccumState, idx: jnp.ndarray) -> AccumState:
 def _consolidate_accums_sorted(s: AccumState):
     """Run-merge + compaction tail over a packed-key-ordered table.
 
-    Run boundaries come from full (hash, keys) row comparison — the packed
-    ordering only guarantees equal keys land adjacent (sort path) or within
-    a tiny cluster (merge path). Returns (state', dup): `dup` flags live
-    same-key rows that survived unmerged (possible only via a packed-key
-    double collision between sources in the merge path) — callers surface
-    it as a failed tick."""
+    Run boundaries come from full (hash, keys) row comparison, so equal keys
+    must land adjacent (the sort path's keys tiebreak). Returns (state',
+    dup), `dup` as `_unmerged_keys` reads it."""
     run_start = ~row_equal_prev((s.hashes, *s.keys))
     # segmented sum by run over every accumulator plus nrows at once
     summed = run_sum(run_start, (*s.accums, s.nrows))
     accums, nrows = summed[:-1], summed[-1]
-    nonzero = nrows != 0
-    for a in accums:
-        nonzero = nonzero | (a != 0)
-    live = run_start & nonzero & (s.hashes != PAD_HASH)
+    live = run_start & _nonzero(accums, nrows) & (s.hashes != PAD_HASH)
     hashes = jnp.where(live, s.hashes, PAD_HASH)
     keys = tuple(jnp.where(live, k, jnp.zeros_like(k)) for k in s.keys)
     accums = tuple(jnp.where(live, a, jnp.zeros_like(a)) for a in accums)
     nrows = jnp.where(live, nrows, 0)
     perm = _stable_partition_perm(live)
     out = _accum_take(AccumState(hashes, keys, accums, nrows), perm)
-    # unmerged duplicates sit within a few slots of each other post-compaction
-    # (a double-collision cluster holds 2 distinct keys from each source)
+    return out, _unmerged_keys(out)
+
+
+def _nonzero(accums, nrows) -> jnp.ndarray:
+    """A group is held while its row count or any accumulator is nonzero."""
+    nonzero = nrows != 0
+    for a in accums:
+        nonzero = nonzero | (a != 0)
+    return nonzero
+
+
+def _unmerged_keys(out: AccumState) -> jnp.ndarray:
+    """Whether a compacted table holds a live key twice: possible only via a
+    packed-key double collision between the sides of a merge, whose
+    unmerged rows sit within a few slots of each other (a double-collision
+    cluster holds 2 distinct keys from each side). Callers surface it as a
+    failed step."""
     from ..repr.hashing import value_view
 
     dup = out.count() < 0  # varying-typed False
@@ -221,7 +230,7 @@ def _consolidate_accums_sorted(s: AccumState):
             kv = value_view(k)
             eq = eq & (kv[d:] == kv[:-d])
         dup = dup | jnp.any(eq)
-    return out, dup
+    return dup
 
 
 @jax.jit
@@ -243,18 +252,68 @@ def consolidate_accums(s: AccumState) -> AccumState:
 def _merge_consolidate_accums(a: AccumState, b: AccumState):
     ka_hi, ka_lo = _accum_pack(a)
     kb_hi, kb_lo = _accum_pack(b)
-    perm = merge_perm(ka_hi, ka_lo, kb_hi, kb_lo)
-    return _consolidate_accums_sorted(_accum_take(AccumState.concat(a, b), perm))
+    rank = merge_rank(ka_hi, ka_lo, kb_hi, kb_lo)
+    perm = merge_perm(ka_hi, ka_lo, kb_hi, kb_lo, rank)
+    # Each side holds a key once, so a key's run in the merged order is at
+    # most its `a` row and then its `b` row, and the one row of the longer
+    # side a row of the shorter can meet is its merged neighbour, which
+    # `rank` names: the pairs are found from the shorter side alone. A pair's
+    # total goes to its `a` row (the run's start, whose key bits the sort
+    # path keeps) and its `b` row dies, so the payload is gathered ONCE,
+    # straight into its compacted slot.
+    na, nb = a.hashes.shape[0], b.hashes.shape[0]
+    if nb <= na:
+        ia, ib = jnp.clip(rank - 1, 0, na - 1), jax.lax.iota(jnp.int32, nb)
+    else:
+        ia, ib = jax.lax.iota(jnp.int32, na), jnp.clip(rank, 0, nb - 1)
+    from ..repr.hashing import value_view
+
+    pair = (a.hashes[ia] == b.hashes[ib]) & (b.hashes[ib] != PAD_HASH)
+    for ka, kb in zip(a.keys, b.keys):
+        pair = pair & (value_view(ka[ia]) == value_view(kb[ib]))
+    a_vals, b_vals = (*a.accums, a.nrows), (*b.accums, b.nrows)
+    if nb <= na:
+        added = tuple(
+            x.at[ia].add(jnp.where(pair, y, 0), mode="promise_in_bounds")
+            for x, y in zip(a_vals, b_vals)
+        )
+        b_dead = pair
+    else:
+        added = tuple(
+            x + jnp.where(pair, y, 0) for x, y in zip(a_vals, multi_take(b_vals, ib))
+        )
+        b_dead = (b.hashes != b.hashes).at[jnp.where(pair, ib, nb)].set(True, mode="drop")
+    a_live = (a.hashes != PAD_HASH) & _nonzero(added[:-1], added[-1])
+    b_live = (b.hashes != PAD_HASH) & ~b_dead & _nonzero(b_vals[:-1], b_vals[-1])
+    live = jnp.concatenate([a_live, b_live])[perm]  # in merged order
+    src = perm[_stable_partition_perm(live)]
+    both = AccumState.concat(AccumState(a.hashes, a.keys, added[:-1], added[-1]), b)
+    g = _accum_take(both, src)
+    held = jax.lax.iota(jnp.int32, na + nb) < jnp.sum(live.astype(jnp.int32))
+    out = AccumState(
+        jnp.where(held, g.hashes, PAD_HASH),
+        tuple(jnp.where(held, k, jnp.zeros_like(k)) for k in g.keys),
+        tuple(jnp.where(held, x, jnp.zeros_like(x)) for x in g.accums),
+        jnp.where(held, g.nrows, 0),
+    )
+    return out, _unmerged_keys(out)
 
 
 def merge_consolidate_accums(a: AccumState, b: AccumState):
     """O(n) merge of two consolidated accum tables by packed key.
 
-    Returns (state', dup). Both inputs must be consolidate_accums /
-    merge_consolidate_accums outputs (packed-key order, unique live keys).
-    `dup` is the loud-failure flag for the 2^-64 packed-key double collision
-    (see _accum_pack) — treated like a capacity overflow by callers, never a
-    silent mis-aggregation."""
+    Returns (state', dup), state' at cap(a) + cap(b) with its live rows
+    first. Both inputs must be consolidate_accums / merge_consolidate_accums
+    outputs (packed-key order, unique live keys). A reduce step merges its
+    consolidated delta into its table here (`accumulable_step`,
+    `fused_reduce._fused_mfp_reduce_step`): only the shorter side is ranked
+    into the other (`merge_perm`), where sorting the concatenation again
+    would sort both. Without a double collision state' is
+    `consolidate_accums(AccumState.concat(a, b))` bit for bit. `dup` is the
+    loud-failure flag for the 2^-64 packed-key double collision (see
+    _accum_pack): a reduce step turns it into a HASH_COLLISION_EXHAUSTED
+    error row (`collision_errs`), the LSM treats it like a capacity
+    overflow; never a silent mis-aggregation."""
     return _merge_consolidate_accums(a, b)
 
 
@@ -417,18 +476,24 @@ def accum_overflow_errs(
 
 
 @jax.jit
-def collision_errs(probe: AccumState, missed, time) -> UpdateBatch:
-    """Error-collection rows for unresolved hash-bucket probes."""
+def collision_errs(probe: AccumState, missed, time, dup=None) -> UpdateBatch:
+    """Error-collection rows for unresolved hash-bucket probes, and one more
+    (in the first row) where `dup`, a table merge's double-collision flag
+    (`merge_consolidate_accums`), is set."""
     from ..expr.scalar import EvalErr
 
     t = to_device_time(time)
     code = jnp.asarray(int(EvalErr.HASH_COLLISION_EXHAUSTED), I64_DTYPE)
+    diffs = missed.astype(DIFF_DTYPE)
+    if dup is not None:
+        diffs = diffs.at[0].add(dup.astype(DIFF_DTYPE))
+    err = diffs != 0
     return UpdateBatch(
-        hashes=jnp.where(missed, jnp.zeros_like(probe.hashes), PAD_HASH),
+        hashes=jnp.where(err, jnp.zeros_like(probe.hashes), PAD_HASH),
         keys=(),
-        vals=(jnp.where(missed, code, 0),),
-        times=jnp.where(missed, t, PAD_TIME),
-        diffs=jnp.where(missed, 1, 0).astype(DIFF_DTYPE),
+        vals=(jnp.where(err, code, 0),),
+        times=jnp.where(err, t, PAD_TIME),
+        diffs=diffs,
     )
 
 
@@ -501,21 +566,27 @@ def _emit_output(
 
 
 def step_counts(
-    new_state: AccumState, contrib: AccumState, old_nrows, errs: UpdateBatch
+    new_state: AccumState, contrib: AccumState, old_nrows, errs: UpdateBatch, dup
 ) -> jnp.ndarray:
-    """i32[3] a reduce step hands the host in ONE read: the live groups of
+    """i32[4] a reduce step hands the host in ONE read: the live groups of
     the table after the step, the groups whose output row changed in it
     (appeared, vanished, or present on both sides with an accumulator that
     moved; `contrib` is consolidated, so its live rows are the groups the
-    tick touched, and one whose deltas cancel is not among them), and the
-    live rows of the error batch the step returns."""
+    tick touched, and one whose deltas cancel is not among them), the live
+    rows of the error batch the step returns, and 1 where the table's merge
+    flagged a double collision (`dup`), else 0."""
     was, now = old_nrows > 0, old_nrows + contrib.nrows > 0
     moved = was != was  # varying-typed False
     for d in contrib.accums:
         moved = moved | (d != 0)
     changed = contrib.live & ((was != now) | (was & now & moved))
     return jnp.stack(
-        [new_state.count(), jnp.sum(changed.astype(jnp.int32)), errs.count()]
+        [
+            new_state.count(),
+            jnp.sum(changed.astype(jnp.int32)),
+            errs.count(),
+            dup.astype(jnp.int32),
+        ]
     )
 
 
@@ -524,11 +595,12 @@ _step_counts = jax.jit(step_counts)
 
 def read_step_counts(counts, errs: UpdateBatch):
     """The host's one read of `step_counts`: (live groups, changed groups,
-    the step's error delta, or None where it holds no row). An empty error
-    delta and none are the same collection, and None is what every operator
-    downstream skips without a program."""
-    groups, changed, n_errs = (int(c) for c in np.asarray(counts))
-    return groups, changed, errs if n_errs else None
+    the step's error delta, or None where it holds no row, whether the
+    table's merge flagged a collision). An empty error delta and none are
+    the same collection, and None is what every operator downstream skips
+    without a program."""
+    groups, changed, n_errs, dup = (int(c) for c in np.asarray(counts))
+    return groups, changed, errs if n_errs else None, bool(dup)
 
 
 def accumulable_step(
@@ -544,10 +616,12 @@ def accumulable_step(
     their new rows, at time t (groups whose accumulators didn't change
     cancel), at twice cap(Δ), or at KEYLESS_OUT_CAP without key columns.
     Rows whose aggregate input expression errors land in Δerrs.
-    The state comes back at cap(state) + cap(Δ); callers cut it back to the
+    The state comes back at cap(state) + cap(Δ), the consolidated delta
+    merged into it (`merge_consolidate_accums`); callers cut it back to the
     bucket of its groups, which `counts` (`step_counts`, still on the
-    device) hands them with the changed groups and Δerrs's live rows in one
-    read (`read_step_counts`).
+    device) hands them with the changed groups, Δerrs's live rows and the
+    merge's collision flag in one read (`read_step_counts`). A double
+    collision in the merge is an error row in Δerrs, never a wrong sum.
     """
     raw_contrib, errs = _contributions(delta, key_cols, aggs)
     contrib = consolidate_accums(raw_contrib)
@@ -556,11 +630,12 @@ def accumulable_step(
     from .consolidate import consolidate  # local import to avoid cycle
 
     out = consolidate(out)
+    new_state, dup = merge_consolidate_accums(state, contrib)
     errs = consolidate(
-        UpdateBatch.concat(errs, collision_errs(contrib, missed, time))
+        UpdateBatch.concat(errs, collision_errs(contrib, missed, time, dup))
     )
     ov = accum_overflow_errs(contrib, old_accums, aggs, time)
     if ov is not None:
         errs = consolidate(UpdateBatch.concat(errs, ov))
-    new_state = consolidate_accums(AccumState.concat(state, contrib))
-    return new_state, out, errs, _step_counts(new_state, contrib, old_nrows, errs)
+    counts = _step_counts(new_state, contrib, old_nrows, errs, dup)
+    return new_state, out, errs, counts

@@ -86,8 +86,23 @@ def searchsorted2(
     return pos + _pred2(a_hi[pos], a_lo[pos], q_hi, q_lo, side).astype(jnp.int32)
 
 
-def merge_perm(
+def merge_rank(
     a_hi: jnp.ndarray, a_lo: jnp.ndarray, b_hi: jnp.ndarray, b_lo: jnp.ndarray
+) -> jnp.ndarray:
+    """The shorter side's rows ranked into the longer (`b` when the sides
+    are as long): per row, the rows of the other side the stable merge puts
+    before it. `b`'s rows go after `a`'s equal ones."""
+    if int(b_hi.shape[0]) <= int(a_hi.shape[0]):
+        return searchsorted2(a_hi, a_lo, b_hi, b_lo, side="right")
+    return searchsorted2(b_hi, b_lo, a_hi, a_lo, side="left")
+
+
+def merge_perm(
+    a_hi: jnp.ndarray,
+    a_lo: jnp.ndarray,
+    b_hi: jnp.ndarray,
+    b_lo: jnp.ndarray,
+    rank: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather permutation of the stable merge of two (hi, lo)-sorted sides.
 
@@ -98,15 +113,14 @@ def merge_perm(
     order, so a 0/1 mark of those slots and its inclusive prefix sum `c` name
     every slot's source: the c-th row of the short side where marked, the
     (p - c)-th row of the long side elsewhere. A head merge (T, T/16) thus
-    searches T/16 rows, not T + T/16. (The mark's zeros derive from the data
-    so varying manual axes match under shard_map.)
+    searches T/16 rows, not T + T/16. `rank` is `merge_rank`'s, where the
+    caller has it already. (The mark's zeros derive from the data so varying
+    manual axes match under shard_map.)
     """
     na, nb = int(a_hi.shape[0]), int(b_hi.shape[0])
     b_short = nb <= na
-    if b_short:  # b's rows go after a's equal ones
-        rank = searchsorted2(a_hi, a_lo, b_hi, b_lo, side="right")
-    else:  # a's rows go before b's equal ones
-        rank = searchsorted2(b_hi, b_lo, a_hi, a_lo, side="left")
+    if rank is None:
+        rank = merge_rank(a_hi, a_lo, b_hi, b_lo)
     slot = lax.iota(jnp.int32, rank.shape[0]) + rank
     zeros = jnp.broadcast_to((a_hi[:1] * 0).astype(jnp.int32), (na + nb,))
     mark = zeros.at[slot].set(1, indices_are_sorted=True, unique_indices=True)

@@ -227,8 +227,8 @@ def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, del
     BULK_ROWS slices against a table held at BULK_ROWS (dataflow/runtime.py),
     a refresh steps the 16,384-row lineitem delta against the one-group table
     at its bucket of 8. Either way the keyless step's output leaves it at
-    KEYLESS_OUT_CAP rows, beside its three counts (groups, changed groups,
-    error rows) for the host's one read."""
+    KEYLESS_OUT_CAP rows, beside its four counts (groups, changed groups,
+    error rows, the table merge's collision flag) for the host's one read."""
     from materialize_tpu.dataflow.runtime import BULK_ROWS
     from materialize_tpu.ops.fused_reduce import _fused_mfp_reduce_step
     from materialize_tpu.ops.reduce import KEYLESS_OUT_CAP
@@ -245,7 +245,7 @@ def test_q6_fused_reduce_step_compiles_for_v5e(one_chip, q6_step, state_cap, del
     ).compile()
     _state, out, _errs, counts = compiled.out_info
     assert {o.shape for o in jax.tree_util.tree_leaves(out)} == {(KEYLESS_OUT_CAP,)}
-    assert counts.shape == (3,)
+    assert counts.shape == (4,)
     mem = compiled.memory_analysis()
     # one slice's temporaries (2.3 GB at the hydration slice) beside the source's spines
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
@@ -303,25 +303,28 @@ def _shaped(tree, sharding, n):
 
 
 @pytest.mark.slow
-def test_q18_per_order_reduce_compiles_for_v5e(one_chip, q18_calls):
+@pytest.mark.parametrize(
+    "delta_cap", [1 << 21, 1 << 14], ids=["hydration_slice_2097152", "refresh_delta_16384"]
+)
+def test_q18_per_order_reduce_compiles_for_v5e(one_chip, q18_calls, delta_cap):
     """Q18's subquery, a sum of quantities per order, holds one group per
     order: 1.5 M at SF1, a table of 2,097,152 rows of capacity, and
     hydration steps lineitem's snapshot against it in BULK_ROWS slices
-    (dataflow/runtime.py). (A refresh's step, over the 16,384-row delta, is
-    the same program at a smaller slice: 0.27 GB, no test of its own.)
-    Slow: the compile takes some 190 s of five cores alone, and beside Q6's
-    hydration compile in a tier-1 run each passes the 300 s limit."""
+    (dataflow/runtime.py); a refresh steps lineitem's 16,384-row delta
+    against it. Both merge the delta into the table. Slow: the slice's
+    compile takes minutes of five cores alone, and beside Q6's hydration
+    compile in a tier-1 run each came near the 300 s limit."""
     from materialize_tpu.dataflow.runtime import BULK_ROWS
     from materialize_tpu.ops.fused_reduce import _fused_mfp_reduce_step
 
     assert BULK_ROWS == 1 << 21
     state, delta, time, static = q18_calls["reduce"]
     compiled = _fused_mfp_reduce_step.lower(
-        _shaped(state, one_chip, 1 << 21), _shaped(delta, one_chip, BULK_ROWS),
+        _shaped(state, one_chip, 1 << 21), _shaped(delta, one_chip, delta_cap),
         jax.ShapeDtypeStruct((), jnp.asarray(time).dtype, sharding=one_chip), **static,
     ).compile()
     _state, _out, _errs, counts = compiled.out_info
-    assert counts.shape == (3,)
+    assert counts.shape == (4,)
     mem = compiled.memory_analysis()
     # the hydration slice's 2.36 GB of temporaries and 0.47 GB of output beside the source's spines
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
